@@ -6,7 +6,7 @@ This is NOT a port of the Java reference. The coordination/protocol state machin
 run host-side in Python (single-threaded, deterministic, simulation-first, mirroring
 the reference's design where an entire cluster runs on one logical clock); the
 performance-critical data plane -- batched dependency computation and execute-order
-closure -- is expressed as JAX/XLA/Pallas tensor programs behind the DepsResolver SPI
+closure -- is expressed as jitted JAX tensor programs (XLA) behind the DepsResolver SPI
 (see accord_tpu.ops), sharded over a jax.sharding.Mesh for multi-chip scale
 (see accord_tpu.parallel).
 
@@ -20,7 +20,7 @@ Layer map (mirrors SURVEY.md section 1):
   coordinate/  L7 client-side coordination state machines + quorum trackers
   impl/        L8 default implementations (in-memory stores, progress log)
   sim/         L9 deterministic whole-cluster simulation harness ("burn test")
-  ops/         TPU data plane: deps-resolution kernels (JAX/Pallas)
+  ops/         TPU data plane: deps-resolution kernels (jax.jit / shard_map)
   parallel/    device-mesh sharding of the data plane
   maelstrom/   JSON-over-stdio harness for black-box linearizability testing
 """

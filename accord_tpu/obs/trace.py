@@ -19,7 +19,7 @@ Event vocabulary (Chrome trace_event phases, exported by obs/export.py):
   s/t/f flow         (coordinator -> replica -> device dispatch linking)
 
 No recorder call may originate under jax tracing: the append funnel
-asserts `jax.core.trace_state_clean()` while recording, so a span
+asserts jax's `trace_state_clean()` while recording, so a span
 accidentally placed inside a jit-traced function fails loudly at trace
 time instead of silently baking one stale event into the compiled
 artifact (guard unit-tested in tests/test_obs.py).
@@ -37,16 +37,13 @@ _jax_clean: Optional[Callable[[], bool]] = None
 
 
 def _tracing_clean() -> bool:
-    """True when NOT under a jax trace (cheap after first call; tolerant
-    of jax being absent or the API moving)."""
+    """True when NOT under a jax trace (cheap after the first call, which
+    imports jax)."""
     global _jax_clean
     if _jax_clean is None:
-        try:
-            from jax.core import trace_state_clean as fn
-        except Exception:  # noqa: BLE001 -- no jax / API drift: no guard
-            def fn() -> bool:
-                return True
-        _jax_clean = fn
+        # jax 0.9 keeps this predicate in jax._src.core only
+        from jax._src.core import trace_state_clean
+        _jax_clean = trace_state_clean
     return _jax_clean()
 
 

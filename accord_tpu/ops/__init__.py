@@ -1,5 +1,5 @@
 """The TPU data plane: batched dependency computation and execute-order
-closure as JAX/XLA/Pallas tensor programs.
+closure as jitted JAX tensor programs compiled by XLA.
 
 This is the point of the whole exercise (SURVEY.md section 7 step 7,
 BASELINE.json north star): the reference implements its deps-calculation hot

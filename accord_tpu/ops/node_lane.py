@@ -226,16 +226,10 @@ class MergedBuffer:
     def copy_async(self) -> None:
         if not self._copied:
             self._copied = True
-            try:
-                self.dev.copy_to_host_async()
-            except AttributeError:
-                pass
+            self.dev.copy_to_host_async()
 
     def is_ready(self) -> bool:
-        try:
-            return self.dev.is_ready()
-        except AttributeError:
-            return True
+        return self.dev.is_ready()
 
     def host(self):
         if self._host is None:

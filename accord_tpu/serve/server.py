@@ -188,11 +188,18 @@ class NodeServer:
         self.txn_error = self.metrics.counter("serve.txn_error")
         self.sink = _SocketSink(self)
         self.resolver = None
+        # which device answers this node's deps queries, as JAX placed the
+        # resolver's arrays (never chosen here): reported in the warm-up
+        # line and in every stats snapshot, so a node that JAX quietly put
+        # on the CPU cannot pass for one on the chip
+        self.device = {"platform": "host", "kind": "host scan"}
         if cfg.device_deps:
             from accord_tpu.ops.resolver import BatchDepsResolver
             # adaptive_window on: the admission governor's pressure hook
             # sheds into this resolver's staged-window scale
             self.resolver = BatchDepsResolver(adaptive_window=True)
+            dev = self.resolver.device
+            self.device = {"platform": dev.platform, "kind": dev.device_kind}
         peer_ids = sorted(set(cfg.peers) | {cfg.node_id})
         topology = build_topology(peer_ids)
         from accord_tpu.impl.progress import ProgressEngine
@@ -218,10 +225,12 @@ class NodeServer:
             cfg.admission_rate, cfg.admission_burst, cfg.max_inflight,
             registry=self.metrics, on_pressure=self._on_pressure)
         # outbound peer links: id -> _Conn (None until connected); frames
-        # queued while the dial is in flight
+        # queued while the dial is in flight, one dial task per peer
         self._peer_conns: Dict[int, Optional[_Conn]] = {}
         self._peer_backlog: Dict[int, List[dict]] = {}
-        self._peer_dialing: set = set()
+        self._peer_dialing: Dict[int, asyncio.Task] = {}
+        # accepted connections (peers' dials and clients), closed on stop
+        self._inbound: set = set()
         self._kick: Optional[asyncio.Event] = None
         self._server: Optional[asyncio.base_events.Server] = None
         self._stopping: Optional[asyncio.Event] = None
@@ -240,8 +249,8 @@ class NodeServer:
             return
         self._peer_backlog.setdefault(to, []).append(env)
         if to not in self._peer_dialing and self._loop is not None:
-            self._peer_dialing.add(to)
-            self._loop.create_task(self._dial_peer(to))
+            self._peer_dialing[to] = self._loop.create_task(
+                self._dial_peer(to))
 
     def send_on_conn(self, conn: _Conn, env: dict) -> None:
         conn.send(env)
@@ -265,8 +274,10 @@ class NodeServer:
                 conn.send(env)
             await self._read_loop(reader, conn)
         finally:
-            self._peer_dialing.discard(to)
-            if self._peer_conns.get(to) is not None:
+            self._peer_dialing.pop(to, None)
+            conn = self._peer_conns.get(to)
+            if conn is not None:
+                conn.writer.close()
                 self._peer_conns[to] = None  # reconnect on next send
 
     # -- inbound -------------------------------------------------------------
@@ -285,15 +296,14 @@ class NodeServer:
     async def _on_client(self, reader: asyncio.StreamReader,
                          writer: asyncio.StreamWriter) -> None:
         conn = _Conn(self, writer)
+        self._inbound.add(conn)
         try:
             await self._read_loop(reader, conn)
         except transport.FrameError as e:
             self.log(f"frame error: {e}")
         finally:
-            try:
-                writer.close()
-            except Exception:
-                pass
+            self._inbound.discard(conn)
+            writer.close()
 
     def handle_envelope(self, env: dict, conn: Optional[_Conn]) -> None:
         kind = env.get("t")
@@ -408,6 +418,8 @@ class NodeServer:
         over the node's full snapshot (txn lifecycle + resolver planes)."""
         snap = self.node.metrics_snapshot()
         snap.update(self.metrics.snapshot())
+        snap["serve.device_platform"] = self.device["platform"]
+        snap["serve.device_kind"] = self.device["kind"]
         return snap
 
     def _jit_cache(self) -> dict:
@@ -492,7 +504,9 @@ class NodeServer:
         if self.cfg.warmup:
             t0 = time.monotonic()
             self.warm_kernels()
-            self.log("warmup done in %.1fs" % (time.monotonic() - t0))
+            self.log("warmup done in %.1fs on %s (%s)" % (
+                time.monotonic() - t0, self.device["platform"],
+                self.device["kind"]))
         host, port = self.cfg.listen
         bind = self.cfg.bind_host or host
         self._server = await asyncio.start_server(self._on_client, bind, port)
@@ -504,6 +518,16 @@ class NodeServer:
         finally:
             ticker.cancel()
             self._server.close()
+            # wait_closed() waits for every accepted connection's handler,
+            # and peers and clients keep theirs open for as long as they
+            # live: close what this server holds (buffered replies such as
+            # shutdown_ok still flush) so the handlers see EOF and return
+            for conn in list(self._inbound):
+                conn.writer.close()
+            dials = list(self._peer_dialing.values())
+            for task in dials:
+                task.cancel()
+            await asyncio.gather(ticker, *dials, return_exceptions=True)
             await self._server.wait_closed()
 
 
@@ -562,6 +586,8 @@ def main(argv=None) -> int:
         warmup=not args.no_warmup,
         rpc_timeout_ms=args.rpc_timeout_ms,
         bind_host=args.bind_host)
+    from accord_tpu.utils.compile_cache import place_compile_cache
+    place_compile_cache()
     server = NodeServer(cfg)
 
     async def _run():

@@ -400,6 +400,10 @@ def main(argv=None) -> int:
 
     config_factory = None
     if args.device_chaos:
+        # the only mode of this entry point that reaches the device
+        # (--device-messages without a tick engine batches on the host)
+        from accord_tpu.utils.compile_cache import place_compile_cache
+        place_compile_cache()
         # the injected faults land on the DEVICE dispatch path, so the run
         # needs device resolvers; a fresh config per run keeps --reconcile
         # legs from sharing resolver state

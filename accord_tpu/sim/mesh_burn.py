@@ -820,4 +820,8 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    # placed here, not in main(): tests call main() in-process, and the
+    # suite runs without a persistent cache
+    from accord_tpu.utils.compile_cache import place_compile_cache
+    place_compile_cache()
     sys.exit(main())

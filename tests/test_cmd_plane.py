@@ -306,3 +306,48 @@ def test_plane_metrics_reach_node_snapshot():
     assert snap.get("cmd_plane_dispatches", 0) >= 1
     assert snap.get("cmd_plane_upload_bytes", 0) > 0
     assert snap.get("cmd_fastpath_device_evals", 0) >= 1
+
+
+def test_plane_failure_replays_span_counted_and_logged(caplog, monkeypatch):
+    """A cmd plane that RAISES (a refused program, a launch error) must not
+    vanish: the resolver's drain replays the span through the Python
+    handlers, counts it in resolver.cmd_span_replays and logs the first
+    one. Only the drain runs -- nothing is dispatched, nothing compiles."""
+    import logging
+
+    from accord_tpu.ops.resolver import BatchDepsResolver
+
+    resolver = BatchDepsResolver(num_buckets=128, initial_cap=256)
+    cluster = Cluster(1, ClusterConfig(
+        num_nodes=1, rf=1, num_shards=1, stores_per_node=1, progress=False,
+        cmd_plane=True, deps_resolver_factory=lambda: resolver,
+        deps_batch_window_ms=2.0))
+    node = cluster.nodes[1]
+    store = node.command_stores.stores[0]
+
+    def refused(ops):
+        raise RuntimeError("XLA refused cmd_tick")
+
+    monkeypatch.setattr(store.cmd_plane, "eval_batch", refused)
+
+    def enqueue(value):
+        txn = _mk_txn((value, value + 1), value)
+        tid = node.next_txn_id(txn.kind, txn.domain)
+        resolver.enqueue_preaccept(
+            store, tid, txn.slice(store.ranges, include_query=False),
+            node.compute_route(txn), Ballot.ZERO)
+        return tid
+
+    with caplog.at_level(logging.ERROR, logger="accord_tpu.ops.resolver"):
+        tids = [enqueue(1), enqueue(2)]
+        items = resolver._drain_and_preaccept(node)
+        assert resolver.cmd_span_replays == 1   # one span of two ops
+        tids.append(enqueue(3))
+        items += resolver._drain_and_preaccept(node)
+    assert resolver.metrics.snapshot()["resolver.cmd_span_replays"] == 2
+    # the host handlers answered every replayed op
+    assert [it.txn_id for it in items] == tids
+    assert all(store.command(t).status == Status.PRE_ACCEPTED for t in tids)
+    assert store.cmd_plane.dispatches == 0
+    logged = [r for r in caplog.records if "cmd plane failed" in r.message]
+    assert len(logged) == 1 and logged[0].exc_info is not None

@@ -1,10 +1,12 @@
 """Serving surface tests: the transport codec (pure, no sockets), the
-admission governor (deterministic injected clock), and -- marked slow --
-real 3-process clusters over TCP: commit + strict-serializability verify,
-and a crash-one-node leg where the surviving quorum keeps committing.
+admission governor (deterministic injected clock), one in-process node on a
+loopback socket (host deps, no compile) for the stop path, and -- marked
+slow -- real 3-process clusters over TCP: commit + strict-serializability
+verify, and a crash-one-node leg where the surviving quorum keeps
+committing.
 
 No sockets are bound at collection time; every bind happens inside a test
-body (and only in the slow ones)."""
+body."""
 from __future__ import annotations
 
 import asyncio
@@ -210,6 +212,48 @@ def test_node_shutdown_idempotent_and_schedulerless():
     second.shutdown()  # must not touch the missing scheduler
 
 
+def test_run_returns_after_shutdown_with_client_still_connected():
+    """`run()` must come back once a client asked for shutdown, although
+    that client (like every peer) keeps its connection open: since Python
+    3.12 `Server.wait_closed()` waits for every accepted connection, so
+    the server closes the ones it holds. Host deps, no warm-up: nothing
+    here compiles."""
+    from accord_tpu.serve.loadgen import LoadClient
+    from accord_tpu.serve.server import NodeServer, ServeConfig
+
+    (port,) = _free_ports(1)
+    addrs = {1: ("127.0.0.1", port)}
+    lines = []
+    server = NodeServer(ServeConfig(1, addrs[1], addrs, device_deps=False,
+                                    warmup=False), log=lines.append)
+    assert server.snapshot()["serve.device_platform"] == "host"
+
+    async def scenario():
+        run = asyncio.ensure_future(server.run())
+        while not any(line.startswith("serving node") for line in lines):
+            assert not run.done(), run
+            await asyncio.sleep(0.01)
+        client = LoadClient(addrs)
+        await client.connect()
+        try:
+            reply = await client.conns[1].request(
+                {"t": "txn", "msg_id": client.next_msg_id(),
+                 "ops": [["append", 7, 1], ["r", 7, None]]}, 10.0)
+            assert reply["t"] == "txn_ok", reply
+            assert reply["txn"][1] == ["r", 7, [1]]
+            reply = await client.admin(1, "shutdown", timeout_s=10.0)
+            assert reply == {"t": "shutdown_ok", "msg_id": reply["msg_id"],
+                             "drained": True}
+            assert client.conns[1].writer is not None  # still held open
+            await asyncio.wait_for(run, timeout=10.0)
+        finally:
+            run.cancel()
+            await client.close()
+
+    asyncio.run(scenario())
+    assert not [line for line in lines if line.startswith("error")], lines
+
+
 # -- multi-process cluster (slow) ---------------------------------------------
 
 def _free_ports(n):
@@ -231,7 +275,10 @@ class _Cluster:
         self.ports = _free_ports(n)
         peers = ",".join(f"{i + 1}=127.0.0.1:{p}"
                          for i, p in enumerate(self.ports))
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        # the node entry point places a persistent compile cache; keep it
+        # out of the checkout and private to this cluster
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=os.path.join(tmpdir, "jax-cache"))
         self.logs = []
         self.procs = []
         for i, port in enumerate(self.ports):
