@@ -314,6 +314,12 @@ GLOSSARY: Dict[str, str] = {
     "resolver.harvest_stall_s": "wall seconds blocked on async transfers",
     "resolver.decode_s": "host-side result materialization wall seconds",
     "resolver.readback_s": "device->host transfer wall seconds",
+    "resolver.device_wait_s": "readback_s spent waiting for the kernels to finish",
+    "resolver.transfer_s": "readback_s spent in the device->host copy after them",
+    "resolver.readback_bytes": "result bytes that reached the host",
+    "resolver.starved_stage_s": "device starved (work pending, no call in flight) on the tick path: preaccept, encode, launch",
+    "resolver.starved_decode_s": "device starved inside a harvest's decode",
+    "resolver.starved_outside_s": "device starved outside every resolver phase (enqueue loop, batch-window timer, event queue)",
     "resolver.materialize_s": "decode minus in-decode readback",
     "resolver.host_hidden_s": "host phase seconds run while a call was in flight",
     "resolver.staged_dispatches": "launches taken off the encode-ahead list",
@@ -343,6 +349,7 @@ GLOSSARY: Dict[str, str] = {
     "resolver.device_canaries": "probation canary dispatches double-decoded",
     # -- resolver computed gauges (folded into resolver.snapshot()) ----------
     "resolver.host_hidden_pct": "share of host phase time hidden in the device window",
+    "resolver.pending": "subjects accepted and not yet answered",
     "resolver.upload_bytes": "bytes shipped host->device by arena scatters",
     "resolver.upload_bytes_full_equiv": "bytes the whole-row scheme would have shipped",
     "resolver.upload_bytes.full": "arena bytes shipped as all-lane rows",

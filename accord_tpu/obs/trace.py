@@ -23,6 +23,10 @@ asserts jax's `trace_state_clean()` while recording, so a span
 accidentally placed inside a jit-traced function fails loudly at trace
 time instead of silently baking one stale event into the compiled
 artifact (guard unit-tested in tests/test_obs.py).
+
+Host pipeline stages are entered through `phase`, which also puts the span
+on the jax profiler's clock and feeds an owner's `Occupancy` account (both
+below; unit-tested in tests/test_obs_phases.py).
 """
 from __future__ import annotations
 
@@ -213,3 +217,131 @@ def node_ts(node) -> int:
     emit byte-identical streams)."""
     svc = getattr(node, "time_service", None)
     return svc.now_micros() if svc is not None else REC.now_us()
+
+
+class Occupancy:
+    """When the device had nothing because the host had given it nothing.
+
+    Two counts on the host clock: work accepted and not yet answered
+    (`pending`) and device calls launched and not yet fetched (`inflight`).
+    Time with work pending and nothing in flight is STARVED time, and each
+    starved interval is credited, whole, to what the host was doing in it:
+    `phases` maps a phase name (as `phase` reports it) to a bucket, a
+    nested phase that is not in the map stays in its parent's bucket, and
+    time outside every phase is bucket "outside". One registry timer
+    `<prefix>.starved_<bucket>_s` per bucket; together they partition
+    starved time exactly. Time with nothing pending is in none of them. An
+    interval is credited when it closes or the host changes phase.
+
+    The clock is read at phase boundaries and at the four transitions
+    (first item accepted, first call launched, last call landed, last
+    answer delivered), never per item.
+    """
+
+    __slots__ = ("pending", "inflight", "_clock", "_phases", "_stack",
+                 "_since")
+
+    def __init__(self, registry, prefix: str, phases: dict,
+                 clock: Callable[[], float] = time.perf_counter):
+        self.pending = 0
+        self.inflight = 0
+        self._clock = clock
+        timers = {b: registry.timer(f"{prefix}.starved_{b}_s")
+                  for b in ("outside", *phases.values())}
+        self._phases = {name: timers[b] for name, b in phases.items()}
+        self._stack = [timers["outside"]]  # the bucket of each open phase
+        # start of the open starved interval, None while not starved
+        self._since: Optional[float] = None
+
+    def _turn(self) -> None:
+        """Close the open starved interval into the current bucket; open
+        the next one if the state now reached is starved."""
+        now = self._clock()
+        if self._since is not None:
+            self._stack[-1].add(now - self._since)
+        self._since = now if self.pending and not self.inflight else None
+
+    def accept(self) -> None:
+        self.pending += 1
+        if self.pending == 1:
+            self._turn()
+
+    def deliver(self, n: int = 1) -> None:
+        self.pending -= n
+        if not self.pending:
+            self._turn()
+
+    def launched(self) -> None:
+        self.inflight += 1
+        if self.inflight == 1:
+            self._turn()
+
+    def landed(self) -> None:
+        self.inflight -= 1
+        if not self.inflight:
+            self._turn()
+
+    def enter(self, name: str) -> None:
+        self._turn()
+        self._stack.append(self._phases.get(name, self._stack[-1]))
+
+    def exit(self) -> None:
+        self._turn()
+        self._stack.pop()
+
+
+class phase:
+    """One host phase of an owner's pipeline, entered and left in one place:
+
+        with phase(registry, "resolver.encode", "resolver.encode_s",
+                   account=occupancy, node=node, track="stage_host",
+                   event="encode") as ph:
+            ...
+            ph.args = {"subjects": n}
+
+    Leaving it adds the wall time to the registry timer (`timer`, where
+    given; `ph.dt` holds it afterwards), emits the flight recorder's
+    complete event on (`track`, `event`) with `ph.args` when REC is enabled,
+    closes the `jax.profiler.TraceAnnotation` opened on entry -- so the span
+    is in the profiler's host plane, on the device trace's clock, whenever a
+    profiler session is open, and costs well under a microsecond when none is
+    -- and tells `account` (an Occupancy) that the host left the phase. `ids`
+    (a dispatch id) become the annotation's arguments and seed `ph.args`.
+    Meant per phase and per dispatch, never per item."""
+
+    __slots__ = ("args", "dt", "_timer", "_account", "_name", "_node",
+                 "_track", "_event", "_span", "_ts", "_t0")
+
+    def __init__(self, registry, name: str, timer: Optional[str] = None, *,
+                 account: Optional[Occupancy] = None, node=None,
+                 track: Optional[str] = None, event: Optional[str] = None,
+                 **ids):
+        from jax.profiler import TraceAnnotation
+        self.args = ids or None
+        self.dt = 0.0
+        self._timer = registry.timer(timer) if timer else None
+        self._account = account
+        self._name = name
+        self._node = node
+        self._track = track
+        self._event = event
+        self._span = TraceAnnotation(name, **ids)
+
+    def __enter__(self) -> "phase":
+        if self._account is not None:
+            self._account.enter(self._name)
+        self._ts = node_ts(self._node) if self._track and REC.enabled else 0
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.dt = dt = time.perf_counter() - self._t0
+        self._span.__exit__(*exc)
+        if self._timer is not None:
+            self._timer.add(dt)
+        if self._track and REC.enabled:
+            REC.complete(node_pid(self._node), self._track, self._event,
+                         self._ts, dur=round(dt * 1e6, 3), args=self.args)
+        if self._account is not None:
+            self._account.exit()

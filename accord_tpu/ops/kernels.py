@@ -259,9 +259,10 @@ def kid_word_scatter(kid_rows, kid_idx, word_idx, words):
 def _pack_bits(m):
     """bool[B, A] -> u32[B, A/32] little-bit-first per lane (A % 32 == 0)."""
     b, a = m.shape
-    weights = (jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32))
-    return jnp.sum(m.reshape(b, a // 32, 32).astype(jnp.uint32)
-                   * weights[None, None, :], axis=-1, dtype=jnp.uint32)
+    with jax.named_scope("pack_bits"):
+        weights = (jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32))
+        return jnp.sum(m.reshape(b, a // 32, 32).astype(jnp.uint32)
+                       * weights[None, None, :], axis=-1, dtype=jnp.uint32)
 
 
 @jax.jit
@@ -287,17 +288,24 @@ def deps_resolve(subj_of, subj_keys, subj_before, subj_kinds,
     subj_kinds:  i32[B]
     act_*:       the device arena (see resolver._StoreArena); cap % 32 == 0
     -> u32[B, cap/32] packed dependency bitmask, little-bit-first per lane
+
+    The stages carry jax.named_scope names (metadata only: the operations'
+    `tf_op` path in a profiler trace), as do finalize_csr's.
     """
     b = subj_before.shape[0]
     k = act_bitmaps.shape[1]
-    subj_bm = jnp.zeros((b, k), jnp.float32) \
-        .at[subj_of, subj_keys].max(1.0, mode="drop").astype(jnp.bfloat16)
-    overlap = jax.lax.dot_general(
-        subj_bm, act_bitmaps.astype(jnp.bfloat16),
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) > 0.5
-    witness = witness_table[subj_kinds[:, None], act_kinds[None, :]] == 1
-    before = _lex_before(act_ts[None, :, :], subj_before[:, None, :])
-    m = overlap & witness & before & act_valid[None, :]
+    with jax.named_scope("subject_bitmap"):
+        subj_bm = jnp.zeros((b, k), jnp.float32) \
+            .at[subj_of, subj_keys].max(1.0, mode="drop").astype(jnp.bfloat16)
+    with jax.named_scope("overlap"):
+        overlap = jax.lax.dot_general(
+            subj_bm, act_bitmaps.astype(jnp.bfloat16),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) > 0.5
+    with jax.named_scope("witness_before_mask"):
+        witness = witness_table[subj_kinds[:, None], act_kinds[None, :]] == 1
+        before = _lex_before(act_ts[None, :, :], subj_before[:, None, :])
+        m = overlap & witness & before & act_valid[None, :]
     return _pack_bits(m)
 
 
@@ -511,38 +519,42 @@ def _packed_segment_compact(m, out_cap: int):
     never exceed the bit count, so the word compaction cannot overflow
     without the bit total overflowing too)."""
     s, w = m.shape
-    pop = _popcount_u32(m)                                    # i32[S, W]
-    counts = jnp.sum(pop, axis=1, dtype=jnp.int32)
-    indptr = jnp.concatenate(
-        [jnp.zeros(1, jnp.int32), jnp.cumsum(counts, dtype=jnp.int32)])
-    flat_pop = pop.reshape(-1)
-    flat_val = m.reshape(-1)
-    # global output offset of each word's first bit (word-major order ==
-    # segment-major, row-ascending)
-    bit_off = jnp.cumsum(flat_pop, dtype=jnp.int32) - flat_pop
-    nz = flat_pop > 0
-    slot = jnp.where(nz,
-                     jnp.cumsum(nz.astype(jnp.int32), dtype=jnp.int32) - 1,
-                     out_cap)
+    with jax.named_scope("popcount_prefix"):
+        pop = _popcount_u32(m)                                # i32[S, W]
+        counts = jnp.sum(pop, axis=1, dtype=jnp.int32)
+        indptr = jnp.concatenate(
+            [jnp.zeros(1, jnp.int32), jnp.cumsum(counts, dtype=jnp.int32)])
+        flat_pop = pop.reshape(-1)
+        flat_val = m.reshape(-1)
+        # global output offset of each word's first bit (word-major order ==
+        # segment-major, row-ascending)
+        bit_off = jnp.cumsum(flat_pop, dtype=jnp.int32) - flat_pop
+        nz = flat_pop > 0
+        slot = jnp.where(
+            nz, jnp.cumsum(nz.astype(jnp.int32), dtype=jnp.int32) - 1,
+            out_cap)
     # compact the nonzero words: ONE S*W-entry scatter of flat indices, then
     # out_cap-sized gathers for (value, bit offset, base row index) -- three
     # full-size scatters here tripled the kernel's wall time
-    src = jnp.zeros(out_cap, jnp.int32) \
-        .at[slot].set(jnp.arange(s * w, dtype=jnp.int32), mode="drop")
-    live = jnp.arange(out_cap, dtype=jnp.int32) \
-        < jnp.sum(nz.astype(jnp.int32))
-    cw_val = jnp.where(live, flat_val[src], jnp.uint32(0))
-    cw_off = bit_off[src]
-    cw_row = (src % w) * 32
+    with jax.named_scope("word_compact"):
+        src = jnp.zeros(out_cap, jnp.int32) \
+            .at[slot].set(jnp.arange(s * w, dtype=jnp.int32), mode="drop")
+        live = jnp.arange(out_cap, dtype=jnp.int32) \
+            < jnp.sum(nz.astype(jnp.int32))
+        cw_val = jnp.where(live, flat_val[src], jnp.uint32(0))
+        cw_off = bit_off[src]
+        cw_row = (src % w) * 32
     # bit-expand only the compacted words
-    bits = ((cw_val[:, None] >> jnp.arange(32, dtype=jnp.uint32)) & 1) \
-        .astype(jnp.int32)                                    # [out_cap, 32]
-    within = jnp.cumsum(bits, axis=1, dtype=jnp.int32) - bits
-    pos = jnp.where((bits > 0) & live[:, None], cw_off[:, None] + within,
-                    out_cap)
-    rows = cw_row[:, None] + jnp.arange(32, dtype=jnp.int32)[None, :]
-    dep_rows = jnp.zeros(out_cap, jnp.int32) \
-        .at[pos.reshape(-1)].set(rows.reshape(-1), mode="drop")
+    with jax.named_scope("bit_expand"):
+        bits = ((cw_val[:, None] >> jnp.arange(32, dtype=jnp.uint32)) & 1) \
+            .astype(jnp.int32)                                # [out_cap, 32]
+        within = jnp.cumsum(bits, axis=1, dtype=jnp.int32) - bits
+        pos = jnp.where((bits > 0) & live[:, None],
+                        cw_off[:, None] + within, out_cap)
+        rows = cw_row[:, None] + jnp.arange(32, dtype=jnp.int32)[None, :]
+    with jax.named_scope("row_scatter"):
+        dep_rows = jnp.zeros(out_cap, jnp.int32) \
+            .at[pos.reshape(-1)].set(rows.reshape(-1), mode="drop")
     return indptr, dep_rows
 
 
@@ -741,25 +753,31 @@ def _finalize_csr_body(packed, word_off, kid_rows, slot_subj, slot_kid,
     either way)."""
     b = packed.shape[0]
     kc, w = kid_rows.shape
-    blk = jax.lax.dynamic_slice_in_dim(packed, word_off, w, axis=1)
-    ok = (slot_subj >= 0) & (slot_subj < b) & (slot_kid >= 0) & (slot_kid < kc)
-    kid_m = kid_rows[jnp.clip(slot_kid, 0, kc - 1)]
-    bound = jnp.sum(jnp.where(
-        ok, jnp.sum(_popcount_u32(kid_m), axis=1, dtype=jnp.int32), 0),
-        dtype=jnp.int32)
-    so = jnp.clip(slot_subj, 0, b - 1)
-    m = jnp.where(ok[:, None], blk[so] & kid_m, jnp.uint32(0))
-    r = subj_row[so]
-    widx = jnp.arange(w, dtype=jnp.int32)
-    selfbit = jnp.where(
-        (r >= 0)[:, None] & (widx[None, :] == (r >> 5)[:, None]),
-        (jnp.uint32(1) << (r & 31).astype(jnp.uint32))[:, None],
-        jnp.uint32(0))
-    m = m & ~selfbit
+    with jax.named_scope("slot_mask"):
+        blk = jax.lax.dynamic_slice_in_dim(packed, word_off, w, axis=1)
+        ok = (slot_subj >= 0) & (slot_subj < b) \
+            & (slot_kid >= 0) & (slot_kid < kc)
+        kid_m = kid_rows[jnp.clip(slot_kid, 0, kc - 1)]
+    with jax.named_scope("bound"):
+        bound = jnp.sum(jnp.where(
+            ok, jnp.sum(_popcount_u32(kid_m), axis=1, dtype=jnp.int32), 0),
+            dtype=jnp.int32)
+    with jax.named_scope("slot_mask"):
+        so = jnp.clip(slot_subj, 0, b - 1)
+        m = jnp.where(ok[:, None], blk[so] & kid_m, jnp.uint32(0))
+        r = subj_row[so]
+        widx = jnp.arange(w, dtype=jnp.int32)
+        selfbit = jnp.where(
+            (r >= 0)[:, None] & (widx[None, :] == (r >> 5)[:, None]),
+            (jnp.uint32(1) << (r & 31).astype(jnp.uint32))[:, None],
+            jnp.uint32(0))
+        m = m & ~selfbit
     indptr, dep_rows = _packed_segment_compact(m, out_cap)
-    dep_ts = act_ts[dep_rows]
-    return (indptr, dep_rows, dep_ts, bound,
-            csr_checksum(indptr, dep_rows, dep_ts))
+    with jax.named_scope("ts_gather"):
+        dep_ts = act_ts[dep_rows]
+    with jax.named_scope("checksum"):
+        csum = csr_checksum(indptr, dep_rows, dep_ts)
+    return indptr, dep_rows, dep_ts, bound, csum
 
 
 @functools.partial(jax.jit, static_argnames=("out_cap",))

@@ -58,6 +58,7 @@ new mapping instead of falling back to the host scan.
 """
 from __future__ import annotations
 
+import functools
 import logging
 from collections import deque
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -66,7 +67,7 @@ import numpy as np
 
 from accord_tpu.local.cfk import CfkStatus
 from accord_tpu.obs.metrics import MetricsRegistry, RegCounter, RegTimer
-from accord_tpu.obs.trace import REC, node_pid, node_ts
+from accord_tpu.obs.trace import REC, Occupancy, node_pid, node_ts, phase
 from accord_tpu.ops.encoding import (TimestampEncoder, WITNESS_TABLE,
                                      encode_interval,
                                      encode_key_point_intervals,
@@ -1489,7 +1490,7 @@ class _Call:
     __slots__ = ("packed", "rpacked", "kpacked", "items", "groups",
                  "np_packed", "np_rpacked", "np_kpacked", "want", "did",
                  "stuck_left", "corrupt_pending", "overflow_pending",
-                 "degraded", "faulted", "canary")
+                 "degraded", "faulted", "canary", "landed")
 
     def __init__(self, packed, rpacked, kpacked, items, groups,
                  want=(True, True, True), did=-1):
@@ -1518,6 +1519,9 @@ class _Call:
         self.degraded = False
         self.faulted = False
         self.canary = False
+        # occupancy account: counted in flight from launch until its
+        # results are on the host (BatchDepsResolver._land)
+        self.landed = False
 
     def buffers(self):
         """(holder, host attr, device value) triples the async-copy / poll /
@@ -1542,13 +1546,14 @@ class _Call:
     def has_device(self) -> bool:
         return self.packed is not None or self.rpacked is not None
 
-    def fetch(self) -> bool:
-        """Blocking read of any result the poll didn't drain; True if it
-        actually had to read (the harvest stall case)."""
+    def fetch(self, read) -> bool:
+        """Blocking read, through `read` (BatchDepsResolver._read), of any
+        result the poll didn't drain; True if it actually had to read (the
+        harvest stall case)."""
         stalled = False
         for holder, attr, dev in self.buffers():
             if getattr(holder, attr) is None:
-                setattr(holder, attr, _dev_read(dev))
+                setattr(holder, attr, read(dev))
                 stalled = True
         return stalled
 
@@ -1618,6 +1623,11 @@ class BatchDepsResolver(DepsResolver):
     harvest_stall_s = RegTimer("resolver.harvest_stall_s")  # blocking xfers
     decode_s = RegTimer("resolver.decode_s")         # result materialization
     readback_s = RegTimer("resolver.readback_s")     # device->host transfer
+    # readback_s split: waiting for the kernels, then for the copy; and the
+    # bytes that reached the host
+    device_wait_s = RegTimer("resolver.device_wait_s")
+    transfer_s = RegTimer("resolver.transfer_s")
+    readback_bytes = RegCounter("resolver.readback_bytes")
     materialize_s = RegTimer("resolver.materialize_s")  # decode minus readback
     host_hidden_s = RegTimer("resolver.host_hidden_s")  # host time overlapped
     #                                                     with an in-flight call
@@ -1688,6 +1698,20 @@ class BatchDepsResolver(DepsResolver):
         # RegCounter/RegTimer descriptors write through to it), BEFORE any
         # counter touch
         self.metrics = MetricsRegistry()
+        # the occupancy account (obs/trace.py): time with work pending and
+        # no call in flight is the device starved by the host, credited to
+        # resolver.starved_{stage,decode,outside}_s by the phase the host
+        # was in -- the tick path (preaccept, encode, launch), the harvest
+        # (decode), or neither (the caller's enqueue loop, the batch-window
+        # timer, the event queue). host_hidden_s counts the opposite case
+        self._occ = Occupancy(self.metrics, "resolver", {
+            "resolver.tick": "stage", "resolver.preaccept": "stage",
+            "resolver.encode": "stage", "resolver.launch": "stage",
+            "resolver.harvest": "decode"})
+        # every host phase of the pipeline goes through this one primitive:
+        # registry timer, flight-recorder span, profiler annotation, account
+        self._phase = functools.partial(phase, self.metrics,
+                                        account=self._occ)
         # the range kernel's covered-bucket contraction reduces intervals
         # modulo the bucket count with int32 arithmetic; that wrap is exact
         # only when num_buckets divides 2^32
@@ -1833,6 +1857,7 @@ class BatchDepsResolver(DepsResolver):
         single source for bench JSON and metrics dumps."""
         snap = self.metrics.snapshot()
         snap["resolver.host_hidden_pct"] = round(self.host_hidden_pct, 3)
+        snap["resolver.pending"] = self._occ.pending
         snap["resolver.upload_bytes"] = self.upload_bytes
         snap["resolver.upload_bytes_full_equiv"] = self.upload_bytes_full_equiv
         for k, v in self.upload_bytes_by_field.items():
@@ -2039,6 +2064,7 @@ class BatchDepsResolver(DepsResolver):
         node = store.node
         self._pa_queues.setdefault(id(node), []).append(
             (store, txn_id, partial_txn, route, ballot, out))
+        self._occ.accept()
         self._schedule_tick(store)
         return out
 
@@ -2047,6 +2073,7 @@ class BatchDepsResolver(DepsResolver):
         node = store.node
         self._deps_queues.setdefault(id(node), []).append(
             (store, txn_id, seekables, before, out))
+        self._occ.accept()
         self._schedule_tick(store)
         return out
 
@@ -2133,7 +2160,6 @@ class BatchDepsResolver(DepsResolver):
         phases below run in the wall-clock shadow of the in-flight call.
         stage_decode stays on the harvest event, which fires per dispatch
         after device_latency_ms and drains in dispatch order."""
-        import time as _time
         self._ticking.discard(id(node))
         if not self.overlap_host:
             items = self._drain_and_preaccept(node)
@@ -2148,22 +2174,17 @@ class BatchDepsResolver(DepsResolver):
         # array build for the NEXT tick's launch. Registrations land in the
         # arena before _encode_plan cuts each plan's field-granular delta
         # upload, so batchmates still witness each other.
-        ts = node_ts(node) if REC.enabled else 0
-        t0 = _time.perf_counter()
-        items = self._drain_and_preaccept(node)
-        self._adapt(node, len(items))
-        plans = [self._stage(node, sub) for sub in self._slices(items)]
-        dt = _time.perf_counter() - t0
-        hidden = bool(self._inflight.get(id(node)))
+        with self._phase("resolver.tick", node=node, track="stage_host",
+                         event="stage_host") as ph:
+            items = self._drain_and_preaccept(node)
+            self._adapt(node, len(items))
+            plans = [self._stage(node, sub) for sub in self._slices(items)]
+            hidden = bool(self._inflight.get(id(node)))
+            ph.args = {"hidden": hidden, "items": len(items)}
         if hidden:
-            self.host_hidden_s += dt
-        if REC.enabled:
-            # dur mirrors the exact host_hidden_s contribution above, so a
-            # trace-side hidden-share computation reconciles with the
-            # registry's host_hidden_pct (asserted by bench_e2e --trace)
-            REC.complete(node_pid(node), "stage_host", "stage_host", ts,
-                         dur=round(dt * 1e6, 3),
-                         args={"hidden": hidden, "items": len(items)})
+            # the span's dur is this exact contribution, so a trace-side
+            # hidden-share computation reconciles with host_hidden_pct
+            self.host_hidden_s += ph.dt
         if plans:
             self._staged[id(node)] = plans
             self._arm_tick(node)
@@ -2174,18 +2195,17 @@ class BatchDepsResolver(DepsResolver):
         each other (deps may be any conservative superset; execution still
         orders by executeAt). A preaccept that raises fails ONLY its own
         AsyncResult -- the rest of the batch, and the pipeline, proceed."""
-        import time as _time
         from accord_tpu.local import commands
         from accord_tpu.local.commands import AcceptOutcome
         pa = self._pa_queues.pop(id(node), [])
         dq = self._deps_queues.pop(id(node), [])
         items: List[_Item] = []
-        t0 = _time.perf_counter()
 
         def _finish(store, t, p, out, outcome):
             if outcome in (AcceptOutcome.REJECTED_BALLOT,
                            AcceptOutcome.TRUNCATED):
                 out.try_set_success((outcome, None, None))
+                self._occ.deliver()
                 return
             items.append(_Item(store, t, store.owned(p.keys),
                                store.command(t).execute_at, out, outcome))
@@ -2195,73 +2215,71 @@ class BatchDepsResolver(DepsResolver):
                 outcome = commands.preaccept(store, t, p, route, ballot)
             except BaseException as e:  # noqa: BLE001
                 out.try_set_failure(e)
+                self._occ.deliver()
                 return
             _finish(store, t, p, out, outcome)
 
-        # contiguous same-store spans route through the device command
-        # arena as ONE cmd_tick dispatch (synchronous within the drain, so
-        # timing -- and thus histories -- stay bit-identical to the host
-        # loop); stores without a plane keep the inline path
-        i = 0
-        while i < len(pa):
-            store = pa[i][0]
-            plane = getattr(store, "cmd_plane", None)
-            if plane is None:
-                _host_one(*pa[i])
-                i += 1
-                continue
-            j = i
-            while j < len(pa) and pa[j][0] is store:
-                j += 1
-            batch = pa[i:j]
-            td = self.tick_driver
-            try:
-                from accord_tpu.ops.cmd_plane import CmdOp
-                cmd_ops = [CmdOp.preaccept(t, p, route, ballot)
-                           for (_s, t, p, route, ballot, _o) in batch]
-                if td is not None and getattr(td, "cmd_defer", False):
-                    # megakernel mode: decide the span with the host twin
-                    # now and ride the device transition lanes into the
-                    # tick's single fused dispatch (the quorum stage); on
-                    # the device-messages path the span's shadow writes
-                    # also fold back in-kernel as a repair scatter instead
-                    # of a later standalone flush
-                    fuse = (getattr(td, "note_cmd_defer", None)
-                            if getattr(td, "device_messages", False)
-                            else None)
-                    res = plane.defer_batch(cmd_ops,
-                                            sink=td.note_cmd_lanes,
-                                            fuse=fuse)
+        with self._phase("resolver.preaccept", "resolver.preaccept_s",
+                         node=node, track="stage_host", event="preaccept",
+                         batch=len(pa)):
+            # contiguous same-store spans route through the device command
+            # arena as ONE cmd_tick dispatch (synchronous within the drain, so
+            # timing -- and thus histories -- stay bit-identical to the host
+            # loop); stores without a plane keep the inline path
+            i = 0
+            while i < len(pa):
+                store = pa[i][0]
+                plane = getattr(store, "cmd_plane", None)
+                if plane is None:
+                    _host_one(*pa[i])
+                    i += 1
+                    continue
+                j = i
+                while j < len(pa) and pa[j][0] is store:
+                    j += 1
+                batch = pa[i:j]
+                td = self.tick_driver
+                try:
+                    from accord_tpu.ops.cmd_plane import CmdOp
+                    cmd_ops = [CmdOp.preaccept(t, p, route, ballot)
+                               for (_s, t, p, route, ballot, _o) in batch]
+                    if td is not None and getattr(td, "cmd_defer", False):
+                        # megakernel mode: decide the span with the host twin
+                        # now and ride the device transition lanes into the
+                        # tick's single fused dispatch (the quorum stage); on
+                        # the device-messages path the span's shadow writes
+                        # also fold back in-kernel as a repair scatter instead
+                        # of a later standalone flush
+                        fuse = (getattr(td, "note_cmd_defer", None)
+                                if getattr(td, "device_messages", False)
+                                else None)
+                        res = plane.defer_batch(cmd_ops,
+                                                sink=td.note_cmd_lanes,
+                                                fuse=fuse)
+                    else:
+                        d0 = int(plane.dispatches)
+                        res = plane.eval_batch(cmd_ops)
+                        if td is not None:
+                            td.note_cmd_dispatches(int(plane.dispatches) - d0)
+                except Exception as e:  # noqa: BLE001 -- degrade, and say so
+                    # the plane answers ops it cannot decide itself (counted in
+                    # cmd_plane_fallbacks); reaching here means it FAILED -- a
+                    # program the compiler refused, a launch error, a handler
+                    # bug. The span replays through the Python handlers so the
+                    # node keeps its guarantees, but never silently
+                    self.cmd_span_replays += 1
+                    if self.cmd_span_replays == 1:
+                        logger.error(
+                            "cmd plane failed on a %d-op PreAccept span; "
+                            "replaying it (and counting later ones in "
+                            "resolver.cmd_span_replays) through the host "
+                            "handlers", len(batch), exc_info=e)
+                    for entry in batch:
+                        _host_one(*entry)
                 else:
-                    d0 = int(plane.dispatches)
-                    res = plane.eval_batch(cmd_ops)
-                    if td is not None:
-                        td.note_cmd_dispatches(int(plane.dispatches) - d0)
-            except Exception as e:  # noqa: BLE001 -- degrade, and say so
-                # the plane answers ops it cannot decide itself (counted in
-                # cmd_plane_fallbacks); reaching here means it FAILED -- a
-                # program the compiler refused, a launch error, a handler
-                # bug. The span replays through the Python handlers so the
-                # node keeps its guarantees, but never silently
-                self.cmd_span_replays += 1
-                if self.cmd_span_replays == 1:
-                    logger.error(
-                        "cmd plane failed on a %d-op PreAccept span; "
-                        "replaying it (and counting later ones in "
-                        "resolver.cmd_span_replays) through the host "
-                        "handlers", len(batch), exc_info=e)
-                for entry in batch:
-                    _host_one(*entry)
-            else:
-                for (st_, t, p, _route, _ballot, out), r in zip(batch, res):
-                    _finish(st_, t, p, out, r.outcome)
-            i = j
-        dt = _time.perf_counter() - t0
-        self.preaccept_s += dt
-        if REC.enabled:
-            REC.complete(node_pid(node), "stage_host", "preaccept",
-                         node_ts(node), dur=round(dt * 1e6, 3),
-                         args={"batch": len(pa)})
+                    for (st_, t, p, _r, _b, out), r in zip(batch, res):
+                        _finish(st_, t, p, out, r.outcome)
+                i = j
         for (store, t, ks, before, out) in dq:
             items.append(_Item(store, t, store.owned(ks), before, out))
         if items:
@@ -3031,17 +3049,41 @@ class BatchDepsResolver(DepsResolver):
         (_Call for the raw candidate buffers, _Group for the finalized CSR
         triples) and timed into readback_s -- the finalized path skips the
         eager raw-buffer readback; fallbacks pay only for what they touch."""
-        import time as _time
         cached = getattr(holder, attr)
         if cached is not None:
             return cached
         if dev is None:
             return None
-        t0 = _time.perf_counter()
-        val = _dev_read(dev)
-        self.readback_s += _time.perf_counter() - t0
+        val = self._read(dev)
         setattr(holder, attr, val)
         return val
+
+    def _read(self, dev):
+        """Blocking device->host read of one result (an array or a finalize
+        tuple), split where the host's two waits differ: for the kernels to
+        finish (device_wait_s), then for the copy that follows
+        (transfer_s). readback_s stays their sum; readback_bytes counts
+        what reached the host."""
+        import jax
+        with self._phase("resolver.device_wait",
+                         "resolver.device_wait_s") as wait:
+            jax.block_until_ready(dev)
+        with self._phase("resolver.transfer", "resolver.transfer_s") as copy:
+            val = _dev_read(dev)
+        self.readback_s += wait.dt + copy.dt
+        self.readback_bytes += sum(
+            a.nbytes for a in (val if isinstance(val, tuple) else (val,)))
+        return val
+
+    def _observe_bound(self, arena, lane: str, dbound, n: int):
+        """Fold the device-computed bound that rode back with a finalize
+        result into the lane's out-cap policy, so the NEXT dispatch's tier
+        needs no host O(keys) popcount pass. Returns the policy."""
+        with self._phase("resolver.bound_readback",
+                         "resolver.bound_readback_s"):
+            pol = self._outcap(arena, lane)
+            pol.observe(int(dbound), n)
+        return pol
 
     def _materialize_finalized(self, call: _Call, g: _Group):
         """Slice-and-wrap: one store's key-domain deps straight from the
@@ -3063,16 +3105,9 @@ class BatchDepsResolver(DepsResolver):
             return None     # kernel never launched (defensive)
         if not self._csum_ok(call, g, buf):
             return None     # corrupted readback: caught before decode
-        import time as _time
         indptr, dep_rows, _, dbound, _ = buf
         ns = len(flat_key)
-        # the device-computed bound rode back with the CSR: fold it into
-        # the out-cap policy so the NEXT dispatch's tier needs no host
-        # O(keys) popcount pass
-        t0 = _time.perf_counter()
-        pol = self._outcap(arena, "key")
-        pol.observe(int(dbound), ns)
-        self.bound_readback_s += _time.perf_counter() - t0
+        pol = self._observe_bound(arena, "key", dbound, ns)
         if call is not None and call.overflow_pending:
             # injected out-cap overflow storm: report the overflow signal
             # without shrinking/garbling anything -- the policy bumps its
@@ -3125,12 +3160,9 @@ class BatchDepsResolver(DepsResolver):
         buf = self._fetch_np(g, "rfin_np", g.rfin_dev)
         if not self._csum_ok(call, g, buf):
             return None     # corrupted readback: caught before decode
-        import time as _time
         indptr, dep_rows, _, dbound, _ = buf
-        t0 = _time.perf_counter()
-        pol = self._outcap(g.arena, "range")
-        pol.observe(int(dbound), max(len(g.rents), 1))
-        self.bound_readback_s += _time.perf_counter() - t0
+        pol = self._observe_bound(g.arena, "range", dbound,
+                                  max(len(g.rents), 1))
         if int(indptr[-1]) > dep_rows.shape[0]:
             # defensively bump the pinned tier (the stab-count bound is a
             # true superset of the compaction, so only a mid-flight rseq
@@ -3227,13 +3259,9 @@ class BatchDepsResolver(DepsResolver):
         buf = self._fetch_np(g, "rkfin_np", g.rkfin_dev)
         if not self._csum_ok(call, g, buf):
             return None     # corrupted readback: caught before decode
-        import time as _time
         indptr, dep_rows, _, dbound, _ = buf
         ns = len(g.rk_slots)
-        t0 = _time.perf_counter()
-        pol = self._outcap(g.arena, "rkey")
-        pol.observe(int(dbound), ns)
-        self.bound_readback_s += _time.perf_counter() - t0
+        pol = self._observe_bound(g.arena, "rkey", dbound, ns)
         if int(indptr[ns]) > dep_rows.shape[0]:
             pol.overflowed()
             return None
@@ -3544,7 +3572,6 @@ class BatchDepsResolver(DepsResolver):
         cut its plan (upload arrays + snapshots + plan-time generation
         pins). The plan launches now (serial mode) or on the next tick's
         stage_dispatch (overlap mode)."""
-        import time as _time
         # ensure adoption of late-attached stores BEFORE snapshotting group
         # generations -- adoption may mutate (and compact) an arena
         for item in items:
@@ -3577,22 +3604,15 @@ class BatchDepsResolver(DepsResolver):
             # yet): an empty call still flows through the pipeline so floors
             # and fallbacks are injected at harvest
             return _Plan(items, groups, empty=True)
-        t0 = _time.perf_counter()
-        plan = self._encode_plan(groups, items)
-        dt = _time.perf_counter() - t0
-        self.encode_s += dt
-        if REC.enabled:
-            REC.complete(node_pid(node), "stage_host", "encode",
-                         node_ts(node), dur=round(dt * 1e6, 3),
-                         args={"subjects": len(items),
-                               "stores": len(groups)})
-        return plan
+        with self._phase("resolver.encode", "resolver.encode_s", node=node,
+                         track="stage_host", event="encode",
+                         subjects=len(items), stores=len(groups)):
+            return self._encode_plan(groups, items)
 
     def _launch(self, node, plan: _Plan, staged: bool = False) -> None:
         """stage_dispatch: fire a plan's kernels (generation pins were
         already taken at plan time, matched by unpin_gen in _harvest),
         enqueue the async readback, and schedule the harvest."""
-        import time as _time
         did = self.dispatches  # monotone per resolver: the trace span key
         if plan.empty:
             call = _Call(None, None, None, plan.items, plan.groups, did=did)
@@ -3626,14 +3646,16 @@ class BatchDepsResolver(DepsResolver):
                 call.faulted = True
                 self.degraded_dispatches += 1
             else:
-                t0 = _time.perf_counter()
-                packed, rpacked, kpacked = self._run_plan(plan)
-                call = _Call(packed, rpacked, kpacked, plan.items,
-                             plan.groups, plan.want, did=did)
-                for _, _, dev in call.buffers():
-                    _dev_copy_async(dev)
-                dt = _time.perf_counter() - t0
-                self.dispatch_s += dt
+                with self._phase("resolver.launch", "resolver.dispatch_s",
+                                 node=node, track="device", event="launch",
+                                 did=did):
+                    packed, rpacked, kpacked = self._run_plan(plan)
+                    call = _Call(packed, rpacked, kpacked, plan.items,
+                                 plan.groups, plan.want, did=did)
+                    for _, _, dev in call.buffers():
+                        _dev_copy_async(dev)
+                if call.has_device:
+                    self._occ.launched()
                 if fault == "stuck":
                     plane.note("stuck")
                     self.device_faults_injected += 1
@@ -3650,10 +3672,6 @@ class BatchDepsResolver(DepsResolver):
                 health = self._health.get(id(node))
                 if health is not None and health.wants_canary:
                     call.canary = True
-                if REC.enabled:
-                    REC.complete(node_pid(node), "device", "launch",
-                                 node_ts(node), dur=round(dt * 1e6, 3),
-                                 args={"did": did})
         self.dispatches += 1
         if staged:
             self.staged_dispatches += 1
@@ -3726,9 +3744,10 @@ class BatchDepsResolver(DepsResolver):
                     if not _dev_ready(dev):
                         done = False
                         break
-                    setattr(holder, attr, _dev_read(dev))
+                    setattr(holder, attr, self._read(dev))
                 if not done:
                     break  # single device stream: later calls finish later
+                self._land(call)
             if q:
                 return True
             self._polling.discard(id(node))
@@ -3737,11 +3756,32 @@ class BatchDepsResolver(DepsResolver):
         poll(interval, prefetch)
 
     def _harvest(self, node) -> None:
-        import time as _time
         q = self._inflight.get(id(node))
         if not q:
             return  # defensive: every dispatch schedules exactly one harvest
         call = q.popleft()
+        with self._phase("resolver.harvest", node=node, did=call.did):
+            results = self._collect(node, call, hidden=bool(q))
+        for item, deps in zip(call.items, results):
+            if item.outcome is not None:
+                item.out.try_set_success((item.outcome, item.before, deps))
+            else:
+                item.out.try_set_success(deps)
+        self._occ.deliver(len(call.items))
+
+    def _land(self, call: _Call) -> None:
+        """The device holds nothing of this call any more: its results are
+        on the host (harvest fetch, or the poll drained them early) or it
+        was given up on."""
+        if call.has_device and not call.landed:
+            call.landed = True
+            self._occ.landed()
+
+    def _collect(self, node, call: _Call, hidden: bool) -> List[Deps]:
+        """The harvest's work on one call: wait for the device and fetch
+        what the poll left (device_wait + transfer), then decode
+        (materialize). `hidden`: calls are still in flight behind this
+        one, so the decode runs inside their device window."""
         stalled = False
         if call.has_device and call.stuck_left:
             # harvest watchdog, deterministic half: an injected stuck call
@@ -3763,10 +3803,9 @@ class BatchDepsResolver(DepsResolver):
                     REC.instant(node_pid(node), "device", "watchdog_trip",
                                 node_ts(node), args={"did": call.did})
         if call.has_device and not call.degraded:
-            t0 = _time.perf_counter()
-            stalled = call.fetch()
-            ft = _time.perf_counter() - t0
-            self.readback_s += ft
+            rb0 = self.readback_s
+            stalled = call.fetch(self._read)
+            ft = self.readback_s - rb0
             if stalled:
                 self.harvest_stall_s += ft
             else:
@@ -3784,46 +3823,38 @@ class BatchDepsResolver(DepsResolver):
                 if fault_plane.ACTIVE is not None:
                     self._apply_corruption(call, fault_plane.ACTIVE)
                 call.corrupt_pending = False
+        self._land(call)
         if REC.enabled:
             REC.async_end(node_pid(node), "device", "window",
                           f"d{call.did}", node_ts(node), local=True,
                           args={"stalled": stalled})
-        t0 = _time.perf_counter()
-        if any((g.pk is not None and g.gen != g.arena.gen)
-               or (g.rp is not None and g.rgen != g.arena.ranges.gen)
-               for g in call.groups):
-            self.stale_harvests += 1
-        rb0 = self.readback_s
-        results = self._decode_dispatch(call)
-        for g in call.groups:
-            if g.pinned:
-                g.arena.unpin_gen(g.gen)
-            if g.rpinned:
-                g.arena.ranges.unpin_gen(g.rgen)
-        dt = _time.perf_counter() - t0
-        self.decode_s += dt
+        with self._phase("resolver.materialize", "resolver.decode_s",
+                         node=node, track="device", event="decode",
+                         did=call.did) as ph:
+            ph.args = {"hidden": hidden, "did": call.did}
+            if any((g.pk is not None and g.gen != g.arena.gen)
+                   or (g.rp is not None and g.rgen != g.arena.ranges.gen)
+                   for g in call.groups):
+                self.stale_harvests += 1
+            rb0 = self.readback_s
+            results = self._decode_dispatch(call)
+            for g in call.groups:
+                if g.pinned:
+                    g.arena.unpin_gen(g.gen)
+                if g.rpinned:
+                    g.arena.ranges.unpin_gen(g.rgen)
         # lazy fallback fetches inside the decode were timed into readback_s;
         # what's left is pure host materialization
-        self.materialize_s += dt - (self.readback_s - rb0)
-        if q:
-            # calls still in flight behind this one: stage_decode ran
-            # inside their device window
-            self.host_hidden_s += dt
-        if REC.enabled:
-            REC.complete(node_pid(node), "device", "decode", node_ts(node),
-                         dur=round(dt * 1e6, 3),
-                         args={"hidden": bool(q), "did": call.did})
+        self.materialize_s += ph.dt - (self.readback_s - rb0)
+        if hidden:
+            self.host_hidden_s += ph.dt
         health = self._health.get(id(node))
         if health is not None and call.has_device and not call.degraded \
                 and not call.faulted:
             # a fully clean device harvest walks DEGRADED back toward
             # HEALTHY (and counts probation canaries via _canary_check)
             health.on_clean_dispatch()
-        for item, deps in zip(call.items, results):
-            if item.outcome is not None:
-                item.out.try_set_success((item.outcome, item.before, deps))
-            else:
-                item.out.try_set_success(deps)
+        return results
 
     # -- synchronous SPI (tests, rare recovery-path callers) ------------------
     def resolve_one(self, store, txn_id, seekables, before) -> Deps:
@@ -3854,7 +3885,7 @@ class BatchDepsResolver(DepsResolver):
             plan = self._encode_plan([g], items, pin=False)
             packed, rpacked, kpacked = self._run_plan(plan)
             call = _Call(packed, rpacked, kpacked, items, [g], plan.want)
-            call.fetch()
+            call.fetch(self._read)
         return self._decode_core(call)
 
     # -- max-conflict (device path; inline mode + bench only) ----------------
@@ -3984,14 +4015,11 @@ class ShardedBatchDepsResolver(BatchDepsResolver):
         # plus each shard's write base, and a psum gather-merges the
         # disjoint dep_rows fragments -- no chip ever materializes the full
         # conflict matrix (lru_cached by mesh; launch time in shard_merge_s)
-        import time as _time
         from accord_tpu.parallel.mesh import sharded_finalize_csr
         kern = sharded_finalize_csr(self.mesh)
-        t0 = _time.perf_counter()
-        out = kern(packed, j_off, kid_rows, j_subj, j_kid, j_srow, act_ts,
-                   out_cap=out_cap)
-        self.shard_merge_s += _time.perf_counter() - t0
-        return out
+        with self._phase("resolver.shard_merge", "resolver.shard_merge_s"):
+            return kern(packed, j_off, kid_rows, j_subj, j_kid, j_srow,
+                        act_ts, out_cap=out_cap)
 
     def _run_range_kernel(self, rsnap, ksnap, iv_of, iv_s, iv_e,
                           sb, sknd, srng):
