@@ -4,9 +4,11 @@ Design notes (TPU-first):
   - The conflict test is a boolean matmul: bitmap[B,K] @ bitmap[A,K]^T on the
     MXU in bfloat16 with float32 accumulation. K (key buckets) is a multiple
     of 128 (lane width); B and A are padded to multiples of 8 (sublanes).
-  - Kind filtering is a gather from the 6x6 witness table; timestamp
-    comparison is lexicographic over two int32 lanes -- both VPU element-wise
-    ops XLA fuses into the matmul epilogue.
+  - Kind filtering is a per-subject bit mask of the 6x6 witness table ANDed
+    with a per-row kind bit (_witness_mask; never a gather over the
+    candidate matrix); timestamp comparison is lexicographic over three
+    int32 lanes -- both VPU element-wise ops XLA fuses into the matmul
+    epilogue.
   - Transitive closure is iterated boolean matmul (repeated squaring), the
     standard reachability-by-matmul formulation; log2(N) MXU rounds.
 All functions are jit-compiled with static shapes; callers pad to bucket
@@ -32,6 +34,37 @@ def _lex_before(a, b):
                   | ((a[..., 1] == b[..., 1]) & (a[..., 2] < b[..., 2])))))
 
 
+def _witness_mask(witness_table, subj_kinds, act_kinds):
+    """The witness relation table[kind_subject, kind_row] == 1 as
+    bool[B, A], without the lookup: each subject's table row becomes a bit
+    mask (bit j set iff table[kind, j] == 1), each active row one bit
+    (1 << kind), and a candidate costs one AND and one compare that fuse
+    with the masks around them. The gather this replaces was materialised
+    by XLA:TPU as an s32[B * A] array at ~7 ns an element: 119 ms of
+    deps_resolve's 178 ms a dispatch at 1,024 x 16,384 (ledger, PR 25).
+    Total like the indexing it replaces: a negative kind wraps once, then
+    clamps, so no shift count is out of range."""
+    n, m = witness_table.shape
+    assert m <= 32, "one uint32 of column bits per table row"
+
+    def norm(kinds, size):
+        return jnp.clip(jnp.where(kinds < 0, kinds + size, kinds),
+                        0, size - 1).astype(jnp.uint32)
+
+    one = jnp.uint32(1)
+    rowbits = jnp.sum(
+        jnp.where(witness_table == 1,
+                  one << jnp.arange(m, dtype=jnp.uint32)[None, :], 0),
+        axis=1, dtype=jnp.uint32)
+    wbits = jnp.sum(
+        jnp.where(norm(subj_kinds, n)[:, None]
+                  == jnp.arange(n, dtype=jnp.uint32)[None, :],
+                  rowbits[None, :], 0),
+        axis=1, dtype=jnp.uint32)
+    abit = one << norm(act_kinds, m)
+    return (wbits[:, None] & abit[None, :]) != 0
+
+
 @functools.partial(jax.jit, static_argnames=())
 def deps_matrix(subj_bitmaps, subj_before, subj_kinds,
                 act_bitmaps, act_ts, act_kinds, act_valid,
@@ -52,11 +85,15 @@ def deps_matrix(subj_bitmaps, subj_before, subj_kinds,
     -> bool[B, A] : dep[b, a] == True iff active txn a is a dependency of
                     subject b (keys overlap AND subject witnesses a's kind AND
                     a started before b's bound AND a != b).
+
+    The witness test is _witness_mask's bit mask and one AND, not a lookup
+    in the table at each candidate: that gather was 119 ms of deps_resolve's
+    178 ms a dispatch on the TPU v5e (ledger, PR 25).
     """
     overlap = jax.lax.dot_general(
         subj_bitmaps.astype(jnp.bfloat16), act_bitmaps.astype(jnp.bfloat16),
         (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) > 0.5
-    witness = witness_table[subj_kinds[:, None], act_kinds[None, :]] == 1
+    witness = _witness_mask(witness_table, subj_kinds, act_kinds)
     before = _lex_before(act_ts[None, :, :], subj_before[:, None, :])
     return overlap & witness & before & act_valid[None, :]
 
@@ -289,6 +326,11 @@ def deps_resolve(subj_of, subj_keys, subj_before, subj_kinds,
     act_*:       the device arena (see resolver._StoreArena); cap % 32 == 0
     -> u32[B, cap/32] packed dependency bitmask, little-bit-first per lane
 
+    The witness test is _witness_mask's per-subject bit mask ANDed with a
+    per-row kind bit: the table lookup at each of B x cap candidates it
+    replaces was 119 ms of this kernel's 178 ms a dispatch on the TPU v5e
+    (ledger, PR 25), and the AND fuses into the contraction's epilogue.
+
     The stages carry jax.named_scope names (metadata only: the operations'
     `tf_op` path in a profiler trace), as do finalize_csr's.
     """
@@ -303,7 +345,7 @@ def deps_resolve(subj_of, subj_keys, subj_before, subj_kinds,
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) > 0.5
     with jax.named_scope("witness_before_mask"):
-        witness = witness_table[subj_kinds[:, None], act_kinds[None, :]] == 1
+        witness = _witness_mask(witness_table, subj_kinds, act_kinds)
         before = _lex_before(act_ts[None, :, :], subj_before[:, None, :])
         m = overlap & witness & before & act_valid[None, :]
     return _pack_bits(m)
@@ -341,7 +383,7 @@ def fused_deps_resolve(subj_of, subj_keys, subj_store, subj_before,
             subj_bm, act_bm.astype(jnp.bfloat16),
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) > 0.5
-        witness = witness_table[subj_kinds[:, None], act_kinds[None, :]] == 1
+        witness = _witness_mask(witness_table, subj_kinds, act_kinds)
         before = _lex_before(act_ts[None, :, :], subj_before[:, None, :])
         mine = (subj_store == slots[s])[:, None]
         outs.append(_pack_bits(
@@ -394,7 +436,7 @@ def fused_range_deps_resolve(iv_of, iv_start, iv_end, subj_store,
             & (r_start[None, :] < iv_end[:, None])
         any_r = jnp.zeros((b, rcap), jnp.int32) \
             .at[iv_of].max(hit_r.astype(jnp.int32), mode="drop") > 0
-        witness_r = witness_table[subj_kinds[:, None], r_kinds[None, :]] == 1
+        witness_r = _witness_mask(witness_table, subj_kinds, r_kinds)
         before_r = _lex_before(r_ts[None, :, :], subj_before[:, None, :])
         mine = (subj_store == r_slots[s])[:, None]
         routs.append(_pack_bits(
@@ -407,7 +449,7 @@ def fused_range_deps_resolve(iv_of, iv_start, iv_end, subj_store,
         any_k = jax.lax.dot_general(
             cov, k_bm.astype(jnp.bfloat16),
             (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) > 0.5
-        witness_k = witness_table[subj_kinds[:, None], k_kinds[None, :]] == 1
+        witness_k = _witness_mask(witness_table, subj_kinds, k_kinds)
         before_k = _lex_before(k_ts[None, :, :], subj_before[:, None, :])
         mine = (subj_store == k_slots[s])[:, None] & subj_is_range[:, None]
         kouts.append(_pack_bits(
@@ -464,14 +506,14 @@ def range_deps_resolve(iv_of, iv_start, iv_end, subj_before, subj_kinds,
         & (r_start[None, :] < iv_end[:, None])
     any_r = jnp.zeros((b, rcap), jnp.int32) \
         .at[iv_of].max(hit_r.astype(jnp.int32), mode="drop") > 0
-    witness_r = witness_table[subj_kinds[:, None], r_kinds[None, :]] == 1
+    witness_r = _witness_mask(witness_table, subj_kinds, r_kinds)
     before_r = _lex_before(r_ts[None, :, :], subj_before[:, None, :])
     m_r = any_r & witness_r & before_r & r_valid[None, :]
     cov = covered_buckets(iv_of, iv_start, iv_end, b, k, 0, k)
     any_k = jax.lax.dot_general(
         cov, k_bm.astype(jnp.bfloat16),
         (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) > 0.5
-    witness_k = witness_table[subj_kinds[:, None], k_kinds[None, :]] == 1
+    witness_k = _witness_mask(witness_table, subj_kinds, k_kinds)
     before_k = _lex_before(k_ts[None, :, :], subj_before[:, None, :])
     m_k = any_k & witness_k & before_k & k_valid[None, :] \
         & subj_is_range[:, None]
@@ -827,7 +869,7 @@ def _range_finalize_csr_body(iv_of, iv_start, iv_end, ent_ok,
         & (r_start[None, :] < iv_end[:, None])
     stab = hit & r_valid[None, :] & inb[:, None]
     bound = jnp.sum(stab.astype(jnp.int32), dtype=jnp.int32)
-    witness = witness_table[subj_kinds[o][:, None], r_kinds[None, :]] == 1
+    witness = _witness_mask(witness_table, subj_kinds[o], r_kinds)
     before = _lex_before(r_ts[None, :, :], subj_before[o][:, None, :])
     m = stab & witness & before
     indptr, dep_rows = _segment_compact(m.astype(jnp.int32), out_cap)

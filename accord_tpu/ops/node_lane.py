@@ -53,8 +53,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from accord_tpu.ops.kernels import (_lex_before, _pack_bits, covered_buckets,
-                                    nnz_tier)
+from accord_tpu.ops.kernels import (_lex_before, _pack_bits, _witness_mask,
+                                    covered_buckets, nnz_tier)
 from accord_tpu.ops.tiers import snap
 
 # Merged subject-row ladder: a cluster tick at N nodes stacks up to
@@ -121,7 +121,7 @@ def _key_resolve_body(subj_of, subj_keys, subj_node, subj_before,
             subj_bm, act_bm.astype(jnp.bfloat16),
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) > 0.5
-        witness = witness_table[subj_kinds[:, None], act_kinds[None, :]] == 1
+        witness = _witness_mask(witness_table, subj_kinds, act_kinds)
         before = _lex_before(act_ts[None, :, :], subj_before[:, None, :])
         mine = (subj_node == slots[s])[:, None]
         outs.append(_pack_bits(
@@ -161,7 +161,7 @@ def _range_resolve_body(iv_of, iv_start, iv_end, subj_node,
             & (r_start[None, :] < iv_end[:, None])
         any_r = jnp.zeros((b, rcap), jnp.int32) \
             .at[iv_of].max(hit_r.astype(jnp.int32), mode="drop") > 0
-        witness_r = witness_table[subj_kinds[:, None], r_kinds[None, :]] == 1
+        witness_r = _witness_mask(witness_table, subj_kinds, r_kinds)
         before_r = _lex_before(r_ts[None, :, :], subj_before[:, None, :])
         mine = (subj_node == r_slots[s])[:, None]
         routs.append(_pack_bits(
@@ -174,7 +174,7 @@ def _range_resolve_body(iv_of, iv_start, iv_end, subj_node,
         any_k = jax.lax.dot_general(
             cov, k_bm.astype(jnp.bfloat16),
             (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) > 0.5
-        witness_k = witness_table[subj_kinds[:, None], k_kinds[None, :]] == 1
+        witness_k = _witness_mask(witness_table, subj_kinds, k_kinds)
         before_k = _lex_before(k_ts[None, :, :], subj_before[:, None, :])
         mine = (subj_node == k_slots[s])[:, None] & subj_is_range[:, None]
         kouts.append(_pack_bits(

@@ -97,6 +97,7 @@ def sharded_deps_step(mesh: Mesh, closure_iters: int = 8):
     Sharding: rows over 'data'; the K contraction over 'model' via psum;
     closure all-gathers row blocks per squaring round.
     """
+    from accord_tpu.ops.kernels import _witness_mask
 
     def step(bitmaps, ts, kinds, table):
         # ---- deps matrix: rows sharded, K sharded, psum over 'model' ----
@@ -106,7 +107,7 @@ def sharded_deps_step(mesh: Mesh, closure_iters: int = 8):
                 bm_rows.astype(jnp.bfloat16), bm_all.astype(jnp.bfloat16),
                 (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
             overlap = jax.lax.psum(partial, "model") > 0.5
-            witness = tbl[kinds_rows[:, None], kinds_all[None, :]] == 1
+            witness = _witness_mask(tbl, kinds_rows, kinds_all)
             a, b = ts_all[None, :, :], ts_rows[:, None, :]
             before = ((a[..., 0] < b[..., 0])
                       | ((a[..., 0] == b[..., 0])
@@ -174,7 +175,8 @@ def sharded_deps_resolve(mesh: Mesh):
 
     Contracts (enforced by ShardedBatchDepsResolver): cap % (32 * data) == 0
     and num_buckets % model == 0 -- both preserved by arena doubling."""
-    from accord_tpu.ops.kernels import _lex_before, _pack_bits
+    from accord_tpu.ops.kernels import (_lex_before, _pack_bits,
+                                        _witness_mask)
 
     def run(subj_of, subj_keys, subj_before, subj_kinds,
             act_bm, act_ts, act_kinds, act_valid, table):
@@ -195,7 +197,7 @@ def sharded_deps_resolve(mesh: Mesh):
                 subj_bm, bm.astype(jnp.bfloat16),
                 (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
             overlap = jax.lax.psum(partial, "model") > 0.5
-            witness = tbl[sknd[:, None], kinds[None, :]] == 1
+            witness = _witness_mask(tbl, sknd, kinds)
             before = _lex_before(ts[None, :, :], sb[:, None, :])
             return _pack_bits(overlap & witness & before & valid[None, :])
 
@@ -265,7 +267,8 @@ def sharded_range_deps_resolve(mesh: Mesh):
     doubling). Bucket coverage is a conservative superset of the true key
     overlap; the host decode re-filters per real key, so single-device and
     sharded answers stay differentially identical."""
-    from accord_tpu.ops.kernels import _lex_before, _pack_bits
+    from accord_tpu.ops.kernels import (_lex_before, _pack_bits,
+                                        _witness_mask)
     model = mesh.shape["model"]
 
     def run(iv_of, iv_start, iv_end, subj_before, subj_kinds, subj_is_range,
@@ -278,7 +281,7 @@ def sharded_range_deps_resolve(mesh: Mesh):
             hit_r = (ivs[:, None] < re_[None, :]) & (rs[None, :] < ive[:, None])
             any_r = jnp.zeros((b, rcap_l), jnp.int32) \
                 .at[ivo].max(hit_r.astype(jnp.int32), mode="drop") > 0
-            witness_r = tbl[sknd[:, None], rkd[None, :]] == 1
+            witness_r = _witness_mask(tbl, sknd, rkd)
             before_r = _lex_before(rts[None, :, :], sb[:, None, :])
             m_r = any_r & witness_r & before_r & rvl[None, :]
             cov = _covered_buckets(ivo, ivs, ive, b, bm.shape[1], model)
@@ -286,7 +289,7 @@ def sharded_range_deps_resolve(mesh: Mesh):
                 cov, bm.astype(jnp.bfloat16),
                 (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
             any_k = jax.lax.psum(partial, "model") > 0.5
-            witness_k = tbl[sknd[:, None], kknd[None, :]] == 1
+            witness_k = _witness_mask(tbl, sknd, kknd)
             before_k = _lex_before(kts[None, :, :], sb[:, None, :])
             m_k = any_k & witness_k & before_k & kvl[None, :] & srng[:, None]
             return _pack_bits(m_r), _pack_bits(m_k)
@@ -330,7 +333,8 @@ def _fused_key_resolve_blocks(nstores, sof, sk, sst, sb, sknd, sl, ars, tbl):
     subject bitmap is built once per shard restricted to the local bucket
     slice; each arena block applies its store's slot mask and packs its own
     lane block."""
-    from accord_tpu.ops.kernels import _lex_before, _pack_bits
+    from accord_tpu.ops.kernels import (_lex_before, _pack_bits,
+                                        _witness_mask)
     b = sb.shape[0]
     k_local = ars[0][0].shape[1]
     base = jax.lax.axis_index("model") * k_local
@@ -346,7 +350,7 @@ def _fused_key_resolve_blocks(nstores, sof, sk, sst, sb, sknd, sl, ars, tbl):
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         overlap = jax.lax.psum(partial, "model") > 0.5
-        witness = tbl[sknd[:, None], kinds[None, :]] == 1
+        witness = _witness_mask(tbl, sknd, kinds)
         before = _lex_before(ts[None, :, :], sb[:, None, :])
         mine = (sst == sl[s])[:, None]
         outs.append(_pack_bits(
@@ -360,7 +364,8 @@ def _fused_range_resolve_blocks(nr, nk, model, ivo, ivs, ive, sst, sb, sknd,
     list), shared like _fused_key_resolve_blocks. NR range arenas answer
     the interval stab over their 'data' row blocks; NK key arenas contract
     the subject intervals' bucket coverage over 'model'."""
-    from accord_tpu.ops.kernels import _lex_before, _pack_bits
+    from accord_tpu.ops.kernels import (_lex_before, _pack_bits,
+                                        _witness_mask)
     b = sb.shape[0]
     routs = []
     for s in range(nr):
@@ -370,7 +375,7 @@ def _fused_range_resolve_blocks(nr, nk, model, ivo, ivs, ive, sst, sb, sknd,
             & (rs[None, :] < ive[:, None])
         any_r = jnp.zeros((b, rcap_l), jnp.int32) \
             .at[ivo].max(hit_r.astype(jnp.int32), mode="drop") > 0
-        witness_r = tbl[sknd[:, None], rkd[None, :]] == 1
+        witness_r = _witness_mask(tbl, sknd, rkd)
         before_r = _lex_before(rts[None, :, :], sb[:, None, :])
         mine = (sst == rsl[s])[:, None]
         routs.append(_pack_bits(
@@ -385,7 +390,7 @@ def _fused_range_resolve_blocks(nr, nk, model, ivo, ivs, ive, sst, sb, sknd,
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
             any_k = jax.lax.psum(partial, "model") > 0.5
-            witness_k = tbl[sknd[:, None], kknd[None, :]] == 1
+            witness_k = _witness_mask(tbl, sknd, kknd)
             before_k = _lex_before(kts[None, :, :], sb[:, None, :])
             mine = (sst == ksl[s])[:, None] & srng[:, None]
             kouts.append(_pack_bits(

@@ -333,6 +333,42 @@ def test_lowered_text_carries_the_scope_names(program, scopes):
     assert not missing, f"{program} lowered without scopes {missing}"
 
 
+def _lowered_resolve(program):
+    """One of the three single-device resolve programs, lowered (never
+    compiled) at the small shapes of `_kernel_args`."""
+    from accord_tpu.ops import kernels
+    resolve, _ = _kernel_args()
+    subj_of, subj_keys, before, s_kinds, act_bm, act_ts, a_kinds, valid, \
+        table = resolve
+    b = len(s_kinds)
+    if program == "deps_resolve":
+        return kernels.deps_resolve.lower(*resolve)
+    if program == "fused_deps_resolve":
+        arena = (act_bm, act_ts, a_kinds, valid)
+        return kernels.fused_deps_resolve.lower(
+            subj_of, subj_keys, np.arange(b, dtype=np.int32) % 2, before,
+            s_kinds, np.arange(2, dtype=np.int32), (arena, arena), table)
+    rcap = 32
+    ivs = np.arange(len(subj_of), dtype=np.int32)
+    r_start = np.arange(rcap, dtype=np.int32)
+    return kernels.range_deps_resolve.lower(
+        subj_of, ivs, ivs + 3, before, s_kinds, np.ones(b, bool),
+        r_start, r_start + 5, act_ts[:rcap], a_kinds[:rcap], valid[:rcap],
+        act_bm, act_ts, a_kinds, valid, table)
+
+
+@pytest.mark.parametrize("program", [
+    "deps_resolve", "fused_deps_resolve", "range_deps_resolve"])
+def test_lowered_resolve_holds_no_gather(program):
+    # the witness test is a kind bit mask and one AND (kernels._witness_mask):
+    # a table lookup at every candidate was 119 ms of 178 a dispatch on the
+    # chip (ledger, PR 25)
+    lowered = _lowered_resolve(program)
+    assert "gather" not in lowered.as_text()
+    if program == "deps_resolve":  # and the scope still holds the AND
+        assert "/witness_before_mask/and" in lowered.as_text(debug_info=True)
+
+
 def test_scope_names_change_no_answer(monkeypatch):
     import jax
     from accord_tpu.ops import kernels

@@ -60,6 +60,103 @@ def test_deps_matrix_vs_naive():
             assert got[b, a] == expect, (b, a)
 
 
+def _table_lookup(table, subj_kinds, act_kinds):
+    """The indexing `_witness_mask` replaces, in NumPy: a negative kind wraps
+    once (int32), then both clamp into the table."""
+    table = np.asarray(table)
+
+    def norm(kinds, size):
+        kinds = np.asarray(kinds, np.int32)
+        return np.clip(np.where(kinds < 0, kinds + np.int32(size), kinds),
+                       0, size - 1)
+
+    return table[norm(subj_kinds, table.shape[0])[:, None],
+                 norm(act_kinds, table.shape[1])[None, :]] == 1
+
+
+def _witness_case(name):
+    rng = np.random.default_rng(sum(name.encode()))
+    table = np.asarray(WITNESS_TABLE)
+    subj = rng.integers(0, 6, 24).astype(np.int32)
+    act = rng.integers(0, 6, 64).astype(np.int32)
+    if name == "all_36_pairs":
+        subj = act = np.arange(6, dtype=np.int32)
+    elif name.startswith("random_table"):
+        table = rng.integers(0, 2, (6, 6)).astype(np.int32)
+    elif name == "values_other_than_0_and_1":
+        table = rng.integers(-1, 3, (6, 6)).astype(np.int32)
+    elif name == "wide_table_bit_31":
+        table = rng.integers(0, 2, (4, 32)).astype(np.int32)
+        table[:, 31] = [1, 0, 1, 1]
+        subj = rng.integers(0, 4, 24).astype(np.int32)
+        act = np.concatenate([rng.integers(0, 32, 60), [31, 31, 30, 0]]) \
+            .astype(np.int32)
+    elif name == "arena_padding_rows_kind_0":
+        act[40:] = 0  # what resolver._StoreArena pads `kinds` with
+    elif name == "out_of_range_kinds":
+        wild = np.array([-2 ** 31, -2 ** 31 + 5, -13, -12, -7, -6, -5, -1, 0,
+                         5, 6, 7, 11, 12, 31, 32, 33, 100, 2 ** 31 - 1],
+                        np.int64).astype(np.int32)
+        subj = act = wild
+    else:
+        assert name == "witness_table_random_kinds"
+    return table, subj, act
+
+
+@pytest.mark.parametrize("name", [
+    "all_36_pairs", "witness_table_random_kinds", "random_table_0",
+    "random_table_1", "random_table_2", "random_table_3",
+    "values_other_than_0_and_1", "wide_table_bit_31",
+    "arena_padding_rows_kind_0", "out_of_range_kinds"])
+def test_witness_mask_is_the_table_lookup(name):
+    import jax
+    import jax.numpy as jnp
+    from accord_tpu.ops.kernels import _witness_mask
+    table, subj, act = _witness_case(name)
+    got = np.asarray(jax.jit(_witness_mask)(table, subj, act))
+    assert got.dtype == np.bool_ and got.shape == (len(subj), len(act))
+    np.testing.assert_array_equal(got, _table_lookup(table, subj, act))
+    # and the indexing itself, as the kernels wrote it until PR 26
+    indexed = jnp.asarray(table)[jnp.asarray(subj)[:, None],
+                                 jnp.asarray(act)[None, :]] == 1
+    np.testing.assert_array_equal(got, np.asarray(indexed))
+
+
+def test_witness_mask_refuses_a_table_wider_than_its_word():
+    from accord_tpu.ops.kernels import _witness_mask
+    with pytest.raises(AssertionError):
+        _witness_mask(np.zeros((2, 33), np.int32), np.zeros(1, np.int32),
+                      np.zeros(1, np.int32))
+
+
+@pytest.mark.parametrize("kinds", ["as_the_scope_test_has_them", "all_six"])
+def test_deps_resolve_vs_numpy_model_of_the_whole_mask(kinds):
+    from accord_tpu.ops.kernels import deps_resolve
+    from tests.test_obs_phases import _kernel_args
+    resolve, _ = _kernel_args(seed=5)
+    (subj_of, subj_keys, before, s_kinds, act_bm, act_ts, a_kinds, valid,
+     table) = resolve
+    if kinds == "all_six":
+        rng = np.random.default_rng(6)
+        s_kinds = rng.integers(0, 6, len(s_kinds)).astype(np.int32)
+        a_kinds = rng.integers(0, 6, len(a_kinds)).astype(np.int32)
+        a_kinds[-8:] = 0
+    b, cap = len(s_kinds), len(a_kinds)
+    bm = np.zeros((b, act_bm.shape[1]), bool)
+    bm[subj_of, subj_keys] = True  # _kernel_args draws no padding entry
+    overlap = (bm.astype(np.int64) @ act_bm.astype(np.int64).T) > 0
+    lex_before = np.array([[tuple(act_ts[a]) < tuple(before[i])
+                            for a in range(cap)] for i in range(b)])
+    m = overlap & _table_lookup(table, s_kinds, a_kinds) & lex_before \
+        & valid[None, :]
+    assert m.any() and not m.all()
+    want = (m.reshape(b, cap // 32, 32).astype(np.uint64)
+            << np.arange(32, dtype=np.uint64)).sum(axis=-1).astype(np.uint32)
+    got = deps_resolve(subj_of, subj_keys, before, s_kinds, act_bm, act_ts,
+                       a_kinds, valid, table)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
 def test_transitive_closure():
     import jax.numpy as jnp
     from accord_tpu.ops.kernels import transitive_closure
