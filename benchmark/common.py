@@ -8,6 +8,7 @@ from __future__ import annotations
 import json
 import math
 import shutil
+import time
 from pathlib import Path
 
 from benchmark import trace_reduce
@@ -53,6 +54,47 @@ class CompileMeter:
     def read(self):
         return {"requests": self.requests, "compile_s": self.seconds,
                 "cache_hits": self.cache_hits}
+
+
+class CollectorWatch:
+    """The garbage collector's runs inside the timed spans, by generation:
+    how many and their seconds. A context manager round each span, and
+    `on_collection` in `gc.callbacks`."""
+
+    def __init__(self):
+        self.timed = False
+        self.collections = [0, 0, 0]
+        self.seconds = [0.0, 0.0, 0.0]
+        self._t0 = None
+
+    def __enter__(self):
+        self.timed = True
+
+    def __exit__(self, *exc):
+        self.timed = False
+
+    def on_collection(self, phase, info):
+        if phase == "start":
+            self._t0 = time.perf_counter() if self.timed else None
+        elif self._t0 is not None:
+            g = info["generation"]
+            self.collections[g] += 1
+            self.seconds[g] += time.perf_counter() - self._t0
+            self._t0 = None
+
+    def read(self):
+        return {"collections": list(self.collections),
+                "seconds": list(self.seconds)}
+
+
+def counter_comparisons(counters):
+    """What `counter_faults` compares, each number beside its limit: the
+    gated counters summed against 0, the least device-work counter against
+    1."""
+    return {"gated_counters": [sum(counters.get(n, 0) for n in GATED_RESOLVER),
+                               0],
+            "device_work_min": [min(counters.get(n, 0) for n in RESOLVER_WORK),
+                                1]}
 
 
 def counter_faults(counters):
@@ -133,11 +175,16 @@ def stop_trace():
     jax.profiler.stop_trace()
 
 
-def window_span():
-    """The benchmark's own host span on the profiler's clock: trace_reduce
-    counts device time and gaps inside such spans only."""
+def host_span(name):
+    """A span of the benchmark's own on the profiler's clock (inert, well
+    under a microsecond, when no profiler session is open)."""
     import jax
-    return jax.profiler.TraceAnnotation(trace_reduce.WINDOW_SPAN)
+    return jax.profiler.TraceAnnotation(name)
+
+
+def window_span():
+    """trace_reduce counts device time and gaps inside such spans only."""
+    return host_span(trace_reduce.WINDOW_SPAN)
 
 
 def reduce_trace(fallback_window_s, dump_to=None):

@@ -106,6 +106,10 @@ def main(argv=None) -> int:
                                               "unit": m["unit"]}
     if args.rehearsal:
         line["rehearsal"] = True
+    # each number `correct` compared, beside its limit: last in the line,
+    # and the last lines of stderr
+    line["compared"] = {k: {"value": v, "limit": lim}
+                        for k, (v, lim) in out.get("compared", {}).items()}
     # what a reader of the run wants beside the contract's line; the last
     # line of stdout is the result
     print(json.dumps({"workload": args.workload, "seed": args.seed,
@@ -114,6 +118,10 @@ def main(argv=None) -> int:
                       "counters": counters, "notes": out.get("notes", {})},
                      default=str), flush=True)
     print(json.dumps(line), flush=True)
+    for k, c in line["compared"].items():
+        print(f"compared {k}: {c['value']} (limit {c['limit']})",
+              file=sys.stderr)
+    print(f"correct: {line['correct']}", file=sys.stderr, flush=True)
     return 0
 
 

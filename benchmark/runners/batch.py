@@ -11,9 +11,15 @@ time and the check between rounds is in none of it.
 from __future__ import annotations
 
 import contextlib
+import gc
 import time
 
 from benchmark import common
+
+ENQUEUE_SPAN = "bench.enqueue"  # the runner's enqueue loop, for gap labels
+# the program's timers whose change over each round goes into `notes`
+ROUND_TIMERS = {"round_wait_s": "resolver.device_wait_s",
+                "round_materialize_s": "resolver.materialize_s"}
 
 
 class Arena:
@@ -59,11 +65,11 @@ class Arena:
         """The plain reference: per key, the registered ids below the bound."""
         return {x for k in keys for x in self.by_key.get(k, ()) if x < bound}
 
-    def round(self, n, timed=None):
+    def round(self, n, timed=None, watch=None):
         """Draw n fresh subjects, resolve them (the timed part, inside
-        `timed()` where given), check every answer. Returns (resolve
-        seconds, cpu seconds, wrong answers, failed resolutions, deps
-        checked)."""
+        `timed()` where given, and inside `watch`, a common.CollectorWatch,
+        which counts the collector's work in it), check every answer. Returns (resolve seconds, cpu seconds, wrong answers, failed
+        resolutions, deps checked)."""
         subjects = [(t, self.store.owned(k), ts, raw)
                     for t, k, ts, raw in (self.fresh() for _ in range(n))]
         answers = [None] * n
@@ -77,14 +83,17 @@ class Arena:
             return on_done
 
         enqueue = self.resolver.enqueue_deps
-        c0 = time.process_time()
-        t0 = time.perf_counter()
-        with timed() if timed is not None else contextlib.nullcontext():
-            for i, (t, owned, bound, _) in enumerate(subjects):
-                enqueue(self.store, t, owned, bound).add_callback(done(i))
-            self.cluster.queue.drain(max_events=1_000_000)
-        resolve_s = time.perf_counter() - t0
-        cpu_s = time.process_time() - c0
+        with watch if watch is not None else contextlib.nullcontext():
+            c0 = time.process_time()
+            t0 = time.perf_counter()
+            with timed() if timed is not None else contextlib.nullcontext():
+                with common.host_span(ENQUEUE_SPAN):
+                    for i, (t, owned, bound, _) in enumerate(subjects):
+                        enqueue(self.store, t, owned,
+                                bound).add_callback(done(i))
+                self.cluster.queue.drain(max_events=1_000_000)
+            resolve_s = time.perf_counter() - t0
+            cpu_s = time.process_time() - c0
         wrong = deps = 0
         for (t, owned, bound, raw), a in zip(subjects, answers):
             want = self.expected(raw, bound)
@@ -102,6 +111,11 @@ def run(p, seed, seconds, trace, meter, dump_trace=None):
     _, _, wrong, failed, deps = arena.round(n)  # warm-up: compiles, untimed
     faults = [f"warm-up round: {wrong} wrong, {failed} failed"] \
         if wrong or failed or not deps else []
+    watch = common.CollectorWatch()
+    gc.callbacks.append(watch.on_collection)
+    timers = {k: arena.resolver.metrics.timer(v)
+              for k, v in ROUND_TIMERS.items()}
+    per_round = {"round_s": [], "round_cpu_s": [], **{k: [] for k in timers}}
 
     compiles_open = meter.requests
     before = arena.counters()
@@ -118,8 +132,13 @@ def run(p, seed, seconds, trace, meter, dump_trace=None):
             common.start_trace()
             slice_state, d0 = "open", arena.resolver.dispatches
         in_slice = slice_state == "open"
+        at = {k: t.total for k, t in timers.items()}
         r, c, w, f, d = arena.round(
-            n, timed=common.window_span if in_slice else None)
+            n, timed=common.window_span if in_slice else None, watch=watch)
+        per_round["round_s"].append(r)
+        per_round["round_cpu_s"].append(c)
+        for k, t in timers.items():
+            per_round[k].append(t.total - at[k])
         resolve_s, cpu_s, rounds = resolve_s + r, cpu_s + c, rounds + 1
         wrong, failed, deps = wrong + w, failed + f, deps + d
         traced_s += r if in_slice else 0.0
@@ -128,6 +147,7 @@ def run(p, seed, seconds, trace, meter, dump_trace=None):
             traced_dispatches = arena.resolver.dispatches - d0
             slice_state = "closed"
             traced = common.reduce_trace(traced_s, dump_to=dump_trace)
+    gc.callbacks.remove(watch.on_collection)
     after = arena.counters()
     counters = common.delta(after, before)
     faults += common.counter_faults(after)
@@ -147,5 +167,10 @@ def run(p, seed, seconds, trace, meter, dump_trace=None):
         "window_opened_at": window_opened_at,
         "notes": {"faults": faults, "rounds": rounds,
                   "deps_per_subject": deps / max(1, rounds * n),
-                  "device_id": arena.resolver.device.id},
+                  "device_id": arena.resolver.device.id,
+                  **per_round, "collector": watch.read()},
+        "compared": {
+            "wrong_answers": [wrong, 0], "failed_resolutions": [failed, 0],
+            "deps_checked_min": [deps, 1],
+            **common.counter_comparisons(after)},
     }

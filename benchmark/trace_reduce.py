@@ -7,15 +7,23 @@ plane (`/device:TPU:<n>`, its "XLA Ops" line), clipped to the benchmark's own
 `bench.window` spans where the trace has them (a runner wraps each measured
 stretch in one, so the checks between the batch cell's rounds are in neither
 busy nor window). With several chips busy is averaged over the device planes.
-The longest gaps between device operations inside the window are labelled
-"host, unattributed": the program puts no span on the profiler's clock yet.
+Each of the longest gaps between device operations inside the window is
+labelled by the host span that holds most of it (`label_gap`): the program's
+`obs.trace.phase` spans (`resolver.materialize`, `resolver.tick`, ...) and
+the runners' own (`bench.enqueue`). "host, unattributed" stays where spans
+cover under half a gap.
 """
 from __future__ import annotations
 
 import bisect
 import json
+import re
 
 WINDOW_SPAN = "bench.window"
+# a span of the program or of a runner: a lower-case dotted name, which no
+# event of JAX's runtime on the host planes has
+SPAN_NAME = re.compile(r"[a-z_]+(\.[a-z_0-9]+)+$")
+UNATTRIBUTED = "host, unattributed"
 DEVICE_PLANE = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"  # one event per program run: "jit_f(<hash>)"
@@ -76,11 +84,33 @@ def short_names(ops, modules):
     return out
 
 
+def label_gap(a, b, spans):
+    """The name of the span that holds most of the gap [a, b). A name holds
+    what the union of its spans covers of the gap. Of the names that hold
+    half of it or more, the one that holds least: a child lies inside its
+    parent, so that is the most specific span that still accounts for the
+    gap (`resolver.materialize` inside `resolver.harvest`). Where none holds
+    half, the name that holds most; UNATTRIBUTED where all spans together
+    hold under half. `spans`: [(name, start, end)]."""
+    by_name = {}
+    for name, s, e in spans:
+        if e > a and s < b:
+            by_name.setdefault(name, []).append([max(s, a), min(e, b)])
+    held = {name: total(union(parts)) for name, parts in by_name.items()}
+    half = (b - a) / 2
+    if total(union(p for parts in by_name.values() for p in parts)) < half:
+        return UNATTRIBUTED
+    enough = [name for name in held if held[name] >= half]
+    if enough:
+        return min(enough, key=held.get)
+    return max(held, key=held.get)
+
+
 def reduce_planes(planes, fallback_window_s):
     """`planes`: [(plane name, [(line name, [(event name, start_ns,
     duration_ns)])])]. Returns busy_s, window_s and the breakdown, or None
     where no device plane holds an operation."""
-    windows, devices = [], []
+    windows, devices, spans = [], [], []
     for plane, lines in planes:
         by_line = dict(lines)
         if plane.startswith(DEVICE_PLANE):
@@ -89,6 +119,8 @@ def reduce_planes(planes, fallback_window_s):
             continue
         for _, events in lines:
             windows += [[s, s + d] for n, s, d in events if n == WINDOW_SPAN]
+            spans += [(n, s, s + d) for n, s, d in events
+                      if n != WINDOW_SPAN and SPAN_NAME.match(n)]
     devices = [ev for ev in devices if ev]
     if not devices:
         return None
@@ -100,13 +132,14 @@ def reduce_planes(planes, fallback_window_s):
     for events in devices:
         merged = clip(union([s, s + d] for _, s, d in events), windows)
         busy.append(total(merged))
-        idle += [b - a for a, b in gaps(merged, windows)]
+        idle += gaps(merged, windows)
         for name, s, d in events:
             inside = total(clip([[s, s + d]], windows))
             if inside:
                 by_op[name] = by_op.get(name, 0) + inside
     n = len(devices)
     ops = sorted(by_op.items(), key=lambda kv: -kv[1])[:TOP]
+    idle = sorted(idle, key=lambda g: g[0] - g[1])[:TOP]
     return {
         "busy_s": sum(busy) / n / 1e9,
         "window_s": total(windows) / 1e9,
@@ -114,8 +147,8 @@ def reduce_planes(planes, fallback_window_s):
         "device_planes": n,
         "breakdown": {
             "device_ops": [[name, ns / n / 1e9] for name, ns in ops],
-            "idle_gaps": [["host, unattributed", ns / 1e9]
-                          for ns in sorted(idle, reverse=True)[:TOP]],
+            "idle_gaps": [[label_gap(a, b, spans), (b - a) / 1e9]
+                          for a, b in idle],
         },
     }
 
