@@ -498,25 +498,33 @@ def range_deps_resolve(iv_of, iv_start, iv_end, subj_before, subj_kinds,
                    K a power of two (covered_buckets wraparound)
     -> (u32[B, rcap/32], u32[B, cap/32]) packed candidate bitmasks, masked by
        witness/before/valid exactly like deps_resolve
+
+    The stages carry jax.named_scope names, as deps_resolve's do.
     """
     b = subj_before.shape[0]
     rcap = r_start.shape[0]
     k = k_bm.shape[1]
-    hit_r = (iv_start[:, None] < r_end[None, :]) \
-        & (r_start[None, :] < iv_end[:, None])
-    any_r = jnp.zeros((b, rcap), jnp.int32) \
-        .at[iv_of].max(hit_r.astype(jnp.int32), mode="drop") > 0
-    witness_r = _witness_mask(witness_table, subj_kinds, r_kinds)
-    before_r = _lex_before(r_ts[None, :, :], subj_before[:, None, :])
-    m_r = any_r & witness_r & before_r & r_valid[None, :]
-    cov = covered_buckets(iv_of, iv_start, iv_end, b, k, 0, k)
-    any_k = jax.lax.dot_general(
-        cov, k_bm.astype(jnp.bfloat16),
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) > 0.5
-    witness_k = _witness_mask(witness_table, subj_kinds, k_kinds)
-    before_k = _lex_before(k_ts[None, :, :], subj_before[:, None, :])
-    m_k = any_k & witness_k & before_k & k_valid[None, :] \
-        & subj_is_range[:, None]
+    with jax.named_scope("interval_overlap"):
+        hit_r = (iv_start[:, None] < r_end[None, :]) \
+            & (r_start[None, :] < iv_end[:, None])
+        any_r = jnp.zeros((b, rcap), jnp.int32) \
+            .at[iv_of].max(hit_r.astype(jnp.int32), mode="drop") > 0
+    with jax.named_scope("range_witness_before_mask"):
+        witness_r = _witness_mask(witness_table, subj_kinds, r_kinds)
+        before_r = _lex_before(r_ts[None, :, :], subj_before[:, None, :])
+        m_r = any_r & witness_r & before_r & r_valid[None, :]
+    with jax.named_scope("covered_buckets"):
+        cov = covered_buckets(iv_of, iv_start, iv_end, b, k, 0, k)
+    with jax.named_scope("bucket_overlap"):
+        any_k = jax.lax.dot_general(
+            cov, k_bm.astype(jnp.bfloat16),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) > 0.5
+    with jax.named_scope("key_witness_before_mask"):
+        witness_k = _witness_mask(witness_table, subj_kinds, k_kinds)
+        before_k = _lex_before(k_ts[None, :, :], subj_before[:, None, :])
+        m_k = any_k & witness_k & before_k & k_valid[None, :] \
+            & subj_is_range[:, None]
     return _pack_bits(m_r), _pack_bits(m_k)
 
 
@@ -528,14 +536,17 @@ def _segment_compact(hits, out_cap: int):
     beyond out_cap are dropped by the scatter; callers detect overflow via
     indptr[-1] > out_cap and fall back."""
     s, n = hits.shape
-    counts = jnp.sum(hits, axis=1, dtype=jnp.int32)
-    indptr = jnp.concatenate(
-        [jnp.zeros(1, jnp.int32), jnp.cumsum(counts, dtype=jnp.int32)])
-    within = jnp.cumsum(hits, axis=1, dtype=jnp.int32) - hits
-    pos = jnp.where(hits > 0, indptr[:-1][:, None] + within, out_cap)
-    col = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None, :], (s, n))
-    dep_rows = jnp.zeros(out_cap, jnp.int32) \
-        .at[pos.reshape(-1)].set(col.reshape(-1), mode="drop")
+    with jax.named_scope("segment_prefix"):
+        counts = jnp.sum(hits, axis=1, dtype=jnp.int32)
+        indptr = jnp.concatenate(
+            [jnp.zeros(1, jnp.int32), jnp.cumsum(counts, dtype=jnp.int32)])
+        within = jnp.cumsum(hits, axis=1, dtype=jnp.int32) - hits
+        pos = jnp.where(hits > 0, indptr[:-1][:, None] + within, out_cap)
+    with jax.named_scope("row_scatter"):
+        col = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None, :],
+                               (s, n))
+        dep_rows = jnp.zeros(out_cap, jnp.int32) \
+            .at[pos.reshape(-1)].set(col.reshape(-1), mode="drop")
     return indptr, dep_rows
 
 
@@ -863,19 +874,24 @@ def _range_finalize_csr_body(iv_of, iv_start, iv_end, ent_ok,
     """range_finalize_csr's trace body, unjitted for protocol_tick (see
     _finalize_csr_body)."""
     b = subj_before.shape[0]
-    o = jnp.clip(iv_of, 0, b - 1)
-    inb = (iv_of >= 0) & (iv_of < b) & ent_ok
-    hit = (iv_start[:, None] < r_end[None, :]) \
-        & (r_start[None, :] < iv_end[:, None])
-    stab = hit & r_valid[None, :] & inb[:, None]
-    bound = jnp.sum(stab.astype(jnp.int32), dtype=jnp.int32)
-    witness = _witness_mask(witness_table, subj_kinds[o], r_kinds)
-    before = _lex_before(r_ts[None, :, :], subj_before[o][:, None, :])
-    m = stab & witness & before
+    with jax.named_scope("interval_stab"):
+        o = jnp.clip(iv_of, 0, b - 1)
+        inb = (iv_of >= 0) & (iv_of < b) & ent_ok
+        hit = (iv_start[:, None] < r_end[None, :]) \
+            & (r_start[None, :] < iv_end[:, None])
+        stab = hit & r_valid[None, :] & inb[:, None]
+    with jax.named_scope("bound"):
+        bound = jnp.sum(stab.astype(jnp.int32), dtype=jnp.int32)
+    with jax.named_scope("witness_before_mask"):
+        witness = _witness_mask(witness_table, subj_kinds[o], r_kinds)
+        before = _lex_before(r_ts[None, :, :], subj_before[o][:, None, :])
+        m = stab & witness & before
     indptr, dep_rows = _segment_compact(m.astype(jnp.int32), out_cap)
-    dep_ts = r_ts[dep_rows]
-    return (indptr, dep_rows, dep_ts, bound,
-            csr_checksum(indptr, dep_rows, dep_ts))
+    with jax.named_scope("ts_gather"):
+        dep_ts = r_ts[dep_rows]
+    with jax.named_scope("checksum"):
+        csum = csr_checksum(indptr, dep_rows, dep_ts)
+    return indptr, dep_rows, dep_ts, bound, csum
 
 
 @jax.jit

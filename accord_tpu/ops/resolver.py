@@ -1656,6 +1656,16 @@ class BatchDepsResolver(DepsResolver):
     # lanes (no host candidate re-filter)
     range_subject_device_decodes = RegCounter(
         "resolver.range_subject_device_decodes")
+    # the range path's own share of encode_s and decode_s (the interval CSR
+    # and the range / rk finalize plans; both stages of the range harvest,
+    # the rk lane's, and the key subjects' range-txn deps), and what it
+    # handled: range-domain subjects and their interval pieces encoded, and
+    # range-vs-range dependencies delivered, one per (intersection, txn)
+    range_encode_s = RegTimer("resolver.range_encode_s")
+    range_decode_s = RegTimer("resolver.range_decode_s")
+    range_subjects = RegCounter("resolver.range_subjects")
+    range_intervals = RegCounter("resolver.range_intervals")
+    range_deps = RegCounter("resolver.range_deps")
     # host launch time of the sharded finalize compaction (per-shard
     # popcount/prefix + gather-merge) on multi-device meshes
     shard_merge_s = RegTimer("resolver.shard_merge_s")
@@ -2359,63 +2369,16 @@ class BatchDepsResolver(DepsResolver):
         # block's slot matches
         subj_store = np.full(b, len(groups), dtype=np.int32)
         gkeys: List[List[Tuple[int, _Item]]] = [[] for _ in groups]
-        givs: List[List[Tuple[int, int, int]]] = [[] for _ in groups]
-        ghull = [False] * len(groups)
-        # finalize_on_device: each group's (local interval-CSR entry, global
-        # item position, key) records -- key-subject point entries are 1:1
-        # with keys, so the finalized range output routes by entry
-        grents: List[List[Tuple[int, int, object]]] = [[] for _ in groups]
-        # finalize_on_device: each group's encodable RANGE subjects as
-        # (global item position, item, interval pieces) -- fed to the
-        # device stab lanes (_RSUB rents entries + the key-arena rk lane)
-        grsubs: List[List[tuple]] = [[] for _ in groups]
+        granges: List[List[Tuple[int, _Item]]] = [[] for _ in groups]
         for gi, g in enumerate(groups):
-            ranges = g.arena.ranges
             for i, item in zip(g.idx, g.items):
                 subj_store[i] = gi
                 item.cover_seq = item.store.cover_seq
                 if isinstance(item.owned, Keys):
                     gkeys[gi].append((i, item))
-                    continue
-                srng[i] = True
-                if not ranges.encode_ok:
-                    item.fallback = "full"
-                    self.range_fallbacks += 1
-                    continue
-                ivs = encode_seekable_intervals(item.owned)
-                if ivs is None:
-                    item.fallback = "full"
-                    self.range_fallbacks += 1
-                    continue
-                ghull[gi] = True
-                if self.finalize_on_device:
-                    # the subject's own pieces become _RSUB rents entries:
-                    # the device interval stab answers its range-vs-range
-                    # deps (per-piece hit segments union idempotently)
-                    base = len(givs[gi])
-                    grents[gi].extend((base + t, i, _RSUB)
-                                      for t in range(len(ivs)))
-                    grsubs[gi].append((i, item, ivs))
-                givs[gi].extend((i, s, e) for (s, e) in ivs)
-            if ranges.encode_ok and ranges.count > 0:
-                # key subjects stab their store's interval rows with point
-                # intervals (the retired host_range_deps union, on device);
-                # the key-parallel encoding feeds the candidate kernel the
-                # exact same pairs encode_seekable_intervals would
-                for i, item in gkeys[gi]:
-                    kivs = encode_key_point_intervals(item.owned)
-                    if kivs is None:
-                        # unencodable keys: this subject's range deps come
-                        # from the host union instead (counted)
-                        item.fallback = "range"
-                        self.range_fallbacks += 1
-                        continue
-                    if self.finalize_on_device:
-                        base = len(givs[gi])
-                        grents[gi].extend(
-                            (base + t, i, k)
-                            for t, (k, _, _) in enumerate(kivs))
-                    givs[gi].extend((i, s, e) for (_, s, e) in kivs)
+                else:
+                    srng[i] = True
+                    granges[gi].append((i, item))
         # -- key-domain kernel plan --------------------------------------
         plan = _Plan(items, groups)
         k_parts = [(gi, g) for gi, g in enumerate(groups)
@@ -2484,7 +2447,106 @@ class BatchDepsResolver(DepsResolver):
             # launch time, so it rides the same deferred-call pipeline
             for gi, g in k_parts:
                 self._plan_key_finalize(plan, g, gkeys[gi], b)
-        # -- range kernel plan -------------------------------------------
+        # -- range path: interval CSR, range kernel plan, range / rk finalize
+        if any(granges) or any(g.arena.ranges.count > 0 for g in groups):
+            with self._phase("resolver.range_encode",
+                             "resolver.range_encode_s"):
+                self._plan_range_kernel(plan, groups, gkeys, granges, b, sb,
+                                        sknd, srng, subj_store)
+        if self.finalize_on_device:
+            # the finalized harvest reads only the compacted CSR results;
+            # the raw candidate buffers stay device-resident (range
+            # subjects included -- the interval-stab + key-index lanes
+            # replace the candidate re-filter) unless some range subject's
+            # group could not plan a stab lane it needs; guard-tripped
+            # fallbacks still fetch lazily
+            want_rp = want_kp = False
+            for g in groups:
+                if not any(not isinstance(it.owned, Keys)
+                           and it.fallback is None for it in g.items):
+                    continue
+                if g.rp is not None and g.rents is None:
+                    want_rp = True
+                if g.kp is not None and g.rk_slots is None:
+                    want_kp = True
+            plan.want = (False, want_rp, want_kp)
+        if pin:
+            for g in groups:
+                if g.pk is not None or g.kp is not None:
+                    g.arena.pin_gen()
+                    g.pinned = True
+                if g.rp is not None:
+                    g.arena.ranges.pin_gen()
+                    g.rpinned = True
+        return plan
+
+    def _plan_range_kernel(self, plan: _Plan, groups: List[_Group], gkeys,
+                           granges, b: int, sb, sknd, srng,
+                           subj_store) -> None:
+        """The range half of _encode_plan, under its own span
+        (resolver.range_encode, inside resolver.encode): the interval CSR of
+        the dispatch -- range subjects' owned pieces, then key subjects as
+        point intervals where their store holds range txns -- the range
+        kernel's deferred call, and the range and rk finalize lanes."""
+        import jax.numpy as jnp
+        from accord_tpu.ops.kernels import nnz_tier
+        givs: List[List[Tuple[int, int, int]]] = [[] for _ in groups]
+        ghull = [False] * len(groups)
+        # finalize_on_device: each group's (local interval-CSR entry, global
+        # item position, key) records -- key-subject point entries are 1:1
+        # with keys, so the finalized range output routes by entry
+        grents: List[List[Tuple[int, int, object]]] = [[] for _ in groups]
+        # finalize_on_device: each group's encodable RANGE subjects as
+        # (global item position, item, interval pieces) -- fed to the
+        # device stab lanes (_RSUB rents entries + the key-arena rk lane)
+        grsubs: List[List[tuple]] = [[] for _ in groups]
+        n_subjects = n_intervals = 0
+        for gi, g in enumerate(groups):
+            ranges = g.arena.ranges
+            for i, item in granges[gi]:
+                if not ranges.encode_ok:
+                    item.fallback = "full"
+                    self.range_fallbacks += 1
+                    continue
+                ivs = encode_seekable_intervals(item.owned)
+                if ivs is None:
+                    item.fallback = "full"
+                    self.range_fallbacks += 1
+                    continue
+                ghull[gi] = True
+                n_subjects += 1
+                n_intervals += len(ivs)
+                if self.finalize_on_device:
+                    # the subject's own pieces become _RSUB rents entries:
+                    # the device interval stab answers its range-vs-range
+                    # deps (per-piece hit segments union idempotently)
+                    base = len(givs[gi])
+                    grents[gi].extend((base + t, i, _RSUB)
+                                      for t in range(len(ivs)))
+                    grsubs[gi].append((i, item, ivs))
+                givs[gi].extend((i, s, e) for (s, e) in ivs)
+            if ranges.encode_ok and ranges.count > 0:
+                # key subjects stab their store's interval rows with point
+                # intervals (the retired host_range_deps union, on device);
+                # the key-parallel encoding feeds the candidate kernel the
+                # exact same pairs encode_seekable_intervals would
+                for i, item in gkeys[gi]:
+                    kivs = encode_key_point_intervals(item.owned)
+                    if kivs is None:
+                        # unencodable keys: this subject's range deps come
+                        # from the host union instead (counted)
+                        item.fallback = "range"
+                        self.range_fallbacks += 1
+                        continue
+                    if self.finalize_on_device:
+                        base = len(givs[gi])
+                        grents[gi].extend(
+                            (base + t, i, k)
+                            for t, (k, _, _) in enumerate(kivs))
+                    givs[gi].extend((i, s, e) for (_, s, e) in kivs)
+        if n_subjects:
+            self.range_subjects += n_subjects
+            self.range_intervals += n_intervals
         intervals = [t for gv in givs for t in gv]
         r_parts = [(gi, g) for gi, g in enumerate(groups)
                    if g.arena.ranges.count > 0 and g.arena.ranges.encode_ok
@@ -2575,32 +2637,6 @@ class BatchDepsResolver(DepsResolver):
                 for gi, g in enumerate(groups):
                     if grsubs[gi] and g.kp is not None:
                         self._plan_rkey_finalize(plan, g, grsubs[gi], b)
-        if self.finalize_on_device:
-            # the finalized harvest reads only the compacted CSR results;
-            # the raw candidate buffers stay device-resident (range
-            # subjects included -- the interval-stab + key-index lanes
-            # replace the candidate re-filter) unless some range subject's
-            # group could not plan a stab lane it needs; guard-tripped
-            # fallbacks still fetch lazily
-            want_rp = want_kp = False
-            for g in groups:
-                if not any(not isinstance(it.owned, Keys)
-                           and it.fallback is None for it in g.items):
-                    continue
-                if g.rp is not None and g.rents is None:
-                    want_rp = True
-                if g.kp is not None and g.rk_slots is None:
-                    want_kp = True
-            plan.want = (False, want_rp, want_kp)
-        if pin:
-            for g in groups:
-                if g.pk is not None or g.kp is not None:
-                    g.arena.pin_gen()
-                    g.pinned = True
-                if g.rp is not None:
-                    g.arena.ranges.pin_gen()
-                    g.rpinned = True
-        return plan
 
     def _plan_key_finalize(self, plan: _Plan, g: _Group, pairs, b: int) -> None:
         """Cut one store's finalize_csr call: the (subject, key) slot list
@@ -3196,6 +3232,9 @@ class BatchDepsResolver(DepsResolver):
         -- builders, so the key-arena rk lane can merge into them."""
         builders: Dict[int, KeyDepsBuilder] = {}
         rsub: Dict[int, RangeDepsBuilder] = {}
+        # range-vs-range deps delivered: one stabbed row per (subject piece,
+        # dep range), so one per (intersection, txn) of the answers
+        delivered = 0
         for j, k, rids in raw:
             item = g.items[j]
             rt = item.store.range_txns
@@ -3203,9 +3242,11 @@ class BatchDepsResolver(DepsResolver):
                 rb = rsub.get(j)
                 if rb is None:
                     rb = rsub[j] = RangeDepsBuilder()
+                delivered += len(rids)
                 for rid in rids:
                     rngs = rt.get(rid)
                     if rngs is None:
+                        delivered -= 1
                         continue
                     for r in rngs.intersection(item.owned):
                         rb.add(r, rid)
@@ -3218,6 +3259,8 @@ class BatchDepsResolver(DepsResolver):
                 if rngs is None or not rngs.contains_key(k):
                     continue
                 kb.add(k, rid)
+        if delivered:
+            self.range_deps += delivered
         return {j: kb.build() for j, kb in builders.items()}, rsub
 
     def _materialize_range_finalized(self, call: _Call, g: _Group):
@@ -3444,55 +3487,22 @@ class BatchDepsResolver(DepsResolver):
                 if not key_stale:
                     kds = self._decode_batch(arena, g.items, gp)
                     self.legacy_decodes += 1
-            # range finalized output: exact per-entry segments for the
-            # group's KEY subjects (kmap) and its range subjects'
-            # range-vs-range deps (rsub builders, from the _RSUB entries)
-            rkb = rsub_rb = None
-            if g.rents is not None:
-                raw_r = g.rmat
-                if raw_r is None and g.rgen == arena.ranges.gen \
-                        and g.rseq == arena.ranges.rseq:
-                    raw_r = self._stab_range_finalized(call, g)
-                if raw_r is not None:
-                    # stage 2 runs here either way: current host maps,
-                    # so fenced caches decode like guarded ones
-                    rkb, rsub_rb = self._finish_range_finalized(g, raw_r)
-            if g.rents is not None and rkb is None:
-                self.finalize_fallbacks += 1
-            # range subjects decode on device only when EVERY stab lane
-            # they need materialized: the interval stab above and the
-            # key-arena rk lane below (each absent lane corresponds to an
-            # arena with no rows at plan time -- correctly empty)
+            # the range lanes, under their own span inside
+            # resolver.materialize: exact per-entry segments for the
+            # group's KEY subjects (rkb) and its range subjects' deps built
+            # from both stab lanes (rsub_deps; None -> candidate decode of
+            # grp / gkp)
+            rkb = rsub_deps = None
             has_rsub = any(not isinstance(it.owned, Keys)
                            and it.fallback is None for it in g.items)
-            rsub_ok = has_rsub and self.finalize_on_device
-            if rsub_ok and g.rp is not None and rkb is None:
-                rsub_ok = False
-            if rsub_ok and g.kp is not None:
-                raw_rk = g.rk_mat
-                if raw_rk is None and g.rk_slots is not None \
-                        and not key_stale and g.gen == arena.gen \
-                        and g.kseq == arena.kseq:
-                    raw_rk = self._stab_rkey_finalized(call, g)
-                if raw_rk is None:
-                    rsub_ok = False
-                    if g.rk_slots is not None:
-                        self.finalize_fallbacks += 1
-                else:
-                    if rsub_rb is None:
-                        rsub_rb = {}
-                    self._finish_rkey_finalized(g, raw_rk, rsub_rb)
-            need_rp = has_rp and (rkb is None
-                                  or (has_rsub and not rsub_ok))
-            if need_rp:
-                buf = self._fetch_np(call, "np_rpacked", call.rpacked)
-                if buf is not None:
-                    grp = buf[idx][:, g.rp[0]:g.rp[1]]
-            if has_kp and any(not isinstance(it.owned, Keys)
-                              for it in g.items) and not rsub_ok:
-                buf = self._fetch_np(call, "np_kpacked", call.kpacked)
-                if buf is not None:
-                    gkp = buf[idx][:, g.kp[0]:g.kp[1]]
+            if g.rents is not None or has_rp or has_kp or has_rsub:
+                with self._phase("resolver.range_decode",
+                                 "resolver.range_decode_s"):
+                    rkb, rsub_deps, grp, gkp = self._decode_range_lanes(
+                        call, g, idx, key_stale, has_rp, has_kp, has_rsub)
+            # key subjects' range-txn deps, merged after the item walk
+            # (under the same span): (item position, KeyDeps)
+            range_unions: List[tuple] = []
             for j, item in enumerate(g.items):
                 store = item.store
                 if item.fallback == "full":
@@ -3507,13 +3517,12 @@ class BatchDepsResolver(DepsResolver):
                         results[g.idx[j]] = store.host_calculate_deps(
                             item.txn_id, item.owned, item.before)
                         continue
-                    if rsub_ok:
+                    if rsub_deps is not None:
                         # fully device-resident: both stab lanes' builders
                         # merged per item; absent builder -> no deps
-                        rb = rsub_rb.get(j) if rsub_rb else None
-                        results[g.idx[j]] = Deps(
-                            KeyDeps.EMPTY, rb.build()) if rb is not None \
-                            else Deps(KeyDeps.EMPTY)
+                        rd = rsub_deps.get(j)
+                        results[g.idx[j]] = Deps(KeyDeps.EMPTY, rd) \
+                            if rd is not None else Deps(KeyDeps.EMPTY)
                         self.range_subject_device_decodes += 1
                         continue
                     d = self._decode_range_subject(
@@ -3547,7 +3556,7 @@ class BatchDepsResolver(DepsResolver):
                 elif rkb is not None:
                     extra = rkb.get(j)
                     if extra is not None and not extra.is_empty():
-                        deps = deps.union(Deps(extra))
+                        range_unions.append((g.idx[j], extra))
                 elif grp is not None:
                     extra = self._decode_key_range_deps(arena, g.rgen,
                                                         grp[j], item)
@@ -3558,7 +3567,76 @@ class BatchDepsResolver(DepsResolver):
                     elif not extra.is_empty():
                         deps = deps.union(Deps(extra))
                 results[g.idx[j]] = deps
+            if range_unions:
+                with self._phase("resolver.range_decode",
+                                 "resolver.range_decode_s"):
+                    for pos, extra in range_unions:
+                        results[pos] = results[pos].union(Deps(extra))
         return results
+
+    def _decode_range_lanes(self, call: _Call, g: _Group, idx,
+                            key_stale: bool, has_rp: bool, has_kp: bool,
+                            has_rsub: bool):
+        """One group's range lanes at harvest: the interval stab's two
+        stages (key subjects' range-txn deps and range subjects'
+        range-vs-range deps), the rk lane's two stages merged into the same
+        builders, and the range subjects' deps built. Returns (rkb: item ->
+        KeyDeps or None, rsub_deps: item -> RangeDeps, or None when some
+        stab lane a range subject needs did not materialize, grp, gkp: the
+        group's slices of the raw candidate buffers the fallbacks need)."""
+        arena = g.arena
+        grp = gkp = None
+        # range finalized output: exact per-entry segments for the
+        # group's KEY subjects (kmap) and its range subjects'
+        # range-vs-range deps (rsub builders, from the _RSUB entries)
+        rkb = rsub_rb = None
+        if g.rents is not None:
+            raw_r = g.rmat
+            if raw_r is None and g.rgen == arena.ranges.gen \
+                    and g.rseq == arena.ranges.rseq:
+                raw_r = self._stab_range_finalized(call, g)
+            if raw_r is not None:
+                # stage 2 runs here either way: current host maps,
+                # so fenced caches decode like guarded ones
+                rkb, rsub_rb = self._finish_range_finalized(g, raw_r)
+        if g.rents is not None and rkb is None:
+            self.finalize_fallbacks += 1
+        # range subjects decode on device only when EVERY stab lane
+        # they need materialized: the interval stab above and the
+        # key-arena rk lane below (each absent lane corresponds to an
+        # arena with no rows at plan time -- correctly empty)
+        rsub_ok = has_rsub and self.finalize_on_device
+        if rsub_ok and g.rp is not None and rkb is None:
+            rsub_ok = False
+        if rsub_ok and g.kp is not None:
+            raw_rk = g.rk_mat
+            if raw_rk is None and g.rk_slots is not None \
+                    and not key_stale and g.gen == arena.gen \
+                    and g.kseq == arena.kseq:
+                raw_rk = self._stab_rkey_finalized(call, g)
+            if raw_rk is None:
+                rsub_ok = False
+                if g.rk_slots is not None:
+                    self.finalize_fallbacks += 1
+            else:
+                if rsub_rb is None:
+                    rsub_rb = {}
+                self._finish_rkey_finalized(g, raw_rk, rsub_rb)
+        need_rp = has_rp and (rkb is None
+                              or (has_rsub and not rsub_ok))
+        if need_rp:
+            buf = self._fetch_np(call, "np_rpacked", call.rpacked)
+            if buf is not None:
+                grp = buf[idx][:, g.rp[0]:g.rp[1]]
+        if has_kp and any(not isinstance(it.owned, Keys)
+                          for it in g.items) and not rsub_ok:
+            buf = self._fetch_np(call, "np_kpacked", call.kpacked)
+            if buf is not None:
+                gkp = buf[idx][:, g.kp[0]:g.kp[1]]
+        rsub_deps = None
+        if rsub_ok:
+            rsub_deps = {j: rb.build() for j, rb in (rsub_rb or {}).items()}
+        return rkb, rsub_deps, grp, gkp
 
     def _decode_dispatch(self, call: _Call) -> List[Deps]:
         """The async harvest decode: core recovery + the store's dep floor

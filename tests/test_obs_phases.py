@@ -10,9 +10,12 @@ Load-bearing properties:
   3. the profiler's clock -- a resolve under a jax.profiler session shows
      the resolver.* spans on a host plane, launch and harvest of one
      dispatch sharing their `did`;
-  4. scope names -- the lowered text of both programs carries every stage
-     name, and the names change no answer;
-  5. the flight recorder's vocabulary is what it was before the primitive.
+  4. scope names -- the lowered text of the key and the range programs
+     carries every stage name, and the names change no answer;
+  5. the flight recorder's vocabulary is what it was before the primitive;
+  6. the range path -- at the range cell's rehearsal sizes its two spans lie
+     inside their parents, their timers inside the parents' timers, and its
+     counters move for range subjects only.
 """
 from __future__ import annotations
 
@@ -316,18 +319,59 @@ RESOLVE_SCOPES = ("subject_bitmap", "overlap", "witness_before_mask",
                   "pack_bits")
 FINALIZE_SCOPES = ("slot_mask", "bound", "popcount_prefix", "word_compact",
                    "bit_expand", "row_scatter", "ts_gather", "checksum")
+RANGE_RESOLVE_SCOPES = ("interval_overlap", "range_witness_before_mask",
+                        "covered_buckets", "bucket_overlap",
+                        "key_witness_before_mask", "pack_bits")
+RANGE_FINALIZE_SCOPES = ("interval_stab", "bound", "witness_before_mask",
+                         "segment_prefix", "row_scatter", "ts_gather",
+                         "checksum")
 
 
-@pytest.mark.parametrize("program,scopes", [
-    ("deps_resolve", RESOLVE_SCOPES), ("finalize_csr", FINALIZE_SCOPES)])
-def test_lowered_text_carries_the_scope_names(program, scopes):
+def _range_kernel_args(seed=3, nv=24, rcap=32):
+    """Inputs of the two range programs at the small shapes of
+    `_kernel_args`: (range_deps_resolve's, range_finalize_csr's)."""
+    resolve, _ = _kernel_args(seed)
+    subj_of, _, before, s_kinds, act_bm, act_ts, a_kinds, valid, table = \
+        resolve
+    rng = np.random.default_rng(seed + 1)
+    iv_s = rng.integers(0, 40, nv).astype(np.int32)
+    iv_e = iv_s + rng.integers(1, 8, nv).astype(np.int32)
+    r_start = rng.integers(0, 40, rcap).astype(np.int32)
+    arena = (r_start, r_start + rng.integers(1, 8, rcap).astype(np.int32),
+             act_ts[:rcap], a_kinds[:rcap], valid[:rcap])
+    return ((subj_of[:nv], iv_s, iv_e, before, s_kinds,
+             rng.random(len(s_kinds)) < 0.5, *arena,
+             act_bm, act_ts, a_kinds, valid, table),
+            (subj_of[:nv], iv_s, iv_e, rng.random(nv) < 0.8, before, s_kinds,
+             *arena, table))
+
+
+def _programs(key):
+    """The (resolve, finalize) pair of the key or of the range path, with
+    their inputs and scope names; finalize_csr takes deps_resolve's output
+    first."""
     from accord_tpu.ops import kernels
-    resolve, finalize = _kernel_args()
-    if program == "deps_resolve":
-        lowered = kernels.deps_resolve.lower(*resolve)
+    if key == "key":
+        resolve, finalize = _kernel_args(seed=9)
+        return ((kernels.deps_resolve, resolve, RESOLVE_SCOPES),
+                (kernels.finalize_csr, finalize, FINALIZE_SCOPES))
+    resolve, finalize = _range_kernel_args(seed=9)
+    return ((kernels.range_deps_resolve, resolve, RANGE_RESOLVE_SCOPES),
+            (kernels.range_finalize_csr, finalize, RANGE_FINALIZE_SCOPES))
+
+
+@pytest.mark.parametrize("program", [
+    "deps_resolve", "finalize_csr", "range_deps_resolve",
+    "range_finalize_csr"])
+def test_lowered_text_carries_the_scope_names(program):
+    (resolve, r_args, r_scopes), (finalize, f_args, f_scopes) = _programs(
+        "range" if program.startswith("range") else "key")
+    if program.endswith("deps_resolve"):
+        lowered, scopes = resolve.lower(*r_args), r_scopes
     else:
-        packed = kernels.deps_resolve(*resolve)
-        lowered = kernels.finalize_csr.lower(packed, *finalize, out_cap=256)
+        if program == "finalize_csr":
+            f_args = (resolve(*r_args),) + f_args
+        lowered, scopes = finalize.lower(*f_args, out_cap=256), f_scopes
     text = lowered.as_text(debug_info=True)
     missing = [s for s in scopes if f"/{s}/" not in text]
     assert not missing, f"{program} lowered without scopes {missing}"
@@ -369,29 +413,30 @@ def test_lowered_resolve_holds_no_gather(program):
         assert "/witness_before_mask/and" in lowered.as_text(debug_info=True)
 
 
-def test_scope_names_change_no_answer(monkeypatch):
+@pytest.mark.parametrize("path", ["key", "range"])
+def test_scope_names_change_no_answer(monkeypatch, path):
     import jax
-    from accord_tpu.ops import kernels
-    resolve, finalize = _kernel_args(seed=9)
-    packed = kernels.deps_resolve(*resolve)
-    named = (packed,) + tuple(
-        kernels.finalize_csr(packed, *finalize, out_cap=256))
-    assert int(named[1][-1]) > 0, "the inputs produced no dependency"
+    (resolve, r_args, r_scopes), (finalize, f_args, _) = _programs(path)
+
+    def answers(resolve, finalize):
+        out = resolve(*r_args)
+        if path == "key":  # finalize_csr takes deps_resolve's output first
+            return (out,) + tuple(finalize(out, *f_args, out_cap=256))
+        return tuple(out) + tuple(finalize(*f_args, out_cap=256))
+
+    named = answers(resolve, finalize)
+    assert int(named[-5][-1]) > 0, "the inputs produced no dependency"
     # the same trace bodies with every scope a no-op
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
     # (fresh functions: jit's trace cache would hand back the named trace)
-    bare_resolve = jax.jit(
-        lambda *a: kernels.deps_resolve.__wrapped__(*a))
+    bare_resolve = jax.jit(lambda *a: resolve.__wrapped__(*a))
     bare_finalize = jax.jit(
-        lambda *a, out_cap: kernels.finalize_csr.__wrapped__(
-            *a, out_cap=out_cap), static_argnames=("out_cap",))
-    assert "/overlap/" not in \
-        bare_resolve.lower(*resolve).as_text(debug_info=True)
-    bare_packed = bare_resolve(*resolve)
-    bare = (bare_packed,) + tuple(
-        bare_finalize(bare_packed, *finalize, out_cap=256))
-    for a, b in zip(named, bare):
+        lambda *a, out_cap: finalize.__wrapped__(*a, out_cap=out_cap),
+        static_argnames=("out_cap",))
+    assert f"/{r_scopes[0]}/" not in \
+        bare_resolve.lower(*r_args).as_text(debug_info=True)
+    for a, b in zip(named, answers(bare_resolve, bare_finalize)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -448,3 +493,78 @@ def test_recorder_events_are_what_they_were(wall):
         assert by_name[name] == pytest.approx(timer * 1e6, abs=0.01)
     hidden = sum(e["dur"] for e in spans if e["args"].get("hidden"))
     assert hidden == pytest.approx(r.host_hidden_s * 1e6, abs=0.01)
+
+
+# -- (f) the range path's spans and counters -----------------------------------
+
+RANGE_CELL = "preaccept-ranges-10k.range-20"
+
+
+@pytest.fixture(scope="module")
+def range_arena():
+    from benchmark import common
+    from benchmark.runners import ranges
+    cell = common.load_json(common.HERE / "workloads" / f"{RANGE_CELL}.json")
+    config = common.load_json(
+        common.HERE / "configs" / f"{cell['config']}.json")
+    p = {**config, **cell, **cell["rehearsal"]}
+    arena = ranges.Arena(p, 11)
+    assert sum(arena.round(p["subjects"])["wrong"].values()) == 0  # compiles
+    return arena, p
+
+
+@pytest.mark.parametrize("share", [0.0, 0.2, 1.0])
+def test_range_timers_fit_their_parents_and_counters_follow_range_subjects(
+        range_arena, share):
+    from benchmark import common
+    arena, p = range_arena
+    arena.range_share = share
+    before = arena.counters()
+    r = arena.round(p["subjects"])
+    d = common.delta(arena.counters(), before)
+    assert sum(r["wrong"].values()) == 0 and r["failed"] == 0
+    # the store holds range txns, so the range path runs for key subjects too
+    assert 0.0 < d["resolver.range_encode_s"] <= d["resolver.encode_s"]
+    assert 0.0 < d["resolver.range_decode_s"] <= d["resolver.decode_s"]
+    n_range = r["subjects"]["range"]
+    assert (n_range > 0) == (share > 0.0)
+    assert d.get("resolver.range_subjects", 0) == n_range
+    assert d.get("resolver.range_deps", 0) == r["range_range_deps"]
+    assert (d.get("resolver.range_intervals", 0) >= n_range) \
+        and (d.get("resolver.range_intervals", 0) > 0) == (n_range > 0)
+
+
+def test_key_only_store_never_enters_the_range_path(resolved):
+    _, _, d = resolved
+    for name in ("resolver.range_encode_s", "resolver.range_decode_s",
+                 "resolver.range_subjects", "resolver.range_intervals",
+                 "resolver.range_deps"):
+        assert name not in d, name
+
+
+def test_range_spans_nest_inside_their_parents(range_arena, tmp_path):
+    import jax
+    arena, p = range_arena
+    arena.range_share = 0.2
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        assert sum(arena.round(p["subjects"])["wrong"].values()) == 0
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    spans = {}
+    for plane in data.planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("resolver."):
+                    spans.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    for child, parent in (("resolver.range_encode", "resolver.encode"),
+                          ("resolver.range_decode", "resolver.materialize")):
+        assert spans.get(child), f"no {child} span on a host plane"
+        for s, e in spans[child]:
+            assert any(ps <= s and e <= pe for ps, pe in spans[parent]), \
+                f"a {child} span lies outside every {parent} span"
