@@ -335,8 +335,10 @@ GLOSSARY: Dict[str, str] = {
     "resolver.outcap_tier_switches": "finalize out-cap tier ladder moves",
     "resolver.bound_readback_s": "device dep-bound scalar readback wall seconds",
     "resolver.range_subject_device_decodes": "range subjects decoded from the device stab",
+    "resolver.range_array_decodes": "groups whose range lanes decoded as arrays over the whole dispatch",
+    "resolver.range_filtered_decodes": "of those, groups that applied the host-map filters a dependency at a time (fenced cache, guards since broken)",
     "resolver.range_encode_s": "encode_s spent on the range path: interval CSR, range kernel plan, range and rk finalize lanes",
-    "resolver.range_decode_s": "decode_s spent on the range path: both stages of the interval stab and of the rk lane, key subjects' range-txn deps",
+    "resolver.range_decode_s": "decode_s spent on the range path: both stages of the interval stab and of the rk lane, and the one sort a domain that cuts the group's answers (a key subject's key-lane pairs included, where its store holds range txns)",
     "resolver.range_subjects": "range-domain subjects encoded for the device path",
     "resolver.range_intervals": "interval pieces of range-domain subjects encoded",
     "resolver.range_deps": "range-vs-range dependencies delivered from the device stab, one per (intersection, txn)",

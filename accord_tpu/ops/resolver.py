@@ -72,14 +72,19 @@ from accord_tpu.ops.encoding import (TimestampEncoder, WITNESS_TABLE,
                                      encode_interval,
                                      encode_key_point_intervals,
                                      encode_seekable_intervals)
-from accord_tpu.primitives.deps import Deps, KeyDepsBuilder, RangeDepsBuilder
-from accord_tpu.primitives.keyspace import Keys, Range, Ranges, Seekables
+from accord_tpu.primitives.deps import (Deps, KeyDeps, KeyDepsBuilder,
+                                        RangeDeps, RangeDepsBuilder)
+from accord_tpu.primitives.keyspace import (Keys, Range, Ranges, Seekables,
+                                            _Successor)
 from accord_tpu.primitives.timestamp import Timestamp, TxnId
 from accord_tpu.utils.async_ import AsyncResult, success
 from accord_tpu.utils.invariants import Invariants
 
 
 logger = logging.getLogger(__name__)
+
+# rows a range arena's row_memo holds before it starts over
+_ROW_MEMO_CAP = 1 << 18
 
 _EMPTY_I32 = np.empty(0, dtype=np.int32)
 _EMPTY_I64 = np.empty(0, dtype=np.int64)
@@ -99,6 +104,20 @@ def _unpack_row(prow: np.ndarray) -> np.ndarray:
                         bitorder="little").reshape(wnz.size, 32)
     rr, cc = np.nonzero(sub)
     return (wnz[rr].astype(np.int64) << 5) | cc
+
+
+def _endpoint_code(p) -> int:
+    """A range endpoint of the integer key domain as one order-preserving
+    int: key k -> 2k, _Successor(k) -> 2k + 1, so `[k, k+)` (a key txn
+    inside a range subject) and `[k, k+1)` (an intersection one key wide)
+    stay two rows and sort as Range orders them."""
+    if isinstance(p, _Successor):
+        return 2 * int(p.key) + 1
+    return 2 * int(p)
+
+
+def _code_endpoint(c: int):
+    return _Successor(c >> 1) if c & 1 else c >> 1
 
 
 class DepsResolver:
@@ -1107,6 +1126,14 @@ class _RangeArena:
         self._encoded_of: Dict[TxnId, List[Tuple[int, int]]] = {}
         self.starts = np.zeros(self.cap, dtype=np.int32)
         self.ends = np.zeros(self.cap, dtype=np.int32)
+        # host only: each row's endpoints as order codes (_endpoint_code),
+        # which keep a _Successor end apart from the next key that the
+        # int32 lanes fold it onto -- the harvest decode cuts exact
+        # intersections from these without touching a Range
+        self.codes = np.zeros((self.cap, 2), dtype=np.int64)
+        # (start code, end code) -> the Range: a RangeDeps row the harvest
+        # decode has made before is not made again (Ranges are immutable)
+        self.row_memo: Dict[Tuple[int, int], Range] = {}
         self.ts = np.zeros((self.cap, 3), dtype=np.int32)
         self.kinds = np.zeros(self.cap, dtype=np.int32)
         self.valid = np.zeros(self.cap, dtype=bool)
@@ -1214,10 +1241,11 @@ class _RangeArena:
                 self._dirty_valid.add(r)
         enc3 = self.owner.encoder.encode_one(txn_id)
         rows = []
-        for (s, e) in encoded:
+        for (s, e), r in zip(encoded, merged):
             row = self._free.pop() if self._free else self._alloc_tail()
             self.starts[row] = s
             self.ends[row] = e
+            self.codes[row] = (_endpoint_code(r.start), _endpoint_code(r.end))
             self.ts[row] = enc3
             self.kinds[row] = int(txn_id.kind)
             self.valid[row] = True
@@ -1241,6 +1269,7 @@ class _RangeArena:
         self.ids_np = ids
         self.starts = np.pad(self.starts, (0, new_cap - self.cap))
         self.ends = np.pad(self.ends, (0, new_cap - self.cap))
+        self.codes = np.pad(self.codes, ((0, new_cap - self.cap), (0, 0)))
         self.ts = np.pad(self.ts, ((0, new_cap - self.cap), (0, 0)))
         self.kinds = np.pad(self.kinds, (0, new_cap - self.cap))
         self.valid = np.pad(self.valid, (0, new_cap - self.cap))
@@ -1265,16 +1294,19 @@ class _RangeArena:
         self._free = []
         self.starts[:] = 0
         self.ends[:] = 0
+        self.codes[:] = 0
         self.ts[:] = 0
         self.kinds[:] = 0
         self.valid[:] = False
         for t, encoded in live:
             enc3 = self.owner.encoder.encode_one(t)
             rows = []
-            for (s, e) in encoded:
+            for (s, e), r in zip(encoded, self.ranges_of[t]):
                 row = self._alloc_tail()
                 self.starts[row] = s
                 self.ends[row] = e
+                self.codes[row] = (_endpoint_code(r.start),
+                                   _endpoint_code(r.end))
                 self.ts[row] = enc3
                 self.kinds[row] = int(t.kind)
                 self.valid[row] = True
@@ -1404,7 +1436,7 @@ class _Group:
                  "kseq", "rseq", "fin_dev", "fin_np", "fin_slots",
                  "rfin_dev", "rfin_np", "rents",
                  "rk_slots", "rkfin_dev", "rkfin_np",
-                 "fin_mat", "rmat", "rk_mat")
+                 "fin_mat", "rmat", "rk_mat", "fin_pos", "rtab")
 
     def __init__(self, store, arena):
         self.store = store
@@ -1429,13 +1461,17 @@ class _Group:
         self.fin_dev = None
         self.fin_np = None
         # (flat_key list, key_off) in legacy-decode slot order, or None
-        # when this group planned no finalized key call
+        # when this group planned no finalized key call; fin_pos: each
+        # slot's key's place in its item's owned keys (the slot numbering
+        # the key lane shares with the interval stab's point entries)
         self.fin_slots = None
+        self.fin_pos = None
         self.rfin_dev = None
         self.rfin_np = None
         # [(global interval-CSR entry, local item index, key)] -- `key` is
         # _RSUB for a range subject's own interval pieces -- or None
         self.rents = None
+        self.rtab = None              # _RentTable of rents, cut on first use
         # range-subject KEY-arena stab lane: [(local item index, key)] per
         # finalize_csr slot (empty list: planned with no covered arena
         # keys; None: not planned -- candidate fallback), plus its device
@@ -1447,11 +1483,103 @@ class _Group:
         # pre-materialized under still-valid pins just before an arena
         # mutation would bump the sequence guards -- plain host objects,
         # immune to the mutation, consumed by _decode_core at harvest.
-        # The range-side lanes cache stage 1 only (rows resolved to txn
-        # ids); their host-map filters run at harvest either way
+        # The range-side lanes cache stage 1 only (a _Stab: the hit
+        # (entry, row) arrays and the row tables as pinned then); their
+        # host-map filters run at harvest
         self.fin_mat = None           # key lane: [KeyDeps] per item
-        self.rmat = None              # range lane: [(j, key, [txn ids])]
-        self.rk_mat = None            # rk lane: [(j, key, [txn ids])]
+        self.rmat = None              # range lane: _Stab over rents
+        self.rk_mat = None            # rk lane: _Stab over rk_slots
+
+
+class _RentTable:
+    """g.rents as arrays, one element an entry: its interval-CSR position
+    `e`, its item `j`, its place `pos` among its item's entries (a key
+    subject's are 1:1 with its owned keys, a range subject's with its owned
+    ranges, both in owned order), `sub` (a range subject's own piece, the
+    _RSUB entries) and there the piece's endpoint codes `cs`, `ce`."""
+
+    __slots__ = ("e", "j", "pos", "sub", "cs", "ce")
+
+
+class _Stab:
+    """Stage 1 of a finalized range-side lane as arrays over the whole
+    dispatch: one (slot, row) pair a dependency the device delivered --
+    `slot` indexes the lane's routing table (g.rents / g.rk_slots), `row`
+    the arena's rows -- with every subject's own rows and freed rows
+    masked out, beside the arena's row tables (`ids`, the three `ts` lanes,
+    `live`; None: every row) as they stood while the lane's pins held:
+    copies, so a fence's cache outlives the mutation it ran ahead of. An
+    interval-stab lane also carries each pair's intersection as endpoint
+    codes (`xs`, `xe`; read on a range subject's own pieces only)."""
+
+    __slots__ = ("slot", "row", "ids", "ts", "live", "xs", "xe")
+
+    def __init__(self):
+        self.live = self.xs = self.xe = None
+
+    def take(self, keep: np.ndarray) -> None:
+        self.slot = self.slot[keep]
+        self.row = self.row[keep]
+        if self.xs is not None:
+            self.xs = self.xs[keep]
+            self.xe = self.xe[keep]
+
+
+def _joint_rank(tables):
+    """One TxnId order over the rows of several arenas' tables [(ids, ts
+    lanes, live mask or None)]: the node encoder's three lanes are common
+    to a node's arenas and order as TxnIds do. -> ([rank by row, one array
+    a table; -1 on a dead row], ids by rank). One txn in two tables shares
+    a rank, so an answer that joins two arenas dedupes by it."""
+    ids = np.concatenate([t[0] for t in tables])
+    ts = np.concatenate([t[1] for t in tables])
+    live = np.concatenate([np.ones(len(t[0]), bool) if t[2] is None
+                           else t[2] for t in tables])
+    rows = np.flatnonzero(live)
+    lts = ts[rows]
+    rows = rows[np.lexsort((lts[:, 2], lts[:, 1], lts[:, 0]))]
+    sts = ts[rows]
+    new = np.ones(rows.size, bool)
+    new[1:] = (sts[1:] != sts[:-1]).any(axis=1)
+    rank = np.full(ids.size, -1, np.int64)
+    rank[rows] = np.cumsum(new) - 1
+    cuts = np.cumsum([len(t[0]) for t in tables])[:-1]
+    return np.split(rank, cuts), ids[rows[new]]
+
+
+def _sort_entries(slot: np.ndarray, rank: np.ndarray, r: int):
+    """(slot, rank) pairs in (slot, rank) order, each once: one sort of
+    one int64 key a pair."""
+    key = slot * r + rank
+    key.sort()
+    if key.size > 1:
+        dup = key[1:] == key[:-1]
+        if dup.any():
+            key = key[np.r_[True, ~dup]]
+    e_slot = key // r
+    return e_slot, key - e_slot * r
+
+
+def _cut_csr(e_slot: np.ndarray, e_rank: np.ndarray, slot_off: np.ndarray,
+             by_rank: np.ndarray, row_objects, make, out) -> None:
+    """Per-item CSR assembly from sorted (slot, rank) pairs, step 8 of
+    _assemble_key_deps for either domain: item i owns the slots
+    [slot_off[i], slot_off[i+1]); its rows are the slots present (their
+    keys or Ranges, made once a present slot by `row_objects(slots)` ->
+    list), its dictionary the ranks present, in TxnId order. out[i] =
+    make(rows, txn_ids, offsets, value_idx) where the item has any pair."""
+    first = np.flatnonzero(np.r_[True, e_slot[1:] != e_slot[:-1]])
+    rows = row_objects(e_slot[first])
+    bounds = np.searchsorted(e_slot, slot_off)
+    row_at = np.searchsorted(first, bounds).tolist()
+    bounds = bounds.tolist()
+    for i in np.flatnonzero(np.diff(bounds)).tolist():
+        a, b = bounds[i], bounds[i + 1]
+        ra, rb = row_at[i], row_at[i + 1]
+        uniq, inv = np.unique(e_rank[a:b], return_inverse=True)
+        out[i] = make(tuple(rows[ra:rb]), tuple(by_rank[uniq].tolist()),
+                      tuple((first[ra:rb] - a).tolist()) + (b - a,),
+                      tuple(inv.tolist()))
 
 
 def _dev_ready(dev) -> bool:
@@ -1666,6 +1794,11 @@ class BatchDepsResolver(DepsResolver):
     range_subjects = RegCounter("resolver.range_subjects")
     range_intervals = RegCounter("resolver.range_intervals")
     range_deps = RegCounter("resolver.range_deps")
+    # groups whose range lanes decoded as arrays over the dispatch, and
+    # those among them that applied stage 2's host-map filters a
+    # dependency at a time (a fenced cache whose guards broke since)
+    range_array_decodes = RegCounter("resolver.range_array_decodes")
+    range_filtered_decodes = RegCounter("resolver.range_filtered_decodes")
     # host launch time of the sharded finalize compaction (per-shard
     # popcount/prefix + gather-merge) on multi-device meshes
     shard_merge_s = RegTimer("resolver.shard_merge_s")
@@ -2655,16 +2788,18 @@ class BatchDepsResolver(DepsResolver):
         want_host_bound = not self.device_out_bound or pol.cold
         pos_of = {i: j for j, i in enumerate(g.idx)}
         flat_key: List[object] = []
+        slot_pos: List[int] = []
         slot_subj: List[int] = []
         slot_kid: List[int] = []
         key_cnt = np.zeros(len(g.items), np.int64)
         bound = 0
         for i, item in pairs:
             cnt = 0
-            for k in item.owned:    # Keys iterates sorted unique
+            for p, k in enumerate(item.owned):  # Keys iterates sorted unique
                 if arena.key_rows.get(k) is None:
                     continue
                 flat_key.append(k)
+                slot_pos.append(p)
                 slot_subj.append(i)
                 slot_kid.append(arena.kid_of[k])
                 if want_host_bound:
@@ -2673,6 +2808,7 @@ class BatchDepsResolver(DepsResolver):
             key_cnt[pos_of[i]] = cnt
         key_off = np.concatenate(([0], np.cumsum(key_cnt)))
         g.fin_slots = (flat_key, key_off)
+        g.fin_pos = np.asarray(slot_pos, np.int64)
         if not flat_key:
             return      # no key has arena rows: the group decodes to EMPTY
         s = nnz_tier(len(flat_key))
@@ -3121,21 +3257,18 @@ class BatchDepsResolver(DepsResolver):
             pol.observe(int(dbound), n)
         return pol
 
-    def _materialize_finalized(self, call: _Call, g: _Group):
-        """Slice-and-wrap: one store's key-domain deps straight from the
-        device-finalized (indptr, dep_rows) CSR -- no unpackbits, no
-        membership gather, no row translation (kseq/gen guards upstream
-        certify rows and slots still mean what the kernel saw). Returns
-        [KeyDeps] per group item, or None when the compaction overflowed
-        its out_cap tier (caller falls back to the legacy decode)."""
-        from accord_tpu.primitives.deps import KeyDeps
+    def _stab_finalized(self, call: _Call, g: _Group):
+        """Stage 1 of the key lane's finalized harvest: the device's
+        (indptr, dep_rows) CSR as flat (slot, row) pairs -- no unpackbits,
+        no membership gather, no row translation (kseq/gen guards upstream
+        certify rows and slots still mean what the kernel saw). Checksum
+        verified and the bound folded into the out-cap policy first. None
+        when the compaction overflowed its out_cap tier or the readback is
+        unusable (caller falls back to the legacy decode)."""
         arena = g.arena
-        items = g.items
-        n = len(items)
-        flat_key, key_off = g.fin_slots
-        out = [KeyDeps.EMPTY] * n
+        flat_key, _ = g.fin_slots
         if not flat_key:
-            return out      # no key had arena rows at plan time
+            return _EMPTY_I64, _EMPTY_I64   # no key had arena rows at plan
         buf = self._fetch_np(g, "fin_np", g.fin_dev)
         if buf is None:
             return None     # kernel never launched (defensive)
@@ -3165,32 +3298,98 @@ class BatchDepsResolver(DepsResolver):
             return None
         h_slot = np.repeat(np.arange(ns), np.diff(indptr[:ns + 1]))
         h_row = dep_rows[:total].astype(np.int64)
-        # covered maps are read at HARVEST time in both paths (the legacy
-        # decode builds flat_cov here too), so elision stays in lockstep
+        return h_slot, h_row
+
+    @staticmethod
+    def _slot_covers(g: _Group):
+        """The key lane's per-slot covered maps, read at HARVEST time in
+        both decode paths (the legacy decode builds flat_cov then too), so
+        elision stays in lockstep -> (flat_cov, covered_any, slot_item:
+        which item owns each flat slot)."""
+        flat_key, key_off = g.fin_slots
+        items = g.items
+        slot_item = np.repeat(np.arange(len(items)), np.diff(key_off))
         flat_cov: List[Optional[dict]] = []
         covered_any = False
-        # slot_item: which item owns each flat slot (key_off is per-item)
-        slot_item = np.repeat(np.arange(n), np.diff(key_off))
-        for s in range(ns):
+        for s in range(len(flat_key)):
             cfks = items[int(slot_item[s])].store.cfks
             c = cfks.get(flat_key[s])
             cov = c.covered if c is not None and c.covered else None
             flat_cov.append(cov)
             covered_any = covered_any or cov is not None
-        return self._assemble_key_deps(arena, items, h_slot, h_row, flat_key,
-                                       flat_cov, covered_any, slot_item,
-                                       key_off, out)
+        return flat_cov, covered_any, slot_item
+
+    def _finish_finalized(self, g: _Group, stab):
+        """Slice-and-wrap: one store's key-domain deps from the key lane's
+        stage 1 -> [KeyDeps] per group item."""
+        flat_key, key_off = g.fin_slots
+        h_slot, h_row = stab
+        out = [KeyDeps.EMPTY] * len(g.items)
+        if not flat_key:
+            return out
+        flat_cov, covered_any, slot_item = self._slot_covers(g)
+        return self._assemble_key_deps(g.arena, g.items, h_slot, h_row,
+                                       flat_key, flat_cov, covered_any,
+                                       slot_item, key_off, out)
+
+    def _materialize_finalized(self, call: _Call, g: _Group):
+        """Both stages of the key lane's finalized harvest. None when the
+        lane has no usable result (caller falls back to the legacy
+        decode)."""
+        stab = self._stab_finalized(call, g)
+        return None if stab is None else self._finish_finalized(g, stab)
+
+    # -- the range lanes, decoded as arrays over the whole dispatch -----------
+    @staticmethod
+    def _rent_table(g: _Group) -> "_RentTable":
+        """g.rents as arrays, cut once a group (plan-time facts only, so
+        the fence and the harvest read the same table)."""
+        tab = g.rtab
+        if tab is not None:
+            return tab
+        rents = g.rents
+        m = len(rents)
+        tab = g.rtab = _RentTable()
+        tab.e = np.fromiter((e for e, _, _ in rents), np.int64, m)
+        tab.j = np.fromiter((j for _, j, _ in rents), np.int64, m)
+        tab.sub = np.fromiter((k is _RSUB for _, _, k in rents), bool, m)
+        # an item's entries are consecutive: its keys, or its pieces, in
+        # the order it owns them
+        first = np.flatnonzero(np.r_[True, tab.j[1:] != tab.j[:-1]])
+        tab.pos = np.arange(m) - np.repeat(first, np.diff(np.r_[first, m]))
+        tab.cs = np.zeros(m, np.int64)
+        tab.ce = np.zeros(m, np.int64)
+        for t in np.flatnonzero(tab.sub).tolist():
+            r = g.items[tab.j[t]].owned[tab.pos[t]]
+            tab.cs[t] = _endpoint_code(r.start)
+            tab.ce[t] = _endpoint_code(r.end)
+        return tab
+
+    @staticmethod
+    def _mask_own_rows(st: "_Stab", slot_item: np.ndarray, items, rows_of):
+        """Self is never a dep: drop each subject's pairs on its own rows
+        (`rows_of`: txn id -> its row or rows in the lane's arena)."""
+        keep = None
+        for j in np.unique(slot_item).tolist():
+            own = rows_of.get(items[j].txn_id)
+            if own is None or own == []:
+                continue
+            hit = (slot_item[st.slot] == j) & np.isin(st.row, own)
+            if hit.any():
+                keep = ~hit if keep is None else keep & ~hit
+        if keep is not None:
+            st.take(keep)
 
     def _stab_range_finalized(self, call: _Call, g: _Group):
-        """Stage 1 of the interval-stab harvest (pin-dependent): resolve
-        each entry's CSR segment to txn ids through the arena's row->txn
-        table -- rgen/rseq holding certifies the mapping is the one the
-        kernel stabbed. Each segment's rows already passed the interval,
-        witness, and before tests ON DEVICE. Returns [(local item index,
-        key-or-_RSUB, [txn ids])] or None on overflow / no buffer. The
-        mutation fence runs this stage under still-valid pins; stage 2
-        (_finish_range_finalized) is host-map-dependent and always runs
-        at harvest."""
+        """Stage 1 of the interval-stab harvest (pin-dependent): every
+        entry's CSR segment as flat (entry, arena row) pairs, with each
+        pair's intersection cut from the endpoint codes -- rgen/rseq
+        holding certifies the rows are the ones the kernel stabbed. Each
+        pair already passed the interval, witness, and before tests ON
+        DEVICE. Returns a _Stab over g.rents, or None on overflow / no
+        buffer. The mutation fence runs this stage under still-valid pins;
+        stage 2 (_filter_range_stab) is host-map-dependent and runs at
+        harvest, where the guards no longer hold."""
         if g.rfin_dev is None and g.rfin_np is None:
             return None
         buf = self._fetch_np(g, "rfin_np", g.rfin_dev)
@@ -3205,98 +3404,85 @@ class BatchDepsResolver(DepsResolver):
             # change or an undersized warm estimate can land here)
             pol.overflowed()
             return None
-        ids = g.arena.ranges.ids_np
-        raw: List[tuple] = []
-        for e, j, k in g.rents:
-            lo, hi = int(indptr[e]), int(indptr[e + 1])
-            if lo == hi:
+        ranges = g.arena.ranges
+        tab = self._rent_table(g)
+        lo = indptr[tab.e].astype(np.int64)
+        cnt = indptr[tab.e + 1] - lo
+        total = int(cnt.sum())
+        st = _Stab()
+        st.slot = np.repeat(np.arange(cnt.size), cnt)
+        # each entry's segment, gathered in entry order
+        at = np.arange(total) + np.repeat(lo - (np.cumsum(cnt) - cnt), cnt)
+        st.row = dep_rows[at].astype(np.int64)
+        n = ranges.count
+        st.ids = ranges.ids_np[:n].copy()
+        st.ts = ranges.ts[:n].copy()
+        st.live = ranges.valid[:n].copy()
+        # a freed row answers for no txn (never met while rseq holds)
+        ok = st.live[st.row]
+        if not ok.all():
+            st.take(ok)
+        self._mask_own_rows(st, tab.j, g.items, ranges.rows_of)
+        codes = ranges.codes
+        st.xs = np.maximum(tab.cs[st.slot], codes[st.row, 0])
+        st.xe = np.minimum(tab.ce[st.slot], codes[st.row, 1])
+        return st
+
+    def _filter_range_stab(self, g: _Group, st: "_Stab") -> "_Stab":
+        """Stage 2 of the interval stab where its guards broke after a
+        fence cached stage 1: the store's CURRENT range_txns membership
+        and containment, a dependency at a time -- the filters the legacy
+        candidate decode applies at harvest, so the fenced cache decodes
+        as the guarded path would have after the same truncation. A range
+        subject's hit txn answers with its CURRENT ranges' intersections.
+        (While rgen/rseq hold, these filters are no-ops -- rseq certifies
+        every stabbed row's txn registered with the same ranges, and the
+        device's stab was exact -- and the harvest skips this stage.)"""
+        tab = g.rtab
+        rt = g.store.range_txns
+        keep = np.zeros(st.slot.size, bool)
+        seen = set()
+        extra: List[tuple] = []
+        for t, (ent, row) in enumerate(zip(st.slot.tolist(),
+                                           st.row.tolist())):
+            rid = st.ids[row]
+            rngs = rt.get(rid)
+            if rngs is None:
                 continue
-            tid = g.items[j].txn_id
-            raw.append((j, k, [rid for rid in
-                               (ids[row] for row in dep_rows[lo:hi])
-                               if rid is not None and rid != tid]))
-        return raw
-
-    def _finish_range_finalized(self, g: _Group, raw):
-        """Stage 2 (host-map-dependent): apply the store's CURRENT
-        range_txns membership and containment -- the exact filters the
-        legacy candidate decode applies at harvest time, so a
-        fence-cached stage 1 decodes bit-identically to the guarded
-        path even when a truncation landed in between. (While the guards
-        hold these filters are no-ops: rseq certifies every stabbed
-        row's txn is still registered with the same ranges.) Key-subject
-        point entries decode to that key's range-txn deps; _RSUB entries
-        (a range subject's own pieces) to its range-vs-range deps -- the
-        hit txn's ranges intersected with the subject's owned set.
-        Returns (kmap: item -> KeyDeps, rsub: item -> RangeDepsBuilder)
-        -- builders, so the key-arena rk lane can merge into them."""
-        builders: Dict[int, KeyDepsBuilder] = {}
-        rsub: Dict[int, RangeDepsBuilder] = {}
-        # range-vs-range deps delivered: one stabbed row per (subject piece,
-        # dep range), so one per (intersection, txn) of the answers
-        delivered = 0
-        for j, k, rids in raw:
-            item = g.items[j]
-            rt = item.store.range_txns
-            if k is _RSUB:
-                rb = rsub.get(j)
-                if rb is None:
-                    rb = rsub[j] = RangeDepsBuilder()
-                delivered += len(rids)
-                for rid in rids:
-                    rngs = rt.get(rid)
-                    if rngs is None:
-                        delivered -= 1
-                        continue
-                    for r in rngs.intersection(item.owned):
-                        rb.add(r, rid)
+            if not tab.sub[ent]:
+                keep[t] = rngs.contains_key(g.rents[ent][2])
                 continue
-            kb = builders.get(j)
-            if kb is None:
-                kb = builders[j] = KeyDepsBuilder()
-            for rid in rids:
-                rngs = rt.get(rid)
-                if rngs is None or not rngs.contains_key(k):
-                    continue
-                kb.add(k, rid)
-        if delivered:
-            self.range_deps += delivered
-        return {j: kb.build() for j, kb in builders.items()}, rsub
-
-    def _materialize_range_finalized(self, call: _Call, g: _Group):
-        """Both stages of the interval-stab harvest (the guarded,
-        unfenced path). None on overflow / no buffer (caller falls back
-        to the candidate decode)."""
-        raw = self._stab_range_finalized(call, g)
-        if raw is None:
-            return None
-        return self._finish_range_finalized(g, raw)
-
-    def _materialize_rkey_finalized(self, call: _Call, g: _Group,
-                                    rsub: Dict[int, RangeDepsBuilder]) -> bool:
-        """Range subjects' KEY-arena deps from the device-exact rk lane:
-        each (subject, covered key) slot's CSR segment already passed the
-        exact kid row-mask, witness, and before tests on device, so the
-        host keeps only the rules the candidate decode also applies at
-        harvest time -- cfk membership, INVALIDATED status, and
-        covered-elision. Merges point deps into `rsub`'s builders. False ->
-        overflow or missing buffer (caller falls back to the candidate
-        decode)."""
-        raw = self._stab_rkey_finalized(call, g)
-        if raw is None:
-            return False
-        self._finish_rkey_finalized(g, raw, rsub)
-        return True
+            j = int(tab.j[ent])
+            if (j, rid) in seen:
+                continue
+            seen.add((j, rid))
+            extra.extend((ent, row, _endpoint_code(r.start),
+                          _endpoint_code(r.end))
+                         for r in rngs.intersection(g.items[j].owned))
+        st.take(keep)
+        if extra:
+            more = np.asarray(extra, np.int64)
+            st.slot = np.concatenate((st.slot, more[:, 0]))
+            st.row = np.concatenate((st.row, more[:, 1]))
+            st.xs = np.concatenate((st.xs, more[:, 2]))
+            st.xe = np.concatenate((st.xe, more[:, 3]))
+        return st
 
     def _stab_rkey_finalized(self, call: _Call, g: _Group):
-        """Stage 1 of the rk-lane harvest (pin-dependent): per-slot dep
-        txn ids through the key arena's row->txn table (gen/kseq holding
-        certifies it). Returns [(local item index, key, [txn ids])] --
-        [] when the lane was planned with no covered arena keys -- or
-        None on overflow / missing buffer. The mutation fence runs this
-        under still-valid pins; stage 2 always runs at harvest."""
+        """Stage 1 of the rk-lane harvest (pin-dependent): the lane's CSR
+        as flat (slot, key-arena row) pairs (gen/kseq holding certifies
+        them). Returns a _Stab over g.rk_slots -- empty when the lane was
+        planned with no covered arena keys -- or None on overflow / missing
+        buffer. The mutation fence runs this under still-valid pins; stage
+        2 always runs at harvest."""
+        arena = g.arena
+        n = arena.count
+        st = _Stab()
+        st.slot = st.row = _EMPTY_I64
+        st.ids = arena.ids_np[:n].copy()
+        st.ts = arena.ts[:n].copy()
         if not g.rk_slots:
-            return []       # planned, but no covered key had an arena id
+            return st       # planned, but no covered key had an arena id
         if g.rkfin_dev is None and g.rkfin_np is None:
             return None
         buf = self._fetch_np(g, "rkfin_np", g.rkfin_dev)
@@ -3304,47 +3490,186 @@ class BatchDepsResolver(DepsResolver):
             return None     # corrupted readback: caught before decode
         indptr, dep_rows, _, dbound, _ = buf
         ns = len(g.rk_slots)
-        pol = self._observe_bound(g.arena, "rkey", dbound, ns)
-        if int(indptr[ns]) > dep_rows.shape[0]:
+        pol = self._observe_bound(arena, "rkey", dbound, ns)
+        total = int(indptr[ns])
+        if total > dep_rows.shape[0]:
             pol.overflowed()
             return None
-        ids = g.arena.ids_np
-        raw: List[tuple] = []
-        for s, (j, k) in enumerate(g.rk_slots):
-            lo, hi = int(indptr[s]), int(indptr[s + 1])
-            if lo == hi:
-                continue
-            tid = g.items[j].txn_id
-            raw.append((j, k, [d for d in
-                               (ids[row] for row in dep_rows[lo:hi])
-                               if d is not None and d != tid]))
-        return raw
+        st.slot = np.repeat(np.arange(ns), np.diff(indptr[:ns + 1]))
+        st.row = dep_rows[:total].astype(np.int64)
+        slot_item = np.fromiter((j for j, _ in g.rk_slots), np.int64, ns)
+        self._mask_own_rows(st, slot_item, g.items, arena.row_of)
+        return st
 
-    def _finish_rkey_finalized(self, g: _Group, raw,
-                               rsub: Dict[int, RangeDepsBuilder]) -> None:
-        """Stage 2 (host-map-dependent): cfk membership, INVALIDATED
-        status and covered-elision against the store's CURRENT maps --
-        the candidate decode's harvest-time rules -- merged into
-        `rsub`'s builders as point deps."""
-        for j, k, dep_ids in raw:
-            item = g.items[j]
-            c = item.store.cfks.get(k)
+    def _filter_rkey_stab(self, g: _Group, st: "_Stab", guarded: bool):
+        """Stage 2 of the rk lane (host-map-dependent, always at harvest):
+        the candidate decode's harvest-time cfk rules against the store's
+        CURRENT maps. A slot whose key has no cfk delivers nothing. While
+        gen/kseq hold (`guarded`), a dependency's cfk membership is what
+        the row masks the device read say, as on the key lane, so only the
+        pairs that can still fail a rule are walked: those on a row the
+        arena marks invalidated (a status can change under the guards) and
+        those in a slot whose key has covers (transitive-dependency
+        elision). A fenced cache whose guards broke walks every pair."""
+        slots = g.rk_slots
+        if st.slot.size == 0:
+            return st
+        cfks = g.store.cfks
+        arena = g.arena
+        gone = np.zeros(len(slots), bool)
+        walk = np.zeros(len(slots), bool)
+        for s, (_, k) in enumerate(slots):
+            c = cfks.get(k)
             if c is None:
+                gone[s] = True
+            elif c.covered:
+                walk[s] = True
+        keep = ~gone[st.slot]
+        if not guarded:
+            check = keep.copy()
+        else:
+            check = walk[st.slot]
+            if arena.invalidated:
+                bad = np.zeros(arena.count, bool)
+                bad[list(arena.invalidated)] = True
+                check |= bad[st.row] & keep
+        for t in np.flatnonzero(check).tolist():
+            j, k = slots[st.slot[t]]
+            item = g.items[j]
+            c = cfks[k]
+            dep_id = st.ids[st.row[t]]
+            info = c.get(dep_id)
+            if info is None or info.status == CfkStatus.INVALIDATED:
+                keep[t] = False
                 continue
-            cov = c.covered if c.covered else None
-            rb = rsub.get(j)
-            if rb is None:
-                rb = rsub[j] = RangeDepsBuilder()
-            pt = Range.point(k)
-            for dep_id in dep_ids:
-                info = c.get(dep_id)
-                if info is None or info.status == CfkStatus.INVALIDATED:
-                    continue
-                e = cov.get(dep_id) if cov else None
-                if e is not None and e[0] <= item.cover_seq \
-                        and e[1] < item.before:
-                    continue  # transitive-dependency elision (cfk rule)
-                rb.add(pt, dep_id)
+            e = c.covered.get(dep_id) if c.covered else None
+            if e is not None and e[0] <= item.cover_seq \
+                    and e[1] < item.before:
+                keep[t] = False   # transitive-dependency elision (cfk rule)
+        if not keep.all():
+            st.take(keep)
+        return st
+
+    def _build_key_subjects(self, g: _Group, rst: "_Stab", kstab):
+        """The group's KEY subjects from the interval stab's point entries
+        and, where the key lane's stage 1 was held back for it (`kstab`),
+        that lane's pairs in the same sort: one KeyDeps an item, cut as
+        _assemble_key_deps cuts the key lane's. Slots number (item, place
+        of the key among the item's owned keys), the order both lanes walk;
+        txn ids order by one rank over both arenas' rows. Returns [KeyDeps]
+        per item: whole with `kstab`, else the range-txn deps alone (the
+        caller unions them into whatever decoded the key lane)."""
+        items = g.items
+        n = len(items)
+        tab = g.rtab
+        cnt = np.fromiter((len(it.owned) if isinstance(it.owned, Keys) else 0
+                           for it in items), np.int64, n)
+        off = np.concatenate(([0], np.cumsum(cnt)))
+        tables = [(rst.ids, rst.ts, rst.live)]
+        if kstab is not None:
+            arena = g.arena
+            tables.append((arena.ids_np[:arena.count],
+                           arena.ts[:arena.count], None))
+        ranks, by_rank = _joint_rank(tables)
+        pick = ~tab.sub[rst.slot]
+        ent = rst.slot[pick]
+        slot = off[tab.j[ent]] + tab.pos[ent]
+        rank = ranks[0][rst.row[pick]]
+        if kstab is not None:
+            h_slot, h_row = kstab
+            flat_cov, covered_any, slot_item = self._slot_covers(g)
+            if covered_any and h_slot.size:
+                # transitive-dependency elision, only over slots with
+                # covers: the key lane's rule (_assemble_key_deps, step 7)
+                has = np.fromiter((c is not None for c in flat_cov), bool,
+                                  len(flat_cov))
+                keep = np.ones(h_slot.size, bool)
+                ids = g.arena.ids_np
+                for t in np.flatnonzero(has[h_slot]).tolist():
+                    s = h_slot[t]
+                    item = items[slot_item[s]]
+                    e = flat_cov[s].get(ids[h_row[t]])
+                    if e is not None and e[0] <= item.cover_seq \
+                            and e[1] < item.before:
+                        keep[t] = False
+                h_slot, h_row = h_slot[keep], h_row[keep]
+            slot = np.concatenate(
+                (slot, off[slot_item[h_slot]] + g.fin_pos[h_slot]))
+            rank = np.concatenate((rank, ranks[1][h_row]))
+        out = [KeyDeps.EMPTY] * n
+        if slot.size:
+            keys = [k for it in items if isinstance(it.owned, Keys)
+                    for k in it.owned]
+            e_slot, e_rank = _sort_entries(slot, rank, len(by_rank))
+            _cut_csr(e_slot, e_rank, off, by_rank,
+                     lambda slots: [keys[u] for u in slots.tolist()],
+                     KeyDeps, out)
+        return out
+
+    def _build_range_subjects(self, g: _Group, rst, kst):
+        """The group's RANGE subjects from both stab lanes: the interval
+        stab's intersections (`rst`; None: no interval row at plan time)
+        and the rk lane's key txns as point ranges (`kst`; likewise), one
+        sort -> {item: RangeDeps}. Rows are the distinct (item, start, end)
+        of the two lanes in Range order, by endpoint codes; a Range is made
+        once a row."""
+        n = len(g.items)
+        tables, c_j, c_s, c_e, slot, row_of = [], [], [], [], [], []
+        nk = 0
+        if kst is not None and kst.slot.size:
+            rk = np.asarray(g.rk_slots, np.int64).reshape(-1, 2)
+            nk = len(rk)
+            c_j.append(rk[:, 0])
+            c_s.append(2 * rk[:, 1])
+            c_e.append(2 * rk[:, 1] + 1)
+            slot.append(kst.slot)
+            row_of.append((len(tables), kst.row))
+            tables.append((kst.ids, kst.ts, None))
+        if rst is not None:
+            pick = g.rtab.sub[rst.slot]
+            if pick.any():
+                c_j.append(g.rtab.j[rst.slot[pick]])
+                c_s.append(rst.xs[pick])
+                c_e.append(rst.xe[pick])
+                slot.append(nk + np.arange(c_j[-1].size))
+                row_of.append((len(tables), rst.row[pick]))
+                tables.append((rst.ids, rst.ts, rst.live))
+                self.range_deps += int(c_j[-1].size)
+        out: Dict[int, RangeDeps] = {}
+        if not tables:
+            return out
+        c_j, c_s, c_e = (np.concatenate(c) for c in (c_j, c_s, c_e))
+        # the candidate rows in Range order an item, densely numbered
+        order = np.lexsort((c_e, c_s, c_j))
+        s_j, s_s, s_e = c_j[order], c_s[order], c_e[order]
+        new = np.ones(order.size, bool)
+        new[1:] = (s_j[1:] != s_j[:-1]) | (s_s[1:] != s_s[:-1]) \
+            | (s_e[1:] != s_e[:-1])
+        dense = np.empty(order.size, np.int64)
+        dense[order] = np.cumsum(new) - 1
+        u_j, u_s, u_e = s_j[new], s_s[new], s_e[new]
+        ranks, by_rank = _joint_rank(tables)
+        e_slot, e_rank = _sort_entries(
+            dense[np.concatenate(slot)],
+            np.concatenate([ranks[t][rows] for t, rows in row_of]),
+            len(by_rank))
+        memo = g.arena.ranges.row_memo
+        if len(memo) > _ROW_MEMO_CAP:
+            memo.clear()
+
+        def ranges_of(slots):
+            rows = []
+            for code in zip(u_s[slots].tolist(), u_e[slots].tolist()):
+                r = memo.get(code)
+                if r is None:
+                    r = memo[code] = Range(_code_endpoint(code[0]),
+                                           _code_endpoint(code[1]))
+                rows.append(r)
+            return rows
+
+        off = np.searchsorted(u_j, np.arange(n + 1))
+        _cut_csr(e_slot, e_rank, off, by_rank, ranges_of, RangeDeps, out)
+        return out
 
     def _decode_key_range_deps(self, arena: _StoreArena, rgen: int,
                                rprow: np.ndarray, item: _Item):
@@ -3441,7 +3766,6 @@ class BatchDepsResolver(DepsResolver):
         key-domain rows translate through the pinned row snapshot, range
         candidates translate by txn id. Falls back to the host scan only
         when no snapshot survived (counted; not expected)."""
-        from accord_tpu.primitives.deps import KeyDeps
         results: List[Optional[Deps]] = [None] * len(call.items)
         if call.degraded:
             # the dispatch was given up on (launch-retry exhaustion or a
@@ -3463,6 +3787,10 @@ class BatchDepsResolver(DepsResolver):
             key_stale = has_pk and g.gen != arena.gen
             gp = grp = gkp = None
             kds = None
+            # the key lane's stage 1, held back where the group's interval
+            # stab can join it: a key subject's two KeyDeps then come out
+            # of one sort as one (_build_key_subjects)
+            kstab = None
             if g.fin_slots is not None:
                 if g.fin_mat is not None:
                     # the mutation fence materialized this lane while its
@@ -3472,14 +3800,18 @@ class BatchDepsResolver(DepsResolver):
                     # device-finalized CSR harvest: exact rows, no raw
                     # readback (empty slot list short-circuits to
                     # all-EMPTY inside)
-                    kds = self._materialize_finalized(call, g)
-                if kds is not None:
+                    kstab = self._stab_finalized(call, g)
+                    if kstab is not None \
+                            and (g.rents is None or call.canary):
+                        kds = self._finish_finalized(g, kstab)
+                        kstab = None
+                if kds is not None or kstab is not None:
                     self.finalized_decodes += 1
                     if call.canary and g.fin_mat is None:
                         # probation: check the finalized decode against
                         # the legacy decode of the same plan-time snapshot
                         self._canary_check(call, g, kds)
-            if kds is None and has_pk:
+            if kds is None and kstab is None and has_pk:
                 if g.fin_slots is not None:
                     self.finalize_fallbacks += 1
                 buf = self._fetch_np(call, "np_packed", call.packed)
@@ -3488,18 +3820,26 @@ class BatchDepsResolver(DepsResolver):
                     kds = self._decode_batch(arena, g.items, gp)
                     self.legacy_decodes += 1
             # the range lanes, under their own span inside
-            # resolver.materialize: exact per-entry segments for the
-            # group's KEY subjects (rkb) and its range subjects' deps built
-            # from both stab lanes (rsub_deps; None -> candidate decode of
-            # grp / gkp)
-            rkb = rsub_deps = None
+            # resolver.materialize: the group's KEY subjects' range-txn
+            # deps (rkb; or, with the key lane's stage 1 held, their whole
+            # KeyDeps) and its range subjects' deps built from both stab
+            # lanes (rsub_deps; None -> candidate decode of grp / gkp)
+            rkb = rsub_deps = joined = None
             has_rsub = any(not isinstance(it.owned, Keys)
                            and it.fallback is None for it in g.items)
             if g.rents is not None or has_rp or has_kp or has_rsub:
                 with self._phase("resolver.range_decode",
                                  "resolver.range_decode_s"):
-                    rkb, rsub_deps, grp, gkp = self._decode_range_lanes(
-                        call, g, idx, key_stale, has_rp, has_kp, has_rsub)
+                    rkb, rsub_deps, grp, gkp, joined = \
+                        self._decode_range_lanes(call, g, idx, key_stale,
+                                                 has_rp, has_kp, has_rsub,
+                                                 kstab)
+                if joined is not None:
+                    kds, kstab = joined, None
+            if kstab is not None:
+                # the interval stab did not materialize: the key lane
+                # decodes alone, as a key-only store's does
+                kds = self._finish_finalized(g, kstab)
             # key subjects' range-txn deps, merged after the item walk
             # (under the same span): (item position, KeyDeps)
             range_unions: List[tuple] = []
@@ -3576,53 +3916,59 @@ class BatchDepsResolver(DepsResolver):
 
     def _decode_range_lanes(self, call: _Call, g: _Group, idx,
                             key_stale: bool, has_rp: bool, has_kp: bool,
-                            has_rsub: bool):
-        """One group's range lanes at harvest: the interval stab's two
-        stages (key subjects' range-txn deps and range subjects'
-        range-vs-range deps), the rk lane's two stages merged into the same
-        builders, and the range subjects' deps built. Returns (rkb: item ->
-        KeyDeps or None, rsub_deps: item -> RangeDeps, or None when some
-        stab lane a range subject needs did not materialize, grp, gkp: the
-        group's slices of the raw candidate buffers the fallbacks need)."""
+                            has_rsub: bool, kstab):
+        """One group's range lanes at harvest, decoded as arrays over the
+        whole dispatch: stage 1 of the interval stab and of the rk lane
+        (or the fence's caches of them), stage 2's host-map filters where
+        a lane's guards no longer hold, then one sort a domain -- the key
+        subjects' range-txn deps (with the key lane's held stage 1,
+        `kstab`, their whole KeyDeps) and the range subjects' deps from
+        both lanes. Returns (rkb: item -> KeyDeps or None, rsub_deps: item
+        -> RangeDeps, or None when some stab lane a range subject needs
+        did not materialize, grp, gkp: the group's slices of the raw
+        candidate buffers the fallbacks need, joined: [KeyDeps] per item
+        where `kstab` was joined in, else None)."""
         arena = g.arena
+        ranges = arena.ranges
         grp = gkp = None
-        # range finalized output: exact per-entry segments for the
-        # group's KEY subjects (kmap) and its range subjects'
-        # range-vs-range deps (rsub builders, from the _RSUB entries)
-        rkb = rsub_rb = None
+        filtered = False
+        # interval stab: point entries for the group's KEY subjects and
+        # its range subjects' own pieces (the _RSUB entries)
+        rst = None
         if g.rents is not None:
-            raw_r = g.rmat
-            if raw_r is None and g.rgen == arena.ranges.gen \
-                    and g.rseq == arena.ranges.rseq:
-                raw_r = self._stab_range_finalized(call, g)
-            if raw_r is not None:
-                # stage 2 runs here either way: current host maps,
-                # so fenced caches decode like guarded ones
-                rkb, rsub_rb = self._finish_range_finalized(g, raw_r)
-        if g.rents is not None and rkb is None:
-            self.finalize_fallbacks += 1
+            rst = g.rmat
+            guarded = g.rgen == ranges.gen and g.rseq == ranges.rseq
+            if rst is None and guarded:
+                rst = self._stab_range_finalized(call, g)
+            if rst is not None and not guarded:
+                # a fenced cache past its guards: current host maps, so
+                # it decodes as a guarded harvest would have
+                rst = self._filter_range_stab(g, rst)
+                filtered = True
+            if rst is None:
+                self.finalize_fallbacks += 1
         # range subjects decode on device only when EVERY stab lane
         # they need materialized: the interval stab above and the
         # key-arena rk lane below (each absent lane corresponds to an
         # arena with no rows at plan time -- correctly empty)
         rsub_ok = has_rsub and self.finalize_on_device
-        if rsub_ok and g.rp is not None and rkb is None:
+        if rsub_ok and g.rp is not None and rst is None:
             rsub_ok = False
+        kst = None
         if rsub_ok and g.kp is not None:
-            raw_rk = g.rk_mat
-            if raw_rk is None and g.rk_slots is not None \
-                    and not key_stale and g.gen == arena.gen \
-                    and g.kseq == arena.kseq:
-                raw_rk = self._stab_rkey_finalized(call, g)
-            if raw_rk is None:
+            kst = g.rk_mat
+            guarded = not key_stale and g.gen == arena.gen \
+                and g.kseq == arena.kseq
+            if kst is None and g.rk_slots is not None and guarded:
+                kst = self._stab_rkey_finalized(call, g)
+            if kst is None:
                 rsub_ok = False
                 if g.rk_slots is not None:
                     self.finalize_fallbacks += 1
             else:
-                if rsub_rb is None:
-                    rsub_rb = {}
-                self._finish_rkey_finalized(g, raw_rk, rsub_rb)
-        need_rp = has_rp and (rkb is None
+                kst = self._filter_rkey_stab(g, kst, guarded)
+                filtered = filtered or not guarded
+        need_rp = has_rp and (rst is None
                               or (has_rsub and not rsub_ok))
         if need_rp:
             buf = self._fetch_np(call, "np_rpacked", call.rpacked)
@@ -3633,10 +3979,21 @@ class BatchDepsResolver(DepsResolver):
             buf = self._fetch_np(call, "np_kpacked", call.kpacked)
             if buf is not None:
                 gkp = buf[idx][:, g.kp[0]:g.kp[1]]
-        rsub_deps = None
+        rkb = rsub_deps = joined = None
+        if rst is not None:
+            built = self._build_key_subjects(g, rst, kstab)
+            if kstab is not None:
+                joined, rkb = built, {}
+            else:
+                rkb = {j: kd for j, kd in enumerate(built)
+                       if not kd.is_empty()}
         if rsub_ok:
-            rsub_deps = {j: rb.build() for j, rb in (rsub_rb or {}).items()}
-        return rkb, rsub_deps, grp, gkp
+            rsub_deps = self._build_range_subjects(g, rst, kst)
+        if rst is not None or rsub_ok:
+            self.range_array_decodes += 1
+            if filtered:
+                self.range_filtered_decodes += 1
+        return rkb, rsub_deps, grp, gkp, joined
 
     def _decode_dispatch(self, call: _Call) -> List[Deps]:
         """The async harvest decode: core recovery + the store's dep floor

@@ -530,6 +530,10 @@ def test_range_timers_fit_their_parents_and_counters_follow_range_subjects(
     assert (n_range > 0) == (share > 0.0)
     assert d.get("resolver.range_subjects", 0) == n_range
     assert d.get("resolver.range_deps", 0) == r["range_range_deps"]
+    # every group's range lanes decode as arrays; nothing here mutates the
+    # store between a launch and its harvest, so no group filters
+    assert d["resolver.range_array_decodes"] == d["resolver.dispatches"]
+    assert "resolver.range_filtered_decodes" not in d
     assert (d.get("resolver.range_intervals", 0) >= n_range) \
         and (d.get("resolver.range_intervals", 0) > 0) == (n_range > 0)
 
@@ -538,7 +542,8 @@ def test_key_only_store_never_enters_the_range_path(resolved):
     _, _, d = resolved
     for name in ("resolver.range_encode_s", "resolver.range_decode_s",
                  "resolver.range_subjects", "resolver.range_intervals",
-                 "resolver.range_deps"):
+                 "resolver.range_deps", "resolver.range_array_decodes",
+                 "resolver.range_filtered_decodes"):
         assert name not in d, name
 
 
