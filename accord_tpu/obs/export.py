@@ -149,7 +149,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         prog="python -m accord_tpu.obs.export",
         description="Summarize a recorded Perfetto trace.")
     ap.add_argument("--summarize", metavar="TRACE_JSON", required=True,
-                    help="path to a trace written by bench.py --trace")
+                    help="path to a trace written by obs.export.write_trace")
     ns = ap.parse_args(argv)
     with open(ns.summarize) as f:
         doc = json.load(f)

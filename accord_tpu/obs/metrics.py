@@ -203,8 +203,8 @@ class MetricsRegistry:
 
     def snapshot_json(self, extra: Optional[dict] = None) -> str:
         """snapshot() as one sorted JSON line -- the shared export behind
-        the serve node's periodic stderr metrics dump and bench_serve's
-        per-leg reports (machine-parseable, diff-stable key order)."""
+        the serve node's periodic stderr metrics dump (machine-parseable,
+        diff-stable key order)."""
         import json
         snap = self.snapshot()
         if extra:
@@ -323,7 +323,6 @@ GLOSSARY: Dict[str, str] = {
     "resolver.materialize_s": "decode minus in-decode readback",
     "resolver.host_hidden_s": "host phase seconds run while a call was in flight",
     "resolver.staged_dispatches": "launches taken off the encode-ahead list",
-    "resolver.padded_dispatches": "fused calls topped up to pad_store_tiers",
     "resolver.prefetched": "harvests whose transfer the readiness poll drained",
     "resolver.polls_armed": "readiness polls armed (device_poll_ms)",
     "resolver.stale_harvests": "calls translated across a compaction",
@@ -343,8 +342,6 @@ GLOSSARY: Dict[str, str] = {
     "resolver.range_intervals": "interval pieces of range-domain subjects encoded",
     "resolver.range_deps": "range-vs-range dependencies delivered from the device stab, one per (intersection, txn)",
     "resolver.shard_merge_s": "sharded finalize launch + fragment-merge wall seconds",
-    "resolver.window_shrinks": "adaptive window scale-down adjustments",
-    "resolver.window_widens": "adaptive window scale-up adjustments",
     # -- resolver device-plane fault handling (ops/fault_plane.py) -----------
     "resolver.device_faults_injected": "injected device faults consumed by the pipeline",
     "resolver.device_retries": "bounded dispatch retries + watchdog probes spent",

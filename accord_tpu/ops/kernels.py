@@ -1002,17 +1002,11 @@ def scatter_nnz_tier(n: int) -> int:
 # is PINNED by the resolver's OutCapTiers hysteresis policy (ops.tiers),
 # fed by the device-computed bound each finalize call reads back -- grow
 # immediately, shrink only after several consecutive quiet dispatches -- so
-# the picked tier is not data-dependent dispatch to dispatch and the bench's
-# zero-recompile assertion covers the finalize kernels without exemption.
-# (With device_out_bound disabled the resolver sizes from the exact host
-# popcount bound instead: the differential baseline.)
+# the picked tier is not data-dependent dispatch to dispatch and a
+# zero-recompile check by jit_cache_sizes() covers the finalize kernels
+# without exemption.
 OUT_TIERS = (256, 2048, 16384)
 OUT_TIER_FLOOR = 32768
-
-
-def out_tier(n: int) -> int:
-    """Padded finalized-CSR entry count for a dispatch with n bound hits."""
-    return snap(n, OUT_TIERS, OUT_TIER_FLOOR)
 
 
 # ---------------------------------------------------------------------------

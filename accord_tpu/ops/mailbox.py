@@ -25,8 +25,8 @@ Message flow per cluster tick:
 
 Overflow is graceful by design: an emit whose payload exceeds the slot width
 or whose destination ring is full simply keeps its host bytes and bumps
-`mailbox_overflow_spills`; the bench steady-state gate asserts that counter
-stays zero at tuned depths.
+`mailbox_overflow_spills`; tests/test_message_plane.py asserts that counter
+stays zero at the default depth.
 
 Sharded meshes (`shards > 1`): the node lanes pad up so shard boundaries
 fall on node boundaries (node v lives on shard v // npsh), the arena and

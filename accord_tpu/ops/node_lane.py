@@ -34,8 +34,8 @@ NODE_SUBJECT_TIERS, the merged CSR to the shared nnz ladder, and the block
 COUNT pads to the resolver's `pad_node_tiers` ladder with cached empty
 arena blocks under slot -1 -- node-count churn (crashes, membership change)
 re-lands on the same compiled tiers, so steady-state burns mint zero new
-jit entries (asserted by bench_mesh_burn via kernels.jit_cache_sizes and
-the node-lane cache sizes below).
+jit entries (asserted over the node-lane cache sizes below by
+tests/test_mesh_burn.py::test_crash_restart_lane_pads_out_without_recompile).
 
 The merge structures built here (build_key_merge / build_range_merge) are
 consumed by THREE launch paths, all bit-identical by the argument above:
@@ -199,9 +199,8 @@ def lane_slice(packed, row_off, word_off, rows: int, words: int):
 
 
 def node_lane_cache_sizes() -> dict:
-    """Compiled-variant counts of the node-lane kernels (the mesh-burn
-    bench folds these into its zero-recompile assertion alongside
-    kernels.jit_cache_sizes)."""
+    """Compiled-variant counts of the node-lane kernels (what
+    tests/test_mesh_burn.py holds still across node-count churn)."""
     return {
         "node_fused_deps_resolve": node_fused_deps_resolve._cache_size(),
         "node_fused_range_deps_resolve":

@@ -170,9 +170,9 @@ def warmup(num_buckets: int = 1024, cap: int = 8192,
     arena-tuple structure; the staged pipeline dispatches the same tiers one
     tick later, so encode-ahead adds no new shapes). Warmup compiles the
     cross product -- a handful of variants, bounded by the deliberately
-    short tier ladders in ops/kernels.py. The bench asserts zero recompiles
-    inside its timed windows against exactly this coverage
-    (kernels.jit_cache_sizes), including the field-granular delta scatters
+    short tier ladders in ops/kernels.py. The benchmark holds compile
+    requests inside its timed windows at zero against exactly this coverage
+    (compile_requests_in_window), including the field-granular delta scatters
     (arena_scatter_keys and the single-lane scatter_rows used by ts-only /
     valid-only updates). `exec_caps` additionally warms the exec_plane's
     per-field lane deltas (exec-ts / applied / pending rows) for each
@@ -200,8 +200,8 @@ def warmup(num_buckets: int = 1024, cap: int = 8192,
     (opt-in) warms the protocol megakernel's quorum-only variants
     (kernels.protocol_tick) across `mega_lane_tiers` (default: the first
     MEGA_LANE_TIERS rungs) for each electorate majority in use; the full
-    fused programs key on per-tick finalize signatures and warm on the
-    bench's dedicated warm pass instead. `exec_tiers` (opt-in) warms the
+    fused programs key on per-tick finalize signatures and warm on a
+    caller's own warm pass instead. `exec_tiers` (opt-in) warms the
     compacted execution-frontier harvest (kernels.frontier_compact) across
     (exec cap x plane count x out_cap) -- plane counts follow `store_tiers`
     plus the solo plane -- and the engine's exec-only fused flush
@@ -1760,7 +1760,6 @@ class BatchDepsResolver(DepsResolver):
     host_hidden_s = RegTimer("resolver.host_hidden_s")  # host time overlapped
     #                                                     with an in-flight call
     staged_dispatches = RegCounter("resolver.staged_dispatches")
-    padded_dispatches = RegCounter("resolver.padded_dispatches")
     prefetched = RegCounter("resolver.prefetched")   # poll-drained transfers
     polls_armed = RegCounter("resolver.polls_armed")
     stale_harvests = RegCounter("resolver.stale_harvests")  # cross-compaction
@@ -1802,9 +1801,6 @@ class BatchDepsResolver(DepsResolver):
     # host launch time of the sharded finalize compaction (per-shard
     # popcount/prefix + gather-merge) on multi-device meshes
     shard_merge_s = RegTimer("resolver.shard_merge_s")
-    # adaptive staged window: scale adjustments per direction
-    window_shrinks = RegCounter("resolver.window_shrinks")
-    window_widens = RegCounter("resolver.window_widens")
     # device-plane fault tolerance (ops/fault_plane.py): applied fault
     # injections, bounded launch retries + harvest re-probes, watchdog
     # trips on wedged calls, checksum-lane catches before decode, and the
@@ -1824,13 +1820,10 @@ class BatchDepsResolver(DepsResolver):
 
     def __init__(self, num_buckets: int = 256, initial_cap: int = 4096,
                  max_dispatch: Optional[int] = None,
-                 fuse_cross_store: bool = True,
-                 overlap_host: bool = True,
                  pad_store_tiers: Optional[int] = None,
                  finalize_on_device: bool = True,
                  adaptive_window: bool = False,
                  kid_cap: int = 4096,
-                 device_out_bound: bool = True,
                  verify_checksums: bool = True,
                  retry_limit: int = 2,
                  watchdog_probes: int = 3,
@@ -1866,16 +1859,6 @@ class BatchDepsResolver(DepsResolver):
         # larger dispatches amortize them; the default stays small to
         # bound jit tiers in tests
         self.max_dispatch = max_dispatch or self.MAX_DISPATCH
-        # True (default): a node tick's items from ALL stores ride one fused
-        # kernel call. False: one dispatch per store per tick -- the
-        # differential baseline the fused path is tested bit-identical to
-        self.fuse_cross_store = fuse_cross_store
-        # True (default): staged tick pipeline -- each tick launches the
-        # PREVIOUS tick's encoded plans first, then preaccepts/encodes the
-        # next batch while that call is in flight, hiding host work inside
-        # the device window. False: today's serial tick (preaccept -> encode
-        # -> launch in one event), the bit-identical differential baseline.
-        self.overlap_host = overlap_host
         # opt-in: pad fused cross-store dispatches to a fixed store tier
         # with cached empty arena blocks so many-store nodes compile ONE
         # jit tier instead of one per participating-store count
@@ -1884,17 +1867,12 @@ class BatchDepsResolver(DepsResolver):
         # finalize_csr / range_finalize_csr on device -- exact key filtering
         # + segment compaction -- so harvest reads back one contiguous
         # (indptr, dep_rows, dep_ts) CSR per store instead of the full bit
-        # matrices. False: the legacy unpackbits decode, the bit-identical
-        # differential baseline (also the automatic per-group fallback when
-        # a sequence guard trips mid-flight).
+        # matrices. False: every group takes the legacy unpackbits decode,
+        # which is the automatic per-group fallback when a sequence guard
+        # trips mid-flight and the other side of the probation canary's
+        # double decode -- the switch tests use to drive that fallback over
+        # a whole workload.
         self.finalize_on_device = finalize_on_device
-        # True (default): finalize out_caps come from the OutCapTiers
-        # hysteresis policy fed by the DEVICE-computed bound riding back
-        # with each finalize result -- no per-dispatch host O(keys)
-        # popcount pass (the host-exact bound seeds only the first, cold
-        # dispatch per arena). False: the legacy host-exact bound + out_tier
-        # snap per dispatch, the differential baseline.
-        self.device_out_bound = device_out_bound
         # one tier policy per (arena, finalize lane): per-slot mean bounds
         # are arena-contention properties, not resolver globals
         self._octiers: Dict[tuple, "OutCapTiers"] = {}
@@ -2269,10 +2247,8 @@ class BatchDepsResolver(DepsResolver):
         if drained == 0:
             if s > 0.25:
                 self._win_scale[id(node)] = max(0.25, s * 0.5)
-                self.window_shrinks += 1
         elif drained >= self.max_dispatch and s < 4.0:
             self._win_scale[id(node)] = min(4.0, s * 2.0)
-            self.window_widens += 1
 
     def note_admission_pressure(self, node, overloaded: bool) -> None:
         """Admission-governor hook (serve/admission.py): entering overload
@@ -2287,29 +2263,19 @@ class BatchDepsResolver(DepsResolver):
             s = self._win_scale.get(id(node), 1.0)
             if s < 4.0:
                 self._win_scale[id(node)] = min(4.0, s * 2.0)
-                self.window_widens += 1
         else:
             if self._win_scale.get(id(node), 1.0) > 1.0:
                 self._win_scale[id(node)] = 1.0
-                self.window_shrinks += 1
 
     def _tick(self, node) -> None:
-        """One node tick. Serial mode (overlap_host=False) runs preaccept ->
-        encode -> launch in this one event, exactly the pre-pipeline
-        behavior. Staged mode reorders the event into stage_dispatch first
-        (launch the PREVIOUS tick's encoded plans, putting the device to
-        work immediately) then stage_host (preaccept + encode the batch
-        drained now, staged for the NEXT tick's launch) -- so the host
-        phases below run in the wall-clock shadow of the in-flight call.
-        stage_decode stays on the harvest event, which fires per dispatch
-        after device_latency_ms and drains in dispatch order."""
+        """One node tick: stage_dispatch first (launch the PREVIOUS tick's
+        encoded plans, putting the device to work immediately) then
+        stage_host (preaccept + encode the batch drained now, staged for the
+        NEXT tick's launch) -- so the host phases below run in the
+        wall-clock shadow of the in-flight call. stage_decode stays on the
+        harvest event, which fires per dispatch after device_latency_ms and
+        drains in dispatch order."""
         self._ticking.discard(id(node))
-        if not self.overlap_host:
-            items = self._drain_and_preaccept(node)
-            self._adapt(node, len(items))
-            for sub in self._slices(items):
-                self._dispatch(node, sub)
-            return
         # STAGE_DISPATCH: launch before any host work this event does
         for plan in self._staged.pop(id(node), []):
             self._launch(node, plan, staged=True)
@@ -2430,20 +2396,11 @@ class BatchDepsResolver(DepsResolver):
         return items
 
     def _slices(self, items: List[_Item]) -> List[List[_Item]]:
-        """Split a tick's items into dispatch slices. Fused (default): ONE
-        device call per tick slice, every store's items riding together;
-        oversized batches split so subject jit tiers stay bounded
-        (8..max_dispatch). Unfused: one dispatch per store per tick -- the
-        fused path's differential baseline."""
-        if self.fuse_cross_store:
-            return [items[lo:lo + self.max_dispatch]
-                    for lo in range(0, len(items), self.max_dispatch)]
-        by_store: Dict[int, List[_Item]] = {}
-        for item in items:
-            by_store.setdefault(id(item.store), []).append(item)
-        return [sub[lo:lo + self.max_dispatch]
-                for sub in by_store.values()
-                for lo in range(0, len(sub), self.max_dispatch)]
+        """Split a tick's items into dispatch slices: ONE device call per
+        tick slice, every store's items riding together; oversized batches
+        split so subject jit tiers stay bounded (8..max_dispatch)."""
+        return [items[lo:lo + self.max_dispatch]
+                for lo in range(0, len(items), self.max_dispatch)]
 
     def _run_plan(self, plan: _Plan):
         """stage_dispatch: fire a plan's deferred kernel launches against
@@ -2483,8 +2440,7 @@ class BatchDepsResolver(DepsResolver):
         their owned ranges (vs both of their store's arenas). With several
         groups, the fused kernels take every participating store's arena
         lanes as one tuple and route subjects by the store-id lane; a single
-        group runs the plain kernels, byte-identical to the old per-store
-        path."""
+        group runs the plain kernels."""
         import jax.numpy as jnp
         from accord_tpu.ops.kernels import nnz_tier, subject_tier
         n = len(items)
@@ -2776,16 +2732,15 @@ class BatchDepsResolver(DepsResolver):
         in the EXACT order the legacy decode walks it (item order, keys
         sorted unique, keys without a row mask skipped -- bit-identity
         depends on this), the device kid/row-mask inputs, and an out_cap
-        tier from the OutCapTiers policy (device_out_bound: fed by the
-        DEVICE-computed bound riding back with each result, so no host
-        O(keys) popcount pass per dispatch; off or cold: the host-exact
-        popcount bound the compaction output can never overflow while
-        kseq holds)."""
+        tier from the OutCapTiers policy (fed by the DEVICE-computed bound
+        riding back with each result, so no host O(keys) popcount pass per
+        dispatch; cold: the host-exact popcount bound the compaction
+        output can never overflow while kseq holds)."""
         import jax.numpy as jnp
-        from accord_tpu.ops.kernels import nnz_tier, out_tier
+        from accord_tpu.ops.kernels import nnz_tier
         arena = g.arena
         pol = self._outcap(arena, "key")
-        want_host_bound = not self.device_out_bound or pol.cold
+        want_host_bound = pol.cold
         pos_of = {i: j for j, i in enumerate(g.idx)}
         flat_key: List[object] = []
         slot_pos: List[int] = []
@@ -2812,9 +2767,7 @@ class BatchDepsResolver(DepsResolver):
         if not flat_key:
             return      # no key has arena rows: the group decodes to EMPTY
         s = nnz_tier(len(flat_key))
-        if not self.device_out_bound:
-            out_cap = out_tier(max(bound, 1))
-        elif want_host_bound:
+        if want_host_bound:
             out_cap = pol.pick(max(bound, 1))
         else:
             out_cap = pol.pick(pol.estimate(len(flat_key)))
@@ -2857,7 +2810,7 @@ class BatchDepsResolver(DepsResolver):
         interval-arena snapshot -- the exact stab reruns against the real
         endpoint lanes, so the fused candidate buffer is not an input."""
         import jax.numpy as jnp
-        from accord_tpu.ops.kernels import out_tier, range_finalize_csr
+        from accord_tpu.ops.kernels import range_finalize_csr
         offs, off = [], 0
         for gv in givs:
             offs.append(off)
@@ -2874,16 +2827,15 @@ class BatchDepsResolver(DepsResolver):
             for e, _, _ in g.rents:
                 ent_ok[e] = True
             pol = self._outcap(g.arena, "range")
-            if not self.device_out_bound or pol.cold:
-                # cold (or device bounds off): seed with the host product
-                # bound (entries x live rows) the stab count can never
-                # exceed; after the first dispatch the DEVICE stab count
-                # riding back with each result feeds the policy instead,
-                # so steady state pays no host count_nonzero pass
+            if pol.cold:
+                # cold: seed with the host product bound (entries x live
+                # rows) the stab count can never exceed; after the first
+                # dispatch the DEVICE stab count riding back with each
+                # result feeds the policy instead, so steady state pays no
+                # host count_nonzero pass
                 nvalid = int(np.count_nonzero(ranges.valid[:ranges.count]))
                 bound = max(len(g.rents) * nvalid, 1)
-                out_cap = (pol.pick(bound) if self.device_out_bound
-                           else out_tier(bound))
+                out_cap = pol.pick(bound)
             else:
                 out_cap = pol.pick(pol.estimate(len(g.rents)))
             rsnap = ranges.device_arrays()
@@ -2910,14 +2862,14 @@ class BatchDepsResolver(DepsResolver):
         kpacked readback) when the arena holds keys the int index cannot
         order."""
         import jax.numpy as jnp
-        from accord_tpu.ops.kernels import nnz_tier, out_tier
+        from accord_tpu.ops.kernels import nnz_tier
         arena = g.arena
         idx = arena.key_index()
         if idx is None:
             return
         keys_sorted, kids_sorted = idx
         pol = self._outcap(arena, "rkey")
-        want_host_bound = not self.device_out_bound or pol.cold
+        want_host_bound = pol.cold
         flat: List[tuple] = []
         slot_subj: List[int] = []
         slot_kid: List[int] = []
@@ -2939,9 +2891,7 @@ class BatchDepsResolver(DepsResolver):
         if not flat:
             return      # no covered key has an arena id: decodes to EMPTY
         s = nnz_tier(len(flat))
-        if not self.device_out_bound:
-            out_cap = out_tier(max(bound, 1))
-        elif want_host_bound:
+        if want_host_bound:
             out_cap = pol.pick(max(bound, 1))
         else:
             out_cap = pol.pick(pol.estimate(len(flat)))
@@ -3035,7 +2985,6 @@ class BatchDepsResolver(DepsResolver):
         pad = pad_block(cap)
         npad = tier - len(blocks)
         blocks.extend([pad] * npad)
-        self.padded_dispatches += 1
         return jnp.concatenate([slots, jnp.full(npad, -1, jnp.int32)])
 
     def _run_fused_kernel(self, ksnaps, slots, subj_of, subj_keys,
@@ -4005,8 +3954,8 @@ class BatchDepsResolver(DepsResolver):
     def _stage(self, node, items: List[_Item]) -> _Plan:
         """stage_host's encode half: group one dispatch slice by store and
         cut its plan (upload arrays + snapshots + plan-time generation
-        pins). The plan launches now (serial mode) or on the next tick's
-        stage_dispatch (overlap mode)."""
+        pins). The plan launches on the next tick's stage_dispatch (or at
+        once, from drain)."""
         # ensure adoption of late-attached stores BEFORE snapshotting group
         # generations -- adoption may mutate (and compact) an arena
         for item in items:
@@ -4133,8 +4082,8 @@ class BatchDepsResolver(DepsResolver):
         self._ensure_poll(node)
 
     def _dispatch(self, node, items: List[_Item]) -> None:
-        """Serial encode+launch of one dispatch slice in a single step (the
-        overlap_host=False tick path and the drain fallback)."""
+        """Serial encode+launch of one dispatch slice in a single step:
+        drain runs queued-but-unticked items through it at shutdown."""
         self._launch(node, self._stage(node, items))
 
     def drain(self, node) -> None:
@@ -4402,20 +4351,15 @@ class ShardedBatchDepsResolver(BatchDepsResolver):
     warmed by parallel.mesh.warmup_sharded's mega_quorum_sizes tiers."""
 
     def __init__(self, mesh=None, num_buckets: int = 256,
-                 initial_cap: int = 4096, fuse_cross_store: bool = True,
-                 overlap_host: bool = True,
+                 initial_cap: int = 4096,
                  pad_store_tiers: Optional[int] = None,
                  finalize_on_device: bool = True,
                  adaptive_window: bool = False, kid_cap: int = 4096,
-                 device_out_bound: bool = True,
                  pad_node_tiers=None):
         super().__init__(num_buckets, initial_cap,
-                         fuse_cross_store=fuse_cross_store,
-                         overlap_host=overlap_host,
                          pad_store_tiers=pad_store_tiers,
                          finalize_on_device=finalize_on_device,
                          adaptive_window=adaptive_window, kid_cap=kid_cap,
-                         device_out_bound=device_out_bound,
                          pad_node_tiers=pad_node_tiers)
         from accord_tpu.parallel.mesh import make_mesh
         self.mesh = mesh if mesh is not None else make_mesh()

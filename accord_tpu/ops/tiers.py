@@ -10,8 +10,8 @@ appear in one caller without the others (and warmup) seeing it.
 `OutCapTiers` is the piece that makes the FINALIZE kernels warmable: their
 out_cap used to be sized from an exact per-dispatch host popcount bound,
 which (a) cost a host O(keys) pass per dispatch and (b) made the picked
-tier data-dependent, so the bench had to exempt finalize kernels from its
-zero-recompile assertion. The policy instead pins a tier with
+tier data-dependent, so no zero-recompile check could cover the finalize
+kernels. The policy instead pins a tier with
 grow-immediately / shrink-after-hysteresis dynamics, fed by the DEVICE
 computed bound that rides back with each finalize result:
 

@@ -3,8 +3,8 @@
   python -m accord_tpu.serve --node-id 1 --listen 127.0.0.1:7101 \
       --peers 1=127.0.0.1:7101,2=127.0.0.1:7102,3=127.0.0.1:7103
 
-`bench.py` (bench_serve) and tests/test_serve.py spawn three of these and
-drive them with serve/loadgen.py.
+tests/test_serve.py spawns three of these and drives them with
+serve/loadgen.py.
 """
 from __future__ import annotations
 

@@ -7,7 +7,7 @@ compilation cache removes that cost for the second process -- provided the
 directory does not move, because a directory that moves never hits.
 
 Every executable entry point (`python -m accord_tpu.serve`,
-`accord_tpu.sim.burn`, `accord_tpu.sim.mesh_burn`, `bench.py`,
+`accord_tpu.sim.burn`, `accord_tpu.sim.mesh_burn`, `benchmark/run.py`,
 `chip_smoke.py`) calls `place_compile_cache()` before its first jit.
 Library imports and the test suite do not.
 """

@@ -8,9 +8,9 @@ burn runs everything together, burn/BurnTest.java:107).
 
 The exec plane stays opt-in for the REST of the sim suite purely for
 wall-clock reasons: the sim's per-tick device dispatch costs ~50x the host
-walk on the CPU test mesh (real-chip batching amortizes this; bench.py
-measures that side). This module is where the combined configuration is
-load-bearing.
+walk on the CPU test mesh (what it costs on a chip is not measured: no
+benchmark cell turns the exec plane on). This module is where the combined
+configuration is load-bearing.
 """
 from __future__ import annotations
 

@@ -359,18 +359,14 @@ def test_out_cap_overflow_bumps_tier_and_falls_back_exactly():
 def test_device_bound_and_range_stab_randomized_differential():
     """The retired host residuals, differentially: the default resolver
     (device-computed out-cap bound + on-device range-subject stabbing) vs
-    the flagged host-bound baseline (device_out_bound=False) vs the legacy
-    unpackbits decode (finalize_on_device=False) -- all bit-identical to
-    the host scans over a randomized mixed workload with multi-piece range
-    subjects, before AND after truncation/prune churn."""
+    the legacy unpackbits decode (finalize_on_device=False) -- both
+    bit-identical to the host scans over a randomized mixed workload with
+    multi-piece range subjects, before AND after truncation/prune churn."""
     rng = np.random.default_rng(2718)
     _, node, store = setup_store()
     dev = BatchDepsResolver(num_buckets=128, initial_cap=128)
-    hostb = BatchDepsResolver(num_buckets=128, initial_cap=128,
-                              device_out_bound=False)
     leg = BatchDepsResolver(num_buckets=128, initial_cap=128,
                             finalize_on_device=False)
-    assert dev.device_out_bound
     store.deps_resolver = dev
     rids, tss = _register_mixed(store, node, rng)
 
@@ -378,7 +374,7 @@ def test_device_bound_and_range_stab_randomized_differential():
         key_seen = range_seen = 0
         for tid, owned, before in subs:
             host = store.host_calculate_deps(tid, owned, before)
-            for r in (dev, hostb, leg):
+            for r in (dev, leg):
                 store.deps_resolver = r
                 got = r.resolve_one(store, tid, owned, before)
                 assert got == host, f"{tid} diverged (bound/stab config)"
@@ -393,8 +389,7 @@ def test_device_bound_and_range_stab_randomized_differential():
                for _, o, _ in subs)
     sweep(subs)
     # the device path really decoded range subjects from the stab, with no
-    # legacy decode and no guard trips; the host-bound baseline rides the
-    # same finalized path (only the out_cap sizing differs)
+    # legacy decode and no guard trips
     assert dev.range_subject_device_decodes > 0
     assert dev.legacy_decodes == 0 and dev.finalize_fallbacks == 0
     # the range lane's out_cap is now fed by the DEVICE stab-count bound
@@ -404,8 +399,6 @@ def test_device_bound_and_range_stab_randomized_differential():
     # device-bound-sized caps never undersize the compaction)
     rpol = dev._outcap(dev._arenas[id(store)], "range")
     assert not rpol.cold, "range lane never observed a device stab bound"
-    assert hostb.range_subject_device_decodes > 0
-    assert hostb.legacy_decodes == 0 and hostb.finalize_fallbacks == 0
     assert leg.legacy_decodes > 0 and leg.finalized_decodes == 0
 
     # truncate half the range txns + prune a few key entries, mirrored into
@@ -414,19 +407,19 @@ def test_device_bound_and_range_stab_randomized_differential():
     for tid in rids[::2]:
         store.range_txns.pop(tid, None)
         store.range_index.remove(tid)
-        for r in (dev, hostb, leg):
+        for r in (dev, leg):
             r.on_truncate(store, tid)
     pruned = 0
     for key in sorted(store.cfks)[:6]:
         cfk = store.cfks[key]
         for t in sorted(cfk._infos)[:1]:
             cfk.remove(t)
-            for r in (dev, hostb, leg):
+            for r in (dev, leg):
                 r.on_prune(store, t, (key,))
             pruned += 1
     assert pruned > 0
     sweep(_subjects(store, node, rng, tss, n=24))
-    for r in (dev, hostb, leg):
+    for r in (dev, leg):
         assert r.host_fallbacks == 0
         assert r.range_fallbacks == 0
 

@@ -7,8 +7,8 @@ Three load-bearing properties:
   1. one fused call per tick across stores, and compacting ONE store's
      arena mid-flight must not disturb the other store's pins or force a
      host fallback;
-  2. fused dispatch decodes bit-identically to per-store dispatch
-     (fuse_cross_store=False) on a randomized mixed key/range workload;
+  2. fused dispatch decodes bit-identically to the host scan on a
+     randomized mixed key/range workload, in fewer calls than stores x ticks;
   3. status-bump updates ship one int32 lane, not the full row --
      upload_bytes stays strictly below the full-row-equivalent baseline.
 """
@@ -183,11 +183,10 @@ def _run_async(cluster, resolver, subs):
     return [o.value() for o in outs]
 
 
-def test_fused_vs_per_store_differential():
+def test_fused_vs_host_differential():
     """Randomized mixed key/range workload over two stores: the fused
-    cross-store dispatch must decode bit-identically to the per-store
-    dispatch (fuse_cross_store=False) AND to the host scan, while issuing
-    fewer device calls than store-count x ticks."""
+    cross-store dispatch must decode bit-identically to the host scan,
+    while issuing fewer device calls than store-count x ticks."""
     rng = np.random.default_rng(31)
     cluster, node, stores = _two_store_node()
     fused = BatchDepsResolver(num_buckets=128, initial_cap=128)
@@ -210,19 +209,9 @@ def test_fused_vs_per_store_differential():
     assert fused.dispatches < 2 * fused.ticks, "fused path disengaged"
     assert fused.host_fallbacks == 0 and fused.range_fallbacks == 0
 
-    # per-store baseline: a fresh resolver (adopts the same store state)
-    # with fusion off -- the old one-dispatch-per-store drain
-    per_store = BatchDepsResolver(num_buckets=128, initial_cap=128,
-                                  fuse_cross_store=False)
-    ps_res = []
-    for wave in subs:
-        ps_res.extend(_run_async(cluster, per_store, wave))
-    assert per_store.dispatches > fused.dispatches
-
     key_seen = range_seen = 0
-    for (store, tid, owned, before), fd, pd in zip(
-            [x for wave in subs for x in wave], fused_res, ps_res):
-        assert fd == pd, f"fused vs per-store diverge on {tid}"
+    for (store, tid, owned, before), fd in zip(
+            [x for wave in subs for x in wave], fused_res):
         host = store.host_calculate_deps(tid, owned, before)
         assert fd == host, f"fused vs host diverge on {tid}"
         key_seen += bool(host.key_deps.all_txn_ids())

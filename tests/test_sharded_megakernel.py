@@ -13,8 +13,7 @@ Tier-1 budget note: the full tier-1 suite runs within ~2% of its hard
 timeout on the reference box, so only the compile-free unit tests ride
 tier 1 here; every differential that compiles a sharded program is
 marked slow. Run the whole module (no -m filter) for the multichip
-smoke -- bench.py's MULTICHIP legs gate the same contract on every
-bench run regardless.
+smoke: nothing else gates this contract.
 """
 from __future__ import annotations
 

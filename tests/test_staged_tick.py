@@ -4,9 +4,9 @@ hide inside the device window, and call N launches at the top of the next
 tick event (stage_dispatch).
 
 Four load-bearing properties:
-  1. overlap_host=True decodes bit-identically to overlap_host=False and
-     to the host scan on a randomized mixed key/range two-store workload,
-     while the staged launch path actually engages;
+  1. the staged tick decodes bit-identically to the host scan on a
+     randomized mixed key/range two-store workload, and every launch comes
+     off the encode-ahead stage;
   2. a preaccept that raises inside stage_host fails ONLY its own
      AsyncResult -- batchmates complete and the pipeline stays live;
   3. compaction landing BETWEEN encode-ahead (plan cut, pins taken) and
@@ -29,16 +29,14 @@ from tests.test_local_engine import mk_txn, setup_store
 from tests.test_ops import _preaccept_population
 
 
-def test_overlap_vs_serial_differential():
+def test_staged_tick_vs_host_differential():
     """Randomized mixed key/range workload over two stores in three waves:
-    the staged pipeline (overlap_host=True, the default) must decode
-    bit-identically to the serial tick (overlap_host=False) AND to the
-    host scan -- and the deferred-launch path must actually engage."""
+    the staged pipeline must decode bit-identically to the host scan --
+    and the deferred-launch path must actually engage."""
     rng = np.random.default_rng(61)
     cluster, node, stores = _two_store_node()
-    overlap = BatchDepsResolver(num_buckets=128, initial_cap=128)
-    assert overlap.overlap_host
-    _attach(stores, node, overlap, latency=5.0)
+    staged = BatchDepsResolver(num_buckets=128, initial_cap=128)
+    _attach(stores, node, staged, latency=5.0)
     for s in stores:
         _register_mixed_per_store(s, node, rng)
 
@@ -50,29 +48,20 @@ def test_overlap_vs_serial_differential():
             wave.extend(_mixed_subjects(s, node, wave_rng, 8))
         waves.append(wave)
 
-    ov_res = []
+    results = []
     for wave in waves:
-        ov_res.extend(_run_async(cluster, overlap, wave))
-    # the tentpole: launches came from the encode-ahead stage, not the
-    # serial encode+launch fallback
-    assert overlap.staged_dispatches > 0
-    assert overlap.staged_dispatches == overlap.dispatches
-    assert overlap.host_fallbacks == 0 and overlap.range_fallbacks == 0
-
-    serial = BatchDepsResolver(num_buckets=128, initial_cap=128,
-                               overlap_host=False)
-    sr_res = []
-    for wave in waves:
-        sr_res.extend(_run_async(cluster, serial, wave))
-    assert serial.staged_dispatches == 0
-    assert serial.host_fallbacks == 0 and serial.range_fallbacks == 0
+        results.extend(_run_async(cluster, staged, wave))
+    # every launch came from the encode-ahead stage, none from drain's
+    # serial encode+launch
+    assert staged.staged_dispatches > 0
+    assert staged.staged_dispatches == staged.dispatches
+    assert staged.host_fallbacks == 0 and staged.range_fallbacks == 0
 
     key_seen = range_seen = 0
-    for (store, tid, owned, before), ov, sr in zip(
-            [x for wave in waves for x in wave], ov_res, sr_res):
-        assert ov == sr, f"overlap vs serial diverge on {tid}"
+    for (store, tid, owned, before), got in zip(
+            [x for wave in waves for x in wave], results):
         host = store.host_calculate_deps(tid, owned, before)
-        assert ov == host, f"overlap vs host diverge on {tid}"
+        assert got == host, f"staged vs host diverge on {tid}"
         key_seen += bool(host.key_deps.all_txn_ids())
         range_seen += bool(host.range_deps.all_txn_ids())
     assert key_seen > 0 and range_seen > 0, "differential vacuous"
