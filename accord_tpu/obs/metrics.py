@@ -336,6 +336,7 @@ GLOSSARY: Dict[str, str] = {
     "resolver.range_subject_device_decodes": "range subjects decoded from the device stab",
     "resolver.range_array_decodes": "groups whose range lanes decoded as arrays over the whole dispatch",
     "resolver.range_filtered_decodes": "of those, groups that applied the host-map filters a dependency at a time (fenced cache, guards since broken)",
+    "resolver.array_cuts": "calls of the whole-dispatch cut (_cut_csr: every item's KeyDeps or RangeDeps from one sort and one cut over the dispatch's pairs), one a domain a group",
     "resolver.range_encode_s": "encode_s spent on the range path: interval CSR, range kernel plan, range and rk finalize lanes",
     "resolver.range_decode_s": "decode_s spent on the range path: both stages of the interval stab and of the rk lane, and the one sort a domain that cuts the group's answers (a key subject's key-lane pairs included, where its store holds range txns)",
     "resolver.range_subjects": "range-domain subjects encoded for the device path",

@@ -507,9 +507,10 @@ class _StoreArena:
         self.gen = 0
         self.retired_ids: Dict[int, np.ndarray] = {}
         self._gen_pins: Dict[int, int] = {}
-        # (gen, count) -> (rank, order) cache for the global ts lexorder --
-        # ts[row] is written once at row creation, so it only invalidates on
-        # compaction (gen) or growth of the live prefix (count)
+        # (gen, count) -> (rank, order, ids by rank) cache for the global ts
+        # lexorder -- ts[row] and ids_np[row] are written once at row
+        # creation, so it only invalidates on compaction (gen) or growth of
+        # the live prefix (count)
         self._rank = None
         # bytes shipped host->device by dirty-row scatters (bench counters):
         # total, broken out per field group, and the bytes the retired
@@ -656,22 +657,23 @@ class _StoreArena:
                           np.int64, rows.size)
         return out[out >= 0]
 
-    def row_rank(self) -> Tuple[np.ndarray, np.ndarray]:
+    def row_rank(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Global ts-lane lexorder over rows [0, count): rank[row] = position
-        of the row in TxnId order, order = the inverse permutation. The lane
-        encoding is order-preserving, so rank order == TxnId order -- the
-        batched decode sorts dep rows once with it instead of lexsorting
-        per item."""
+        of the row in TxnId order, order = the inverse permutation, by_rank
+        = the rows' txn ids in that order. The lane encoding is
+        order-preserving, so rank order == TxnId order -- the batched
+        decode sorts dep rows once with it instead of lexsorting per
+        item."""
         key = (self.gen, self.count)
         cached = self._rank
         if cached is not None and cached[0] == key:
-            return cached[1], cached[2]
+            return cached[1:]
         ts = self.ts[:self.count]
         order = np.lexsort((ts[:, 2], ts[:, 1], ts[:, 0]))
         rank = np.empty(self.count, np.int64)
         rank[order] = np.arange(self.count)
-        self._rank = (key, rank, order)
-        return rank, order
+        self._rank = (key, rank, order, self.ids_np[order])
+        return self._rank[1:]
 
     def update(self, txn_id: TxnId, key_set, status: CfkStatus,
                conflict_ts: Timestamp) -> None:
@@ -1549,7 +1551,10 @@ def _joint_rank(tables):
 
 def _sort_entries(slot: np.ndarray, rank: np.ndarray, r: int):
     """(slot, rank) pairs in (slot, rank) order, each once: one sort of
-    one int64 key a pair."""
+    one int64 key a pair (`r` bounds the ranks from above). Step 6 of the
+    batch decode for every lane: the key lane's pairs through
+    _assemble_key_deps, the range lanes' and a range-state store's key
+    subjects through their builders."""
     key = slot * r + rank
     key.sort()
     if key.size > 1:
@@ -1562,24 +1567,63 @@ def _sort_entries(slot: np.ndarray, rank: np.ndarray, r: int):
 
 def _cut_csr(e_slot: np.ndarray, e_rank: np.ndarray, slot_off: np.ndarray,
              by_rank: np.ndarray, row_objects, make, out) -> None:
-    """Per-item CSR assembly from sorted (slot, rank) pairs, step 8 of
-    _assemble_key_deps for either domain: item i owns the slots
-    [slot_off[i], slot_off[i+1]); its rows are the slots present (their
-    keys or Ranges, made once a present slot by `row_objects(slots)` ->
-    list), its dictionary the ranks present, in TxnId order. out[i] =
-    make(rows, txn_ids, offsets, value_idx) where the item has any pair."""
-    first = np.flatnonzero(np.r_[True, e_slot[1:] != e_slot[:-1]])
+    """Per-item CSR assembly from sorted (slot, rank) pairs, step 8 of the
+    batch decode for either domain, as one cut over the whole dispatch:
+    item i owns the slots [slot_off[i], slot_off[i+1]); its rows are the
+    slots present (their keys or Ranges, made once a present slot by
+    `row_objects(slots)` -> list), its dictionary the ranks present, in
+    TxnId order (`by_rank`: txn ids by rank). out[i] = make(rows, txn_ids,
+    offsets, value_idx) where the item has any pair.
+
+    Every pair's item, every item's dictionary (one sort of item * R + rank
+    and a flag-diff), every pair's place in its item's dictionary and every
+    row's offset from its item's first pair are computed as arrays over
+    the dispatch. The ids and the offsets leave numpy through one tolist
+    each; value_idx, the one lane as long as the pairs, through one
+    memoryview, so that an item's ints are made as its tuple is (a list of
+    every pair's int, made whole and sliced after, costs a second and a
+    third pass over objects long out of the cache). The per-item body only
+    slices those; the count of numpy calls does not depend on the items."""
+    if not e_slot.size:
+        return
+    n = len(slot_off) - 1
+    r = len(by_rank)
+    assert n * r < 1 << 62, "item * R + rank must fit int64"
+    new = np.ones(e_slot.size, bool)
+    new[1:] = e_slot[1:] != e_slot[:-1]
+    first = np.flatnonzero(new)
     rows = row_objects(e_slot[first])
     bounds = np.searchsorted(e_slot, slot_off)
-    row_at = np.searchsorted(first, bounds).tolist()
+    row_at = np.searchsorted(first, bounds)
+    cnt = np.diff(bounds)
+    item = np.repeat(np.arange(n), cnt)
+    # the dictionaries: the distinct (item, rank) in order (`new` now flags
+    # the first of each), and each pair's place among its item's (`place`
+    # counts from 1 over the dispatch, d_at + 1 brings it to the item's 0)
+    key = item * r + e_rank
+    o = np.argsort(key)
+    key = key[o]
+    new[1:] = key[1:] != key[:-1]
+    key = key[new]
+    place = np.empty(o.size, np.int64)
+    place[o] = np.cumsum(new)
+    d_item = key // r
+    d_at = np.searchsorted(d_item, np.arange(n + 1))
+    inv = memoryview(place - (d_at + 1)[item])
+    ids = by_rank[key - d_item * r].tolist()
+    # row offsets from the item's first pair, the item's pair count closing
+    # each item's run: item i's offsets are offs[row_at[i] + i :
+    # row_at[i + 1] + i + 1]
+    offs = np.insert(first - bounds[item[first]], row_at[1:], cnt).tolist()
+    live = np.flatnonzero(cnt).tolist()
     bounds = bounds.tolist()
-    for i in np.flatnonzero(np.diff(bounds)).tolist():
-        a, b = bounds[i], bounds[i + 1]
+    row_at = row_at.tolist()
+    d_at = d_at.tolist()
+    for i in live:
         ra, rb = row_at[i], row_at[i + 1]
-        uniq, inv = np.unique(e_rank[a:b], return_inverse=True)
-        out[i] = make(tuple(rows[ra:rb]), tuple(by_rank[uniq].tolist()),
-                      tuple((first[ra:rb] - a).tolist()) + (b - a,),
-                      tuple(inv.tolist()))
+        out[i] = make(tuple(rows[ra:rb]), tuple(ids[d_at[i]:d_at[i + 1]]),
+                      tuple(offs[ra + i:rb + i + 1]),
+                      tuple(inv[bounds[i]:bounds[i + 1]]))
 
 
 def _dev_ready(dev) -> bool:
@@ -1798,6 +1842,8 @@ class BatchDepsResolver(DepsResolver):
     # dependency at a time (a fenced cache whose guards broke since)
     range_array_decodes = RegCounter("resolver.range_array_decodes")
     range_filtered_decodes = RegCounter("resolver.range_filtered_decodes")
+    # calls of _cut_csr, the whole-dispatch cut: one a domain a group
+    array_cuts = RegCounter("resolver.array_cuts")
     # host launch time of the sharded finalize compaction (per-shard
     # popcount/prefix + gather-merge) on multi-device meshes
     shard_merge_s = RegTimer("resolver.shard_merge_s")
@@ -3111,20 +3157,21 @@ class BatchDepsResolver(DepsResolver):
                            key_off: np.ndarray, out: list) -> list:
         """Steps 6-8 of the batch decode, shared verbatim by the legacy
         unpackbits path and the finalized-CSR materialize (same flat-slot
-        layout, so the two paths stay bit-identical by construction): one
-        global (slot, rank) sort, covered-elision, per-item CSR slices."""
-        from accord_tpu.primitives.deps import KeyDeps
-        n = len(items)
+        layout, so the two paths stay bit-identical by construction): the
+        (slot, row) pairs as (slot, rank) through _sort_entries, the
+        covered elision, and _cut_csr's cut of every item's KeyDeps. No
+        sort and no per-item numpy call of its own."""
         if h_slot.size == 0:
             return out
         # 6. one global sort: flat slots increase per (item, key), so
-        #    (slot, rank) order groups by item, then key, then TxnId order
-        rank, order = arena.row_rank()
-        o = np.lexsort((rank[h_row], h_slot))
-        h_slot = h_slot[o]
-        h_row = h_row[o]
+        #    (slot, rank) order groups by item, then key, then TxnId order.
+        #    rank <-> row is a bijection over the arena's rows, so the
+        #    sorted pairs' rows come back as order[rank]
+        rank, order, by_rank = arena.row_rank()
+        h_slot, h_rank = _sort_entries(h_slot, rank[h_row], len(order))
         # 7. transitive-dependency elision, only over slots with covers
         if covered_any:
+            h_row = order[h_rank]
             seg = np.flatnonzero(np.r_[True, h_slot[1:] != h_slot[:-1]])
             seg_end = np.r_[seg[1:], h_slot.size]
             keep = np.ones(h_slot.size, bool)
@@ -3145,24 +3192,14 @@ class BatchDepsResolver(DepsResolver):
                         keep[t] = False
             if not keep.all():
                 h_slot = h_slot[keep]
-                h_row = h_row[keep]
+                h_rank = h_rank[keep]
         if h_slot.size == 0:
             return out
-        # 8. per-item CSR assembly from its slice of the sorted arrays
-        h_rank = rank[h_row]
-        bounds = np.searchsorted(h_slot, key_off)
-        for i in range(n):
-            a, b = int(bounds[i]), int(bounds[i + 1])
-            if a == b:
-                continue
-            seg_slot = h_slot[a:b]
-            uniq, inv = np.unique(h_rank[a:b], return_inverse=True)
-            txn_ids = tuple(arena.ids_np[order[uniq]].tolist())
-            kb = np.flatnonzero(np.r_[True, seg_slot[1:] != seg_slot[:-1]])
-            keys_present = tuple(flat_key[seg_slot[j]] for j in kb)
-            offsets = tuple(kb.tolist()) + (b - a,)
-            out[i] = KeyDeps(keys_present, txn_ids, offsets,
-                             tuple(inv.tolist()))
+        # 8. every item's CSR from its slice of the sorted pairs
+        self.array_cuts += 1
+        _cut_csr(h_slot, h_rank, key_off, by_rank,
+                 lambda slots: [flat_key[u] for u in slots.tolist()],
+                 KeyDeps, out)
         return out
 
     def _fetch_np(self, holder, attr: str, dev):
@@ -3550,6 +3587,7 @@ class BatchDepsResolver(DepsResolver):
             keys = [k for it in items if isinstance(it.owned, Keys)
                     for k in it.owned]
             e_slot, e_rank = _sort_entries(slot, rank, len(by_rank))
+            self.array_cuts += 1
             _cut_csr(e_slot, e_rank, off, by_rank,
                      lambda slots: [keys[u] for u in slots.tolist()],
                      KeyDeps, out)
@@ -3617,6 +3655,7 @@ class BatchDepsResolver(DepsResolver):
             return rows
 
         off = np.searchsorted(u_j, np.arange(n + 1))
+        self.array_cuts += 1
         _cut_csr(e_slot, e_rank, off, by_rank, ranges_of, RangeDeps, out)
         return out
 

@@ -12,6 +12,7 @@ import numpy as np
 
 from accord_tpu.local.cfk import CfkStatus
 from accord_tpu.ops.resolver import BatchDepsResolver
+from accord_tpu.primitives.deps import Deps, KeyDeps
 from accord_tpu.primitives.keyspace import Keys, Range, Ranges
 from accord_tpu.primitives.timestamp import Domain, Timestamp, TxnId, TxnKind
 from tests.test_fused_dispatch import (_attach, _far, _mixed_subjects,
@@ -453,3 +454,94 @@ def test_finalized_truncation_output_cap_growth():
     assert resolver.finalized_decodes == 1
     assert resolver.legacy_decodes == 0
     _assert_clean(resolver)
+
+
+def _shuffled_key_store_with_covers(seed, n=60, keyspace=12):
+    """A key-only store whose arena rows are NOT in TxnId order: the ids
+    are minted in order and registered shuffled, so a row and its rank
+    differ on nearly every row. A third of the txns then commit, and the
+    later committed writes cover the committed deps below them on each of
+    their keys. Attaches a finalizing resolver first. Returns (rng, node,
+    store, resolver, tids in id order, their stamps, their key sets, the
+    covered (key, txn) pairs)."""
+    rng = np.random.default_rng(seed)
+    _, node, store = setup_store()
+    fin = BatchDepsResolver(num_buckets=128, initial_cap=128)
+    store.deps_resolver = fin
+    stamps = [node.unique_now() for _ in range(n)]
+    tids = [TxnId.create(ts.epoch, ts.hlc, ts.node, TxnKind.WRITE, Domain.KEY)
+            for ts in stamps]
+    key_sets = [sorted({int(k) for k in
+                        rng.integers(0, keyspace, 1 + int(rng.integers(0, 3)))})
+                for _ in range(n)]
+    for i in rng.permutation(n).tolist():
+        store.register(tids[i], Keys(key_sets[i]), CfkStatus.WITNESSED,
+                       stamps[i])
+    committed = sorted(rng.choice(n, n // 3, replace=False).tolist())
+    for i in committed:
+        store.register(tids[i], Keys(key_sets[i]), CfkStatus.COMMITTED,
+                       stamps[i], tids[i].as_timestamp())
+    for i in committed[len(committed) // 2:]:
+        per_key = {k: [tids[j] for j in range(i) if k in key_sets[j]]
+                   for k in key_sets[i]}
+        store.register_commit_cover(tids[i], tids[i].as_timestamp(),
+                                    Deps(KeyDeps.of(per_key)))
+    covered = {(k, t) for k, c in store.cfks.items() for t in c.covered}
+    return rng, node, store, fin, tids, stamps, key_sets, covered
+
+
+def _fields(deps):
+    kd, rd = deps.key_deps, deps.range_deps
+    return (kd.keys, kd.txn_ids, kd.offsets, kd.value_idx,
+            rd.ranges, rd.txn_ids, rd.offsets, rd.value_idx)
+
+
+def test_covered_elision_recovers_rows_from_ranks():
+    """Step 7 of _assemble_key_deps reads the covered map by txn id, and
+    since the pairs are sorted as (slot, rank) it recovers each pair's row
+    as order[rank]. Rows registered out of TxnId order make a rank read as
+    a row name another txn: the elision would then drop or keep the wrong
+    dependency and the answer would leave the host scan's. One sync batch,
+    covers present, one subject itself registered (and itself covered)."""
+    rng, node, store, fin, tids, stamps, key_sets, covered = \
+        _shuffled_key_store_with_covers(41)
+    assert len(covered) >= 5, "no covers: the case is vacuous"
+    arena = fin._arenas[id(store)]
+    rank = arena.row_rank()[0]
+    assert (rank != np.arange(rank.size)).sum() > rank.size // 2
+
+    me = next(t for _, t in sorted(covered))
+    far = _far(node)
+    subs = [(me, store.owned(Keys(key_sets[tids.index(me)])), far)]
+    for i in range(24):
+        ks = {int(k) for k in rng.integers(0, 12, 1 + int(rng.integers(0, 4)))}
+        before = far if i % 3 else stamps[int(rng.integers(20, len(stamps)))]
+        subs.append((node.next_txn_id(TxnKind.WRITE, Domain.KEY),
+                     store.owned(Keys(ks)), before))
+    cuts0 = fin.array_cuts
+    got = fin.resolve_batch(store, subs)
+    assert fin.array_cuts == cuts0 + 1      # one group, one domain
+    elided = 0
+    for (tid, owned, before), deps in zip(subs, got):
+        host = store.host_calculate_deps(tid, owned, before)
+        assert deps == host, f"{tid}: {deps!r} != host scan {host!r}"
+        assert tid not in deps.key_deps.all_txn_ids()
+        for k in owned:
+            gone = {t for kk, t in covered if kk == k} \
+                - set(deps.key_deps.for_key(k))
+            elided += len(gone)
+    assert elided > 0, "no covered dependency was elided"
+    assert fin.finalized_decodes > 0 and fin.legacy_decodes == 0
+    _assert_clean(fin)
+
+    # the legacy decode of the same batch, on the same store state: the
+    # same objects field for field (both end in _assemble_key_deps)
+    leg = BatchDepsResolver(num_buckets=128, initial_cap=128,
+                            finalize_on_device=False)
+    store.deps_resolver = leg
+    leg_got = leg.resolve_batch(store, subs)
+    assert leg.legacy_decodes > 0 and leg.finalized_decodes == 0
+    assert leg.array_cuts == 1
+    for (tid, _, _), fd, ld in zip(subs, got, leg_got):
+        assert _fields(fd) == _fields(ld), f"legacy vs finalized on {tid}"
+    _assert_clean(leg)

@@ -534,8 +534,23 @@ def test_range_timers_fit_their_parents_and_counters_follow_range_subjects(
     # store between a launch and its harvest, so no group filters
     assert d["resolver.range_array_decodes"] == d["resolver.dispatches"]
     assert "resolver.range_filtered_decodes" not in d
+    # the whole-dispatch cut, once a domain a group: the key subjects'
+    # (their key-lane pairs joined in) and the range subjects', each where
+    # the dispatch holds any
+    if share in (0.0, 1.0):
+        assert d["resolver.array_cuts"] == d["resolver.dispatches"]
+    else:
+        assert d["resolver.dispatches"] < d["resolver.array_cuts"] \
+            <= 2 * d["resolver.dispatches"]
     assert (d.get("resolver.range_intervals", 0) >= n_range) \
         and (d.get("resolver.range_intervals", 0) > 0) == (n_range > 0)
+
+
+def test_key_only_store_cuts_once_a_dispatch(resolved):
+    arena, _, d = resolved
+    assert d["resolver.array_cuts"] == d["resolver.dispatches"] > 0
+    assert arena.resolver.snapshot()["resolver.array_cuts"] >= \
+        d["resolver.array_cuts"]
 
 
 def test_key_only_store_never_enters_the_range_path(resolved):
