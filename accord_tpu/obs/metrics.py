@@ -337,6 +337,18 @@ GLOSSARY: Dict[str, str] = {
     "resolver.range_array_decodes": "groups whose range lanes decoded as arrays over the whole dispatch",
     "resolver.range_filtered_decodes": "of those, groups that applied the host-map filters a dependency at a time (fenced cache, guards since broken)",
     "resolver.array_cuts": "calls of the whole-dispatch cut (_cut_csr: every item's KeyDeps or RangeDeps from one sort and one cut over the dispatch's pairs), one a domain a group",
+    "resolver.arena_sync_s": "encode_s spent bringing the device arena up to the host shadows: dirty rows shipped by device_arrays(), dirty kid words by kid_arrays() (the host waits for the oldest of the device's copies past _SYNC_QUEUED queued)",
+    "resolver.arena_rows_uploaded": "arena rows shipped to the device, of any lane group (whole rows, key sets, one lane)",
+    "resolver.arena_upload_calls": "device scatter calls those uploads took (arena_scatter, arena_scatter_keys, scatter_rows, kid_word_scatter)",
+    "resolver.compact_s": "time in _StoreArena.compact(): the scan for live rows and, where they fit half the capacity, the rebuild (refused attempts too)",
+    "resolver.arena_compactions": "key-arena compactions that rebuilt the row mapping",
+    "resolver.compact_rows_kept": "live rows those compactions kept",
+    "resolver.grow_s": "time doubling a full key arena: the host lanes and arena_grow on the device",
+    "resolver.arena_growths": "key-arena capacity doublings",
+    "resolver.fence_s": "truncate_s spent in the mutation fence: waiting for every call in flight and caching its finalized lanes before a truncation or prune bumps the guards",
+    "resolver.fence_materializes": "finalized lanes the fence cached",
+    "resolver.truncate_s": "time in on_truncate and on_prune, the fence included",
+    "resolver.truncated_txns": "arena rows those calls emptied (tombstones until the next compaction)",
     "resolver.range_encode_s": "encode_s spent on the range path: interval CSR, range kernel plan, range and rk finalize lanes",
     "resolver.range_decode_s": "decode_s spent on the range path: both stages of the interval stab and of the rk lane, and the one sort a domain that cuts the group's answers (a key subject's key-lane pairs included, where its store holds range txns)",
     "resolver.range_subjects": "range-domain subjects encoded for the device path",

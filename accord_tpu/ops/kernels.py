@@ -280,7 +280,8 @@ def scatter_rows(dst, idx, rows):
     """dst[cap, ...] with dst[idx[i]] = rows[i] -- the incremental device
     active-set update (dirty rows only; jit caches per (cap, len(idx)) shape
     bucket)."""
-    return dst.at[idx].set(rows)
+    with jax.named_scope("lane_scatter"):
+        return dst.at[idx].set(rows)
 
 
 @jax.jit
@@ -290,7 +291,8 @@ def kid_word_scatter(kid_rows, kid_idx, word_idx, words):
     coordinates. The host dedupes coordinates and sources each word's full
     current value, so duplicate-index write hazards never arise; padding
     entries use kid_idx == KC (out of bounds, dropped)."""
-    return kid_rows.at[kid_idx, word_idx].set(words, mode="drop")
+    with jax.named_scope("kid_word_scatter"):
+        return kid_rows.at[kid_idx, word_idx].set(words, mode="drop")
 
 
 def _pack_bits(m):
@@ -903,13 +905,17 @@ def arena_scatter(bitmaps, ts, exec_ts, kinds, valid,
     indices; padding entries use cap -- out of bounds, dropped): each dirty
     row's bitmap is zeroed, then its current buckets scatter-set, so rows
     whose key sets shrank lose their stale bits. Row-padding duplicates
-    row[0] with identical lane data -- harmless double write."""
-    cleared = bitmaps.at[rows].set(0.0)
-    return (cleared.at[key_rows, key_mods].max(1.0, mode="drop"),
-            ts.at[rows].set(ts_rows),
-            exec_ts.at[rows].set(exec_rows),
-            kinds.at[rows].set(kind_rows),
-            valid.at[rows].set(valid_rows))
+    row[0] with identical lane data -- harmless double write. The stages
+    carry jax.named_scope names (metadata only), as deps_resolve's do."""
+    with jax.named_scope("bitmap_rebuild"):
+        cleared = bitmaps.at[rows].set(0.0)
+        rebuilt = cleared.at[key_rows, key_mods].max(1.0, mode="drop")
+    with jax.named_scope("lane_scatter"):
+        return (rebuilt,
+                ts.at[rows].set(ts_rows),
+                exec_ts.at[rows].set(exec_rows),
+                kinds.at[rows].set(kind_rows),
+                valid.at[rows].set(valid_rows))
 
 
 @jax.jit
@@ -920,8 +926,9 @@ def arena_scatter_keys(bitmaps, rows, key_rows, key_mods):
     Same clear-then-max CSR contract as arena_scatter. (The [kmin, kmax]
     hull lanes this used to refresh are retired -- the range kernel now
     contracts over the same bitmaps.)"""
-    cleared = bitmaps.at[rows].set(0.0)
-    return cleared.at[key_rows, key_mods].max(1.0, mode="drop")
+    with jax.named_scope("bitmap_rebuild"):
+        cleared = bitmaps.at[rows].set(0.0)
+        return cleared.at[key_rows, key_mods].max(1.0, mode="drop")
 
 
 @jax.jit
@@ -947,8 +954,9 @@ def arena_grow(bitmaps, ts, exec_ts, kinds, valid, new_cap: int):
         widths = [(0, grow)] + [(0, 0)] * (a.ndim - 1)
         return jnp.pad(a, widths, constant_values=value)
 
-    return (pad(bitmaps), pad(ts), pad(exec_ts, neg), pad(kinds),
-            pad(valid, False))
+    with jax.named_scope("arena_grow"):
+        return (pad(bitmaps), pad(ts), pad(exec_ts, neg), pad(kinds),
+                pad(valid, False))
 
 
 def pad_to(x: np.ndarray, size: int, axis: int = 0) -> np.ndarray:

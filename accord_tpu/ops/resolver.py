@@ -390,6 +390,17 @@ def warmup(num_buckets: int = 1024, cap: int = 8192,
         jax.block_until_ready(out)
 
 
+# No arena scatter donates its input (a staged plan may still hold the
+# lanes it snapshotted), so each one in flight keeps a whole copy of the
+# lane it rewrites alive. A sync lets this many of them queue behind the
+# one that runs, then waits for the oldest before it enqueues the next: a
+# compaction's re-upload is 1,500 scatters of a 1 GB bitmap at 262,144
+# rows, and unchecked they filled a 16 GB chip (my chip run 1, PR 33). The
+# queue holds the lanes it may wait for, so it is counted in lanes, not in
+# bytes: a byte limit let a small arena keep 32 of them (my chip run 5).
+_SYNC_QUEUED = 2
+
+
 class _NodeEncoder:
     """The per-NODE timestamp-encoder cell shared by every store arena on
     the node: the fused cross-store kernels compare all subject/row
@@ -427,7 +438,20 @@ class _StoreArena:
     def __init__(self, num_buckets: int, initial_cap: int = 4096,
                  range_cap: int = 64,
                  shared_encoder: Optional[_NodeEncoder] = None,
-                 kid_cap: int = 4096):
+                 kid_cap: int = 4096, *, metrics: MetricsRegistry,
+                 account: Occupancy):
+        # the owning resolver's registry and occupancy account: the arena's
+        # lifecycle (device sync, compaction, growth) reports there through
+        # the same primitive as every pipeline phase
+        self._metrics = metrics
+        self._phase = functools.partial(phase, metrics, account=account)
+        self._rows_uploaded = self._metrics.counter(
+            "resolver.arena_rows_uploaded")
+        self._upload_calls = self._metrics.counter(
+            "resolver.arena_upload_calls")
+        # the outputs of the scatters the running sync has enqueued and not
+        # yet waited for, oldest first
+        self._pending: deque = deque()
         self.num_buckets = num_buckets
         self.cap = initial_cap
         self.count = 0
@@ -568,9 +592,19 @@ class _StoreArena:
         capacity (caller grows instead). Bumps `gen`: in-flight async calls
         hold packed rows in the OLD mapping; their harvests translate those
         rows through the snapshot pinned below (no host fallback)."""
-        live = [i for i in range(self.count) if self.key_sets[i]]
-        if len(live) > self.cap // 2:
-            return False
+        with self._phase("resolver.compact", "resolver.compact_s"):
+            live = [i for i in range(self.count) if self.key_sets[i]]
+            kept = len(live) <= self.cap // 2
+            if kept:
+                self._compact_onto(live)
+                self._metrics.counter("resolver.arena_compactions").inc()
+                self._metrics.counter("resolver.compact_rows_kept").inc(
+                    len(live))
+        return kept
+
+    def _compact_onto(self, live: List[int]) -> None:
+        """compact()'s rebuild: the rows in `live`, in order, become rows
+        0..len(live)-1 of a fresh mapping."""
         if self._gen_pins.get(self.gen):
             # calls encoded against this mapping are still in flight: keep
             # the row->txn table alive so their harvests can translate
@@ -625,7 +659,6 @@ class _StoreArena:
         self._dirty_ts = set()
         self._dirty_valid = set()
         self.gen += 1
-        return True
 
     # -- in-flight generation pinning -----------------------------------------
     def pin_gen(self) -> int:
@@ -685,10 +718,14 @@ class _StoreArena:
                                    "active txn %s outside encoder window",
                                    txn_id)
             if self.count == self.cap and not self.compact():
-                self._grow_host()
-                if self._device is not None:
-                    from accord_tpu.ops.kernels import arena_grow
-                    self._device = arena_grow(*self._device, new_cap=self.cap)
+                with self._phase("resolver.grow", "resolver.grow_s",
+                                 cap=self.cap * self.GROW):
+                    self._grow_host()
+                    if self._device is not None:
+                        from accord_tpu.ops.kernels import arena_grow
+                        self._device = arena_grow(*self._device,
+                                                  new_cap=self.cap)
+                    self._metrics.counter("resolver.arena_growths").inc()
             row = self.count
             self.count += 1
             self.txn_ids.append(txn_id)
@@ -854,15 +891,16 @@ class _StoreArena:
             offsets.append(len(value_idx))
         return KeyDeps(tuple(keys), txn_ids, tuple(offsets), tuple(value_idx))
 
-    def remove_keys(self, txn_id: TxnId, keys) -> None:
+    def remove_keys(self, txn_id: TxnId, keys) -> bool:
         """A store truncated its record of txn_id: its slice of the keys no
-        longer yields deps (other stores' keys in the row live on)."""
+        longer yields deps (other stores' keys in the row live on). True
+        where that emptied the row: a tombstone until the next compaction."""
         row = self.row_of.get(txn_id)
         if row is None:
-            return
+            return False
         remaining = self.key_sets[row] - frozenset(keys)
         if remaining == self.key_sets[row]:
-            return
+            return False
         for k in self.key_sets[row] - remaining:
             self._clear_key_row_bit(k, row)
         self.key_sets[row] = remaining
@@ -875,9 +913,31 @@ class _StoreArena:
         if not remaining:
             self.valid[row] = False
             self._mark_dirty(row, self._dirty_valid)
+        return not remaining
 
     # -- device sync ----------------------------------------------------------
     def device_arrays(self):
+        """The device lanes, brought up to the host shadows first where a
+        row is dirty (under resolver.arena_sync; a clean arena opens no
+        span)."""
+        if self._device is None or self._dirty_full or self._dirty_keys \
+                or self._dirty_ts or self._dirty_valid:
+            with self._phase("resolver.arena_sync",
+                             "resolver.arena_sync_s"):
+                self._sync_device()
+                self._pending.clear()
+        return self._device
+
+    def _throttle(self, lane) -> None:
+        """`lane` is the output of a scatter just enqueued: one more copy
+        queued. Past _SYNC_QUEUED, wait for the oldest (the later ones keep
+        the device busy meanwhile)."""
+        self._pending.append(lane)
+        if len(self._pending) > _SYNC_QUEUED:
+            import jax
+            jax.block_until_ready(self._pending.popleft())
+
+    def _sync_device(self) -> None:
         import jax.numpy as jnp
         from accord_tpu.ops.kernels import scatter_nnz_tier
         if self._device is None:
@@ -923,7 +983,6 @@ class _StoreArena:
             self._scatter_lane(sorted(self._dirty_valid), 4, "valid",
                                self.valid)
             self._dirty_valid.clear()
-        return self._device
 
     def _csr_chunks(self, rows: List[int]):
         """Greedy chunks bounded in BOTH rows (<= 64) and flat CSR key
@@ -969,8 +1028,11 @@ class _StoreArena:
         self.upload_bytes += nb
         self.upload_bytes_by_field["full"] += nb
         self.upload_bytes_full_equiv += nb
+        self._rows_uploaded.inc(len(chunk))
+        self._upload_calls.inc()
         self._device = arena_scatter(
             *self._device, *(jnp.asarray(a) for a in uploads))
+        self._throttle(self._device[0])
 
     def _scatter_keys_chunk(self, chunk: List[int]) -> None:
         """Key-set-only delta: rebuild the rows' bitmaps from the CSR;
@@ -995,9 +1057,12 @@ class _StoreArena:
         nb = sum(a.nbytes for a in uploads)
         self.upload_bytes += nb
         self.upload_bytes_by_field["keys"] += nb
+        self._rows_uploaded.inc(len(chunk))
+        self._upload_calls.inc()
         d = list(self._device)
         d[0] = arena_scatter_keys(d[0], *(jnp.asarray(a) for a in uploads))
         self._device = tuple(d)
+        self._throttle(d[0])
 
     def _scatter_lane(self, rows: List[int], lane: int, field: str,
                       src: np.ndarray) -> None:
@@ -1011,7 +1076,9 @@ class _StoreArena:
         def account(nbytes: int, _m: int) -> None:
             self.upload_bytes += nbytes
             self.upload_bytes_by_field[field] += nbytes
+            self._upload_calls.inc()
 
+        self._rows_uploaded.inc(len(rows))
         d = list(self._device)
         d[lane] = flush_lane(d[lane], rows, src, account)
         self._device = tuple(d)
@@ -1021,7 +1088,18 @@ class _StoreArena:
         row kid = the packed row-mask of the key with that dense id. Synced
         by word-granular deltas -- each dirty (kid, word) coordinate ships
         the word's FULL current value (host-deduped set, so no read-modify-
-        write hazard), chunked through the shared scatter_nnz tiers."""
+        write hazard), chunked through the shared scatter_nnz tiers; under
+        resolver.arena_sync where there is anything to ship."""
+        w = self.cap // 32
+        if self._kid_dev is None or self._dirty_kid_words \
+                or self._kid_dev.shape != (self.kid_cap, w):
+            with self._phase("resolver.arena_sync",
+                             "resolver.arena_sync_s"):
+                self._sync_kids()
+                self._pending.clear()
+        return self._kid_dev
+
+    def _sync_kids(self) -> None:
         import jax.numpy as jnp
         from accord_tpu.ops.kernels import kid_word_scatter, scatter_nnz_tier
         w = self.cap // 32
@@ -1056,10 +1134,11 @@ class _StoreArena:
                 # full-equivalent baseline too (granular-vs-full deltas
                 # stay a statement about the row lanes)
                 self.upload_bytes_full_equiv += nb
+                self._upload_calls.inc()
                 self._kid_dev = kid_word_scatter(
                     self._kid_dev, jnp.asarray(kid_idx),
                     jnp.asarray(word_idx), jnp.asarray(words))
-        return self._kid_dev
+                self._throttle(self._kid_dev)
 
     def key_index(self):
         """(keys_sorted int64[n], kids int32[n]) over every key the arena
@@ -1844,6 +1923,28 @@ class BatchDepsResolver(DepsResolver):
     range_filtered_decodes = RegCounter("resolver.range_filtered_decodes")
     # calls of _cut_csr, the whole-dispatch cut: one a domain a group
     array_cuts = RegCounter("resolver.array_cuts")
+    # the store's lifecycle, each under its own span. The arenas count the
+    # first three groups into this registry themselves: device sync
+    # (resolver.arena_sync: dirty rows shipped by device_arrays(), dirty
+    # words by kid_arrays(); rows of any lane, and device scatter calls),
+    # compaction (resolver.compact: attempts timed, rebuilds counted with
+    # the rows they kept) and growth (resolver.grow: host lanes and the
+    # on-device pad). Then the mutation fence (resolver.fence: finalized
+    # lanes materialized ahead of a truncation or prune) and the
+    # truncations themselves (resolver.truncate, which holds the fence:
+    # on_truncate and on_prune, and the rows they emptied)
+    arena_sync_s = RegTimer("resolver.arena_sync_s")
+    arena_rows_uploaded = RegCounter("resolver.arena_rows_uploaded")
+    arena_upload_calls = RegCounter("resolver.arena_upload_calls")
+    compact_s = RegTimer("resolver.compact_s")
+    arena_compactions = RegCounter("resolver.arena_compactions")
+    compact_rows_kept = RegCounter("resolver.compact_rows_kept")
+    grow_s = RegTimer("resolver.grow_s")
+    arena_growths = RegCounter("resolver.arena_growths")
+    fence_s = RegTimer("resolver.fence_s")
+    fence_materializes = RegCounter("resolver.fence_materializes")
+    truncate_s = RegTimer("resolver.truncate_s")
+    truncated_txns = RegCounter("resolver.truncated_txns")
     # host launch time of the sharded finalize compaction (per-shard
     # popcount/prefix + gather-merge) on multi-device meshes
     shard_merge_s = RegTimer("resolver.shard_merge_s")
@@ -2153,7 +2254,8 @@ class BatchDepsResolver(DepsResolver):
                 enc = self._encoders[id(store.node)] = _NodeEncoder()
             arena = _StoreArena(self.num_buckets, self.initial_cap,
                                 self.range_cap, shared_encoder=enc,
-                                kid_cap=self.kid_cap)
+                                kid_cap=self.kid_cap, metrics=self.metrics,
+                                account=self._occ)
             self._arenas[id(store)] = arena
             # adopt anything registered before the resolver was attached
             for key, cfk in store.cfks.items():
@@ -2184,45 +2286,63 @@ class BatchDepsResolver(DepsResolver):
         materialized deps are plain host objects the mutation cannot
         touch -- so the later harvest decodes from the cache instead of
         paying the legacy-fallback readback. On a real device this
-        blocks on the in-flight transfer; truncation waves are rare
-        (durability cadence) next to the per-tick dispatch rate."""
+        blocks the store's thread on every in-flight call: 1.08-1.13 s a
+        wave with a round's four dispatches of 1,024 in flight at 262,144
+        rows, 268 us a subject, nearly all of it the wait for the device
+        (my chip run 1, PR 33; `fence_us_per_subject.batch`)."""
         q = self._inflight.get(id(store.node))
         if not q:
             return
-        for call in q:
-            for g in call.groups:
-                if g.arena is not arena:
-                    continue
-                key_ok = g.gen == arena.gen and g.kseq == arena.kseq
-                if g.fin_slots is not None and g.fin_mat is None and key_ok:
+        ranges = arena.ranges
+
+        def lanes(g):
+            """Which of g's finalized lanes (key, range, rk) still want
+            materializing: planned, not cached yet, guards intact."""
+            key_ok = g.gen == arena.gen and g.kseq == arena.kseq
+            return (g.fin_slots is not None and g.fin_mat is None and key_ok,
+                    g.rents is not None and g.rmat is None
+                    and g.rgen == ranges.gen and g.rseq == ranges.rseq,
+                    g.rk_slots is not None and g.rk_mat is None and key_ok)
+
+        todo = [(call, g, want) for call in q for g in call.groups
+                if g.arena is arena and any(want := lanes(g))]
+        if not todo:
+            return  # every later call of a wave: the first one cached all
+        with self._phase("resolver.fence", "resolver.fence_s"):
+            for call, g, (key, rng, rkey) in todo:
+                if key:
                     g.fin_mat = self._materialize_finalized(call, g)
-                if g.rents is not None and g.rmat is None \
-                        and g.rgen == arena.ranges.gen \
-                        and g.rseq == arena.ranges.rseq:
+                    self.fence_materializes += g.fin_mat is not None
+                if rng:
                     # stage 1 only: the host-map filters (stage 2) run at
                     # harvest against post-mutation state, keeping fenced
                     # and guarded harvests bit-identical
                     g.rmat = self._stab_range_finalized(call, g)
-                if g.rk_slots is not None and g.rk_mat is None and key_ok:
+                    self.fence_materializes += g.rmat is not None
+                if rkey:
                     g.rk_mat = self._stab_rkey_finalized(call, g)
+                    self.fence_materializes += g.rk_mat is not None
 
     def on_truncate(self, store, txn_id: TxnId) -> None:
         arena = self._arenas.get(id(store))
         if arena is None:
             return
-        self._fence_finalized(store, arena)
-        row = arena.row_of.get(txn_id)
-        if row is not None:
-            # the arena is per store, so every key in the row is this
-            # store's record -- no slice filtering needed anymore
-            arena.remove_keys(txn_id, arena.key_sets[row])
-        arena.ranges.truncate(txn_id)
+        with self._phase("resolver.truncate", "resolver.truncate_s"):
+            self._fence_finalized(store, arena)
+            row = arena.row_of.get(txn_id)
+            if row is not None:
+                # the arena is per store, so every key in the row is this
+                # store's record -- no slice filtering needed anymore
+                self.truncated_txns += arena.remove_keys(
+                    txn_id, arena.key_sets[row])
+            arena.ranges.truncate(txn_id)
 
     def on_prune(self, store, txn_id: TxnId, keys) -> None:
         arena = self._arenas.get(id(store))
         if arena is not None:
-            self._fence_finalized(store, arena)
-            arena.remove_keys(txn_id, keys)
+            with self._phase("resolver.truncate", "resolver.truncate_s"):
+                self._fence_finalized(store, arena)
+                self.truncated_txns += arena.remove_keys(txn_id, keys)
 
     # -- async batched path (the hot path) ------------------------------------
     def enqueue_preaccept(self, store, txn_id, partial_txn, route,

@@ -52,6 +52,7 @@ from accord_tpu.primitives.txn import Txn
 from accord_tpu.serve import transport
 from accord_tpu.serve.admission import AdmissionController
 from accord_tpu.sim.list_store import ListQuery, ListRead, ListStore
+from accord_tpu.utils.collector import settled_collector
 from accord_tpu.utils.rng import RandomSource
 
 
@@ -513,8 +514,11 @@ class NodeServer:
         self.log(f"serving node {self.cfg.node_id} on {bind}:{port}"
                  + (f" (advertised {host})" if bind != host else ""))
         ticker = self._loop.create_task(self._ticker())
+        # a node's stores hold millions of acyclic objects: full collections
+        # are stalls of the loop that free nothing (utils/collector.py)
         try:
-            await self._stopping.wait()
+            with settled_collector():
+                await self._stopping.wait()
         finally:
             ticker.cancel()
             self._server.close()

@@ -1,5 +1,5 @@
-"""The benchmark's per-layer metrics against the program's counters: the
-batch runner in process at the cell's rehearsal sizes, then every
+"""The benchmark's per-layer metrics against the program's counters: each
+cell's runner in process at the cell's rehearsal sizes, then every
 `benchmark/layer_metrics/*.batch.json` that BENCHMARK.json lists for the
 cell through `common.evaluate_ratio`. A counter renamed in the program, a
 metric file without its manifest entry, or the two disagreeing, fails here
@@ -14,12 +14,21 @@ from benchmark import common
 
 CELL = "preaccept-batch-10k.resolve-4096"
 RANGE_CELL = "preaccept-ranges-10k.range-20"
+LIVE_CELL = "preaccept-batch-100k.resolve-4096"
+CELLS = (CELL, RANGE_CELL, LIVE_CELL)
 MANIFEST = common.load_json(common.ROOT / "BENCHMARK.json")
 RANGE_METRICS = ("range_encode_us_per_subject.batch",
                  "range_decode_us_per_subject.batch",
                  "range_intervals_per_subject.batch",
                  "range_deps_per_subject.batch",
                  "range_device_us_per_dispatch.batch")
+LIVE_METRICS = ("preaccept_us_per_subject.batch",
+                "arena_sync_us_per_subject.batch",
+                "arena_rows_uploaded_per_subject.batch",
+                "truncate_us_per_txn.batch",
+                "fence_us_per_subject.batch",
+                "compact_ms_per_compaction.batch",
+                "arena_sync_device_us_per_dispatch.batch")
 
 
 def listed(cell):
@@ -28,7 +37,7 @@ def listed(cell):
 
 
 LISTED = [pytest.param(cell, m, id=f"{cell.split('.')[1]}-{m['name']}")
-          for cell in (CELL, RANGE_CELL) for m in listed(cell)]
+          for cell in CELLS for m in listed(cell)]
 
 
 def _run(cell_name):
@@ -47,7 +56,7 @@ def _run(cell_name):
 @pytest.fixture(scope="module")
 def runs():
     """The counters of one rehearsal-size run of each cell."""
-    return {cell: _run(cell) for cell in (CELL, RANGE_CELL)}
+    return {cell: _run(cell) for cell in CELLS}
 
 
 @pytest.fixture(scope="module")
@@ -69,6 +78,10 @@ def test_the_cell_lists_its_metrics():
     assert [m["name"] for m in listed(RANGE_CELL)] == \
         [n for n in names if n not in RANGE_METRICS] + list(RANGE_METRICS)
     assert not set(names) & set(RANGE_METRICS)
+    # and the live cell the sibling's and its own seven (PR 33)
+    assert [m["name"] for m in listed(LIVE_CELL)] == \
+        [n for n in names if n not in RANGE_METRICS] + list(LIVE_METRICS)
+    assert not set(names) & set(LIVE_METRICS)
 
 
 @pytest.mark.parametrize("cell,entry", LISTED)
@@ -79,7 +92,8 @@ def test_manifest_entry_and_metric_file_agree(cell, entry):
     for key in ("name", "unit", "layer", "better", "source", "moves"):
         assert spec[key] == entry[key], f"{entry['name']}: {key} differs"
     assert spec["runner"] == (
-        "ranges" if entry["name"] in RANGE_METRICS else "batch")
+        "ranges" if entry["name"] in RANGE_METRICS
+        else "live" if entry["name"] in LIVE_METRICS else "batch")
 
 
 @pytest.mark.parametrize("cell,entry", LISTED)
@@ -106,6 +120,22 @@ def test_range_metrics_read_nothing_where_the_program_has_no_range_path(
     for name in RANGE_METRICS:
         spec = common.load_json(common.HERE / "layer_metrics" / f"{name}.json")
         assert common.evaluate_ratio(spec, counters) is None, name
+
+
+def test_live_metrics_read_nothing_on_the_static_cells(runs):
+    """A store that never registers, truncates or fills (the two accepted
+    cells, or a parent without the counters) gives the seven nothing to
+    read; the preaccept span opens on every tick and finds an empty queue
+    there, so that one reads next to 0."""
+    for cell in (CELL, RANGE_CELL):
+        for name in LIVE_METRICS:
+            spec = common.load_json(
+                common.HERE / "layer_metrics" / f"{name}.json")
+            value = common.evaluate_ratio(spec, runs[cell])
+            if name == "preaccept_us_per_subject.batch":
+                assert 0.0 <= value < 1.0, (cell, value)
+            else:
+                assert not value, (cell, name, value)
 
 
 def test_fetch_split_is_the_readback_metric(counters):

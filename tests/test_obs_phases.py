@@ -15,7 +15,12 @@ Load-bearing properties:
   5. the flight recorder's vocabulary is what it was before the primitive;
   6. the range path -- at the range cell's rehearsal sizes its two spans lie
      inside their parents, their timers inside the parents' timers, and its
-     counters move for range subjects only.
+     counters move for range subjects only;
+  7. the store's lifecycle -- at the live cell's rehearsal sizes the five
+     spans (arena_sync, compact, grow, fence, truncate) lie inside their
+     parents, their timers inside the parents' timers, their counters move
+     only where a registration, a wave or a fill happened, and the arena's
+     device programs carry their stage names.
 """
 from __future__ import annotations
 
@@ -588,3 +593,169 @@ def test_range_spans_nest_inside_their_parents(range_arena, tmp_path):
         for s, e in spans[child]:
             assert any(ps <= s and e <= pe for ps, pe in spans[parent]), \
                 f"a {child} span lies outside every {parent} span"
+
+
+# -- (g) the store's lifecycle --------------------------------------------------
+
+LIVE_CELL = "preaccept-batch-100k.resolve-4096"
+LIFECYCLE = ("resolver.arena_sync_s", "resolver.arena_rows_uploaded",
+             "resolver.arena_upload_calls", "resolver.compact_s",
+             "resolver.arena_compactions", "resolver.compact_rows_kept",
+             "resolver.grow_s", "resolver.arena_growths", "resolver.fence_s",
+             "resolver.fence_materializes", "resolver.truncate_s",
+             "resolver.truncated_txns")
+
+
+def _live_params():
+    from benchmark import common
+    cell = common.load_json(common.HERE / "workloads" / f"{LIVE_CELL}.json")
+    config = common.load_json(
+        common.HERE / "configs" / f"{cell['config']}.json")
+    # cap 128 -> 256 and a compaction every 4th round: the rehearsal's own
+    # shape is longer, so that the runner's set-up has rounds to fill
+    return {**config, **cell, **cell["rehearsal"],
+            "resident_rounds": 4, "steady_cap": 256}
+
+
+def _query_round(live):
+    """A round of bare queries (`enqueue_deps`): nothing is registered, no
+    wave runs. The change of every counter over it."""
+    from benchmark import common
+    subjects = [live.fresh() for _ in range(live.p["subjects"])]
+    answers = []
+    before = live.counters()
+    for txn_id, _, partial, _ in subjects:
+        live.resolver.enqueue_deps(
+            live.store, txn_id, live.store.owned(partial.keys),
+            txn_id.as_timestamp()).add_callback(
+                lambda value, failure: answers.append((value, failure)))
+    live.cluster.queue.drain(max_events=1_000_000)
+    assert len(answers) == len(subjects)
+    assert all(f is None for _, f in answers)
+    return common.delta(live.counters(), before)
+
+
+def test_lifecycle_counters_move_only_where_something_happened():
+    from benchmark import common
+    from benchmark.runners import live as live_runner
+    p = _live_params()
+    live = live_runner.Deployment(p, 11)
+    seen = set()
+    for i in range(p["resident_rounds"] + 8):
+        before = live.counters()
+        r = live.round()
+        d = common.delta(live.counters(), before)
+        assert (r["wrong"], r["failed"], r["refused"]) == (0, 0, 0)
+        # a registration: every round has them, and they are what syncs
+        assert d["resolver.arena_rows_uploaded"] >= p["subjects"]
+        assert 0.0 < d["resolver.arena_sync_s"] <= d["resolver.encode_s"] \
+            <= d["resolver.starved_stage_s"]
+        # a wave: truncation and the fence, and only then
+        waved = bool(r["waved"])
+        assert bool(d.get("resolver.truncated_txns")) == waved
+        assert bool(d.get("resolver.fence_materializes")) == waved
+        assert (d.get("resolver.truncate_s", 0.0) > 0.0) == waved
+        assert d.get("resolver.fence_s", 0.0) <= \
+            d.get("resolver.truncate_s", 0.0)
+        # a fill: the arena was full when a row was asked for, so it grew
+        # or compacted, and only then
+        filled = bool(d.get("resolver.arena_compactions")
+                      or d.get("resolver.arena_growths"))
+        assert (d.get("resolver.compact_s", 0.0) > 0.0) == filled
+        assert d.get("resolver.compact_s", 0.0) + \
+            d.get("resolver.grow_s", 0.0) <= d["resolver.preaccept_s"]
+        seen |= {k for k in LIFECYCLE if d.get(k)}
+    assert seen == set(LIFECYCLE)
+    # a round of bare queries ships what the last wave left dirty (the rows
+    # it emptied: their key sets and valid flags, and the kid words), and
+    # nothing else moves; the next one finds nothing registered, no wave,
+    # no fill: none of them moves
+    d = _query_round(live)
+    assert d["resolver.arena_rows_uploaded"] == 2 * p["subjects"]
+    assert {k for k in LIFECYCLE if d.get(k)} == {
+        "resolver.arena_sync_s", "resolver.arena_rows_uploaded",
+        "resolver.arena_upload_calls"}
+    d = _query_round(live)
+    assert d["resolver.dispatches"] > 0 and d["resolver.subjects"] > 0
+    assert [k for k in LIFECYCLE if d.get(k)] == []
+
+
+def test_lifecycle_spans_nest_inside_their_parents(tmp_path):
+    import jax
+    from benchmark.runners import live as live_runner
+    p = _live_params()
+    live = live_runner.Deployment(p, 13)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        # from an empty store: the growth, the first waves, a compaction
+        for _ in range(p["resident_rounds"] + 5):
+            r = live.round()
+            assert (r["wrong"], r["failed"], r["refused"]) == (0, 0, 0)
+    finally:
+        jax.profiler.stop_trace()
+    assert live.resolver.arena_growths and live.resolver.arena_compactions
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    spans = {}
+    for plane in data.planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("resolver."):
+                    assert plane.name.startswith("/host:")
+                    spans.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    for child, parent in (("resolver.arena_sync", "resolver.encode"),
+                          ("resolver.compact", "resolver.preaccept"),
+                          ("resolver.grow", "resolver.preaccept"),
+                          ("resolver.fence", "resolver.truncate"),
+                          ("resolver.preaccept", "resolver.tick"),
+                          ("resolver.encode", "resolver.tick")):
+        assert spans.get(child), f"no {child} span on a host plane"
+        for s, e in spans[child]:
+            assert any(ps <= s and e <= pe for ps, pe in spans[parent]), \
+                f"a {child} span lies outside every {parent} span"
+    # a truncation is the store's call: inside no phase of the pipeline
+    for s, e in spans["resolver.truncate"]:
+        assert not any(ps <= s and e <= pe
+                       for name in ("resolver.tick", "resolver.harvest")
+                       for ps, pe in spans[name])
+    assert len(spans["resolver.truncate"]) == live.resolver.truncated_txns
+    assert len(spans["resolver.fence"]) == 5  # one a wave
+
+
+ARENA_SCOPES = {
+    "arena_scatter": ("bitmap_rebuild", "lane_scatter"),
+    "arena_scatter_keys": ("bitmap_rebuild",),
+    "scatter_rows": ("lane_scatter",),
+    "kid_word_scatter": ("kid_word_scatter",),
+    "arena_grow": ("arena_grow",),
+}
+
+
+@pytest.mark.parametrize("program", sorted(ARENA_SCOPES))
+def test_arena_programs_carry_their_scope_names(program):
+    from accord_tpu.ops import kernels
+    cap, k, m, z = 64, 32, 8, 64
+    bm = np.zeros((cap, k), np.float32)
+    ts = np.zeros((cap, 3), np.int32)
+    kd = np.zeros(cap, np.int32)
+    vl = np.zeros(cap, bool)
+    rows = np.zeros(m, np.int32)
+    csr = (np.full(z, cap, np.int32), np.zeros(z, np.int32))
+    args = {
+        "arena_scatter": (bm, ts, ts, kd, vl, rows, *csr, ts[:m], ts[:m],
+                          kd[:m], vl[:m]),
+        "arena_scatter_keys": (bm, rows, *csr),
+        "scatter_rows": (ts, rows, ts[:m]),
+        "kid_word_scatter": (np.zeros((16, cap // 32), np.uint32),
+                             np.full(z, 16, np.int32), np.zeros(z, np.int32),
+                             np.zeros(z, np.uint32)),
+        "arena_grow": (bm, ts, ts, kd, vl),
+    }[program]
+    kwargs = {"new_cap": 2 * cap} if program == "arena_grow" else {}
+    text = getattr(kernels, program).lower(*args, **kwargs).as_text(
+        debug_info=True)
+    missing = [s for s in ARENA_SCOPES[program] if f"/{s}/" not in text]
+    assert not missing, f"{program} lowered without scopes {missing}"
