@@ -337,9 +337,10 @@ GLOSSARY: Dict[str, str] = {
     "resolver.range_array_decodes": "groups whose range lanes decoded as arrays over the whole dispatch",
     "resolver.range_filtered_decodes": "of those, groups that applied the host-map filters a dependency at a time (fenced cache, guards since broken)",
     "resolver.array_cuts": "calls of the whole-dispatch cut (_cut_csr: every item's KeyDeps or RangeDeps from one sort and one cut over the dispatch's pairs), one a domain a group",
-    "resolver.arena_sync_s": "encode_s spent bringing the device arena up to the host shadows: dirty rows shipped by device_arrays(), dirty kid words by kid_arrays() (the host waits for the oldest of the device's copies past _SYNC_QUEUED queued)",
+    "resolver.arena_sync_s": "encode_s spent bringing the device arena up to the host shadows: dirty rows shipped by device_arrays(), dirty kid words by kid_arrays() (host time of the calls: the scatters rewrite in place and the host waits for none)",
     "resolver.arena_rows_uploaded": "arena rows shipped to the device, of any lane group (whole rows, key sets, one lane)",
     "resolver.arena_upload_calls": "device scatter calls those uploads took (arena_scatter, arena_scatter_keys, scatter_rows, kid_word_scatter)",
+    "resolver.arena_scatters_donated": "of those, calls that rewrote in place lanes the sync owned (arena_scatter, arena_scatter_keys, kid_word_scatter; not the first after device_arrays() or kid_arrays() handed the lanes out, which works on a copy, and never scatter_rows)",
     "resolver.compact_s": "time in _StoreArena.compact(): the scan for live rows and, where they fit half the capacity, the rebuild (refused attempts too)",
     "resolver.arena_compactions": "key-arena compactions that rebuilt the row mapping",
     "resolver.compact_rows_kept": "live rows those compactions kept",

@@ -285,12 +285,22 @@ def scatter_rows(dst, idx, rows):
 
 
 @jax.jit
+def arena_copy(*lanes):
+    """A device copy of each of `lanes`: the first step of an arena sync
+    that starts from lanes a staged plan may hold (resolver._StoreArena).
+    The copies are the sync's own, so every scatter after it donates."""
+    with jax.named_scope("arena_copy"):
+        return tuple(jnp.copy(a) for a in lanes)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
 def kid_word_scatter(kid_rows, kid_idx, word_idx, words):
     """Incremental update of the per-key packed row-mask mirror
     (finalize_csr's kid_rows lane): write whole u32 WORDS at (kid, word)
     coordinates. The host dedupes coordinates and sources each word's full
     current value, so duplicate-index write hazards never arise; padding
-    entries use kid_idx == KC (out of bounds, dropped)."""
+    entries use kid_idx == KC (out of bounds, dropped). `kid_rows` is
+    DONATED and rewritten in place: the caller owns it (see arena_scatter)."""
     with jax.named_scope("kid_word_scatter"):
         return kid_rows.at[kid_idx, word_idx].set(words, mode="drop")
 
@@ -896,7 +906,7 @@ def _range_finalize_csr_body(iv_of, iv_start, iv_end, ent_ok,
     return indptr, dep_rows, dep_ts, bound, csum
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4))
 def arena_scatter(bitmaps, ts, exec_ts, kinds, valid,
                   rows, key_rows, key_mods, ts_rows, exec_rows, kind_rows,
                   valid_rows):
@@ -906,26 +916,38 @@ def arena_scatter(bitmaps, ts, exec_ts, kinds, valid,
     row's bitmap is zeroed, then its current buckets scatter-set, so rows
     whose key sets shrank lose their stale bits. Row-padding duplicates
     row[0] with identical lane data -- harmless double write. The stages
-    carry jax.named_scope names (metadata only), as deps_resolve's do."""
+    carry jax.named_scope names (metadata only), as deps_resolve's do.
+
+    The five lanes are DONATED and rewritten in place (undonated, each call
+    copied the whole [cap, K] bitmap to change 64 rows of it): the caller
+    owns them, and must never pass an array a staged plan may still hold.
+    _StoreArena keeps that rule; arena_scatter_keys and kid_word_scatter
+    follow it too."""
     with jax.named_scope("bitmap_rebuild"):
         cleared = bitmaps.at[rows].set(0.0)
         rebuilt = cleared.at[key_rows, key_mods].max(1.0, mode="drop")
     with jax.named_scope("lane_scatter"):
+        # the two [cap, 3] lanes by ELEMENT: the chip gives a row scatter's
+        # operand the layout {1,0:T(8,128)}, three columns padded to 128,
+        # so each lane was copied into a 134 MB temporary and back, 0.41 ms
+        # a lane a call at 262,144 rows, which donation does not remove
+        # (my chip run 1, PR 34; by element: no copy, under 0.12 ms)
+        at = (rows[:, None], jnp.arange(3, dtype=jnp.int32)[None, :])
         return (rebuilt,
-                ts.at[rows].set(ts_rows),
-                exec_ts.at[rows].set(exec_rows),
+                ts.at[at].set(ts_rows),
+                exec_ts.at[at].set(exec_rows),
                 kinds.at[rows].set(kind_rows),
                 valid.at[rows].set(valid_rows))
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0,))
 def arena_scatter_keys(bitmaps, rows, key_rows, key_mods):
     """Field-granular scatter for KEY-SET-ONLY row changes (key widening,
     prune/truncate shrinks): rebuild the dirty rows' bitmaps from the CSR
     without shipping the ts/exec/kind/valid lanes the change didn't touch.
     Same clear-then-max CSR contract as arena_scatter. (The [kmin, kmax]
     hull lanes this used to refresh are retired -- the range kernel now
-    contracts over the same bitmaps.)"""
+    contracts over the same bitmaps.) `bitmaps` is DONATED."""
     with jax.named_scope("bitmap_rebuild"):
         cleared = bitmaps.at[rows].set(0.0)
         return cleared.at[key_rows, key_mods].max(1.0, mode="drop")
@@ -1621,6 +1643,7 @@ def jit_cache_sizes() -> dict:
         "fused_range_deps_resolve": fused_range_deps_resolve._cache_size(),
         "arena_scatter": arena_scatter._cache_size(),
         "arena_scatter_keys": arena_scatter_keys._cache_size(),
+        "arena_copy": arena_copy._cache_size(),
         "scatter_rows": scatter_rows._cache_size(),
         "range_scatter": range_scatter._cache_size(),
         "finalize_csr": finalize_csr._cache_size(),

@@ -174,7 +174,8 @@ def warmup(num_buckets: int = 1024, cap: int = 8192,
     requests inside its timed windows at zero against exactly this coverage
     (compile_requests_in_window), including the field-granular delta scatters
     (arena_scatter_keys and the single-lane scatter_rows used by ts-only /
-    valid-only updates). `exec_caps` additionally warms the exec_plane's
+    valid-only updates) and the arena_copy a sync starts with where its
+    lanes were handed out. `exec_caps` additionally warms the exec_plane's
     per-field lane deltas (exec-ts / applied / pending rows) for each
     execution-arena capacity in use. `out_tiers` (opt-in: it multiplies the
     cross product) warms the finalized-CSR harvest kernels -- finalize_csr
@@ -210,7 +211,8 @@ def warmup(num_buckets: int = 1024, cap: int = 8192,
     across every (cmd arena cap x out_cap) the progress sweeps query."""
     import jax.numpy as jnp
     from accord_tpu.ops.kernels import (NNZ_TIERS, SCATTER_NNZ_TIERS,
-                                        arena_scatter, arena_scatter_keys,
+                                        arena_copy, arena_scatter,
+                                        arena_scatter_keys,
                                         deps_resolve, fused_deps_resolve,
                                         fused_range_deps_resolve,
                                         range_deps_resolve, range_scatter,
@@ -232,14 +234,21 @@ def warmup(num_buckets: int = 1024, cap: int = 8192,
     rvl = jnp.zeros(range_cap, bool)
     table = jnp.asarray(WITNESS_TABLE)
     out = None
+    if scatter_tiers:
+        # a sync's first step where its lanes were handed out: a copy of
+        # all five (whole rows) or of the bitmap alone (key sets)
+        bm, ts, ex, kd, vl = arena_copy(bm, ts, ex, kd, vl)
+        bm, = arena_copy(bm)
     for m in scatter_tiers:
         for z in scatter_nnz_tiers:
-            out = arena_scatter(
+            # the arena's scatters donate the lanes they rewrite: thread
+            # the outputs through, the later programs here read them
+            bm, ts, ex, kd, vl = arena_scatter(
                 bm, ts, ex, kd, vl, jnp.zeros(m, jnp.int32),
                 jnp.full(z, cap, jnp.int32), jnp.zeros(z, jnp.int32),
                 jnp.zeros((m, 3), jnp.int32), jnp.zeros((m, 3), jnp.int32),
                 jnp.zeros(m, jnp.int32), jnp.zeros(m, bool))
-            out = arena_scatter_keys(
+            out = bm = arena_scatter_keys(
                 bm, jnp.zeros(m, jnp.int32),
                 jnp.full(z, cap, jnp.int32), jnp.zeros(z, jnp.int32))
         out = range_scatter(
@@ -290,11 +299,11 @@ def warmup(num_buckets: int = 1024, cap: int = 8192,
         from accord_tpu.ops.kernels import (finalize_csr, kid_word_scatter,
                                             range_finalize_csr)
         w = cap // 32
-        kid_rows = jnp.zeros((kid_cap, w), jnp.uint32)
+        kid_rows, = arena_copy(jnp.zeros((kid_cap, w), jnp.uint32))
         for z in scatter_nnz_tiers:
-            out = kid_word_scatter(kid_rows, jnp.full(z, kid_cap, jnp.int32),
-                                   jnp.zeros(z, jnp.int32),
-                                   jnp.zeros(z, jnp.uint32))
+            out = kid_rows = kid_word_scatter(
+                kid_rows, jnp.full(z, kid_cap, jnp.int32),
+                jnp.zeros(z, jnp.int32), jnp.zeros(z, jnp.uint32))
         zero_off = jnp.asarray(0, jnp.int32)
         for b in batch_tiers:
             sb = jnp.zeros((b, 3), jnp.int32)
@@ -390,17 +399,6 @@ def warmup(num_buckets: int = 1024, cap: int = 8192,
         jax.block_until_ready(out)
 
 
-# No arena scatter donates its input (a staged plan may still hold the
-# lanes it snapshotted), so each one in flight keeps a whole copy of the
-# lane it rewrites alive. A sync lets this many of them queue behind the
-# one that runs, then waits for the oldest before it enqueues the next: a
-# compaction's re-upload is 1,500 scatters of a 1 GB bitmap at 262,144
-# rows, and unchecked they filled a 16 GB chip (my chip run 1, PR 33). The
-# queue holds the lanes it may wait for, so it is counted in lanes, not in
-# bytes: a byte limit let a small arena keep 32 of them (my chip run 5).
-_SYNC_QUEUED = 2
-
-
 class _NodeEncoder:
     """The per-NODE timestamp-encoder cell shared by every store arena on
     the node: the fused cross-store kernels compare all subject/row
@@ -449,9 +447,8 @@ class _StoreArena:
             "resolver.arena_rows_uploaded")
         self._upload_calls = self._metrics.counter(
             "resolver.arena_upload_calls")
-        # the outputs of the scatters the running sync has enqueued and not
-        # yet waited for, oldest first
-        self._pending: deque = deque()
+        self._scatters_donated = self._metrics.counter(
+            "resolver.arena_scatters_donated")
         self.num_buckets = num_buckets
         self.cap = initial_cap
         self.count = 0
@@ -524,6 +521,17 @@ class _StoreArena:
         self._dirty_ts: set = set()
         self._dirty_valid: set = set()
         self._device = None
+        # WHO HOLDS A DEVICE LANE. An array that device_arrays() or
+        # kid_arrays() has returned is lent: a staged plan's closure may
+        # keep it until it launches, or re-runs at a larger out-cap, ticks
+        # later, so it is never donated. Every other array here (fresh
+        # zeros, arena_grow's pad, a scatter's output) the arena alone has
+        # seen, and the next arena_scatter / arena_scatter_keys /
+        # kid_word_scatter rewrites it in place. A sync that starts from
+        # lent lanes copies, once, each one it is about to write (_own).
+        # _lent: indexes into _device.
+        self._lent: set = set()
+        self._kid_lent = False
         # bumped by compact(): in-flight async calls hold packed rows in the
         # OLD row mapping. Dispatch pins the generation it encoded against;
         # compact() then snapshots the retiring row->txn table so the harvest
@@ -723,8 +731,11 @@ class _StoreArena:
                     self._grow_host()
                     if self._device is not None:
                         from accord_tpu.ops.kernels import arena_grow
+                        # (a pad cannot alias its input: nothing donated;
+                        # its outputs are the arena's own)
                         self._device = arena_grow(*self._device,
                                                   new_cap=self.cap)
+                        self._lent.clear()
                     self._metrics.counter("resolver.arena_growths").inc()
             row = self.count
             self.count += 1
@@ -925,17 +936,24 @@ class _StoreArena:
             with self._phase("resolver.arena_sync",
                              "resolver.arena_sync_s"):
                 self._sync_device()
-                self._pending.clear()
+        self._lent.update(range(len(self._device)))
         return self._device
 
-    def _throttle(self, lane) -> None:
-        """`lane` is the output of a scatter just enqueued: one more copy
-        queued. Past _SYNC_QUEUED, wait for the oldest (the later ones keep
-        the device busy meanwhile)."""
-        self._pending.append(lane)
-        if len(self._pending) > _SYNC_QUEUED:
-            import jax
-            jax.block_until_ready(self._pending.popleft())
+    def _own(self, lanes) -> bool:
+        """The scatter about to run donates `lanes` of _device: those that
+        were lent are replaced by a device copy first (one arena_copy call),
+        so the lent arrays stay whole for whoever holds them. True where
+        none was lent: the scatter runs in place at no copy."""
+        lent = [i for i in lanes if i in self._lent]
+        if not lent:
+            return True
+        from accord_tpu.ops.kernels import arena_copy
+        d = list(self._device)
+        for i, own in zip(lent, arena_copy(*(d[i] for i in lent))):
+            d[i] = own
+        self._device = tuple(d)
+        self._lent.difference_update(lent)
+        return False
 
     def _sync_device(self) -> None:
         import jax.numpy as jnp
@@ -949,6 +967,7 @@ class _StoreArena:
                 jnp.zeros(self.cap, jnp.int32),
                 jnp.zeros(self.cap, bool),
             )
+            self._lent.clear()
             self._dirty_full = set(range(self.count))
             self._dirty_keys.clear()
             self._dirty_ts.clear()
@@ -1030,9 +1049,10 @@ class _StoreArena:
         self.upload_bytes_full_equiv += nb
         self._rows_uploaded.inc(len(chunk))
         self._upload_calls.inc()
+        if self._own(range(5)):
+            self._scatters_donated.inc()
         self._device = arena_scatter(
             *self._device, *(jnp.asarray(a) for a in uploads))
-        self._throttle(self._device[0])
 
     def _scatter_keys_chunk(self, chunk: List[int]) -> None:
         """Key-set-only delta: rebuild the rows' bitmaps from the CSR;
@@ -1059,16 +1079,19 @@ class _StoreArena:
         self.upload_bytes_by_field["keys"] += nb
         self._rows_uploaded.inc(len(chunk))
         self._upload_calls.inc()
+        if self._own((0,)):
+            self._scatters_donated.inc()
         d = list(self._device)
         d[0] = arena_scatter_keys(d[0], *(jnp.asarray(a) for a in uploads))
         self._device = tuple(d)
-        self._throttle(d[0])
 
     def _scatter_lane(self, rows: List[int], lane: int, field: str,
                       src: np.ndarray) -> None:
         """Single-lane delta (exec-ts bumps, valid flips): ship one lane's
         dirty rows via the shared flush_lane helper (ops/deltas.py), which
-        the exec plane's field deltas ride too."""
+        the exec plane's field deltas ride too. Its scatter_rows does not
+        donate (the exec plane's holders are its own affair, and the lanes
+        here are small: `valid` is cap bytes): a copy a call, as before."""
         if not rows:
             return
         from accord_tpu.ops.deltas import flush_lane
@@ -1082,6 +1105,7 @@ class _StoreArena:
         d = list(self._device)
         d[lane] = flush_lane(d[lane], rows, src, account)
         self._device = tuple(d)
+        self._lent.discard(lane)
 
     def kid_arrays(self):
         """Device mirror of key_rows for finalize_csr: u32[kid_cap, cap/32],
@@ -1096,15 +1120,17 @@ class _StoreArena:
             with self._phase("resolver.arena_sync",
                              "resolver.arena_sync_s"):
                 self._sync_kids()
-                self._pending.clear()
+        self._kid_lent = True
         return self._kid_dev
 
     def _sync_kids(self) -> None:
         import jax.numpy as jnp
-        from accord_tpu.ops.kernels import kid_word_scatter, scatter_nnz_tier
+        from accord_tpu.ops.kernels import (arena_copy, kid_word_scatter,
+                                            scatter_nnz_tier)
         w = self.cap // 32
         if self._kid_dev is None or self._kid_dev.shape != (self.kid_cap, w):
             self._kid_dev = jnp.zeros((self.kid_cap, w), jnp.uint32)
+            self._kid_lent = False
             # wholesale rebuild: every nonzero word of every key's mask
             self._dirty_kid_words = {
                 (self.kid_of[k], int(wi))
@@ -1135,10 +1161,15 @@ class _StoreArena:
                 # stay a statement about the row lanes)
                 self.upload_bytes_full_equiv += nb
                 self._upload_calls.inc()
+                if self._kid_lent:
+                    # the table kid_arrays() returned: a plan may hold it
+                    self._kid_dev, = arena_copy(self._kid_dev)
+                    self._kid_lent = False
+                else:
+                    self._scatters_donated.inc()
                 self._kid_dev = kid_word_scatter(
                     self._kid_dev, jnp.asarray(kid_idx),
                     jnp.asarray(word_idx), jnp.asarray(words))
-                self._throttle(self._kid_dev)
 
     def key_index(self):
         """(keys_sorted int64[n], kids int32[n]) over every key the arena
@@ -1926,7 +1957,8 @@ class BatchDepsResolver(DepsResolver):
     # the store's lifecycle, each under its own span. The arenas count the
     # first three groups into this registry themselves: device sync
     # (resolver.arena_sync: dirty rows shipped by device_arrays(), dirty
-    # words by kid_arrays(); rows of any lane, and device scatter calls),
+    # words by kid_arrays(); rows of any lane, device scatter calls, and
+    # those among them that rewrote lanes the sync owned, in place),
     # compaction (resolver.compact: attempts timed, rebuilds counted with
     # the rows they kept) and growth (resolver.grow: host lanes and the
     # on-device pad). Then the mutation fence (resolver.fence: finalized
@@ -1936,6 +1968,7 @@ class BatchDepsResolver(DepsResolver):
     arena_sync_s = RegTimer("resolver.arena_sync_s")
     arena_rows_uploaded = RegCounter("resolver.arena_rows_uploaded")
     arena_upload_calls = RegCounter("resolver.arena_upload_calls")
+    arena_scatters_donated = RegCounter("resolver.arena_scatters_donated")
     compact_s = RegTimer("resolver.compact_s")
     arena_compactions = RegCounter("resolver.arena_compactions")
     compact_rows_kept = RegCounter("resolver.compact_rows_kept")
@@ -2289,7 +2322,9 @@ class BatchDepsResolver(DepsResolver):
         blocks the store's thread on every in-flight call: 1.08-1.13 s a
         wave with a round's four dispatches of 1,024 in flight at 262,144
         rows, 268 us a subject, nearly all of it the wait for the device
-        (my chip run 1, PR 33; `fence_us_per_subject.batch`)."""
+        (my chip run 1, PR 33; `fence_us_per_subject.batch`): for the
+        dispatches themselves, 0.234 s each -- it read the same once the
+        arena's sync stopped copying lanes ahead of them (PR 34)."""
         q = self._inflight.get(id(store.node))
         if not q:
             return

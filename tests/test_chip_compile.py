@@ -1,0 +1,93 @@
+"""The arena's sync programs compiled for the chip, without one: the TPU's
+compiler is installed here and compiles for a v5e that is described and not
+attached. Nothing runs; what is held is what the compiler does with the
+live cell's steady shapes (f32[262144, 1024] bitmaps, u32[16384, 8192] kid
+table):
+
+  * each program that donates aliases every lane it returns onto its
+    input and needs no temporary: no whole-lane copy survives, neither the
+    one donation removes nor a layout change (a row scatter of a [cap, 3]
+    lane cost a 134 MB temporary and two copies a call, PR 34);
+  * `arena_copy` donates nothing and returns as many bytes as it was given.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU's library at a time, and every xdist worker
+imports this file.
+"""
+from __future__ import annotations
+
+import numpy as np
+import pytest
+
+CAP, BUCKETS, KID_CAP = 262144, 1024, 16384
+
+
+@pytest.fixture(scope="module")
+def shaped():
+    """(shape, dtype) -> a ShapeDtypeStruct on one described v5e chip; the
+    persistent compile cache is off meanwhile (a program compiled for a
+    described chip cannot be read back from it)."""
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever the plugin raises
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _args(shaped, program, m, z):
+    lanes = (shaped((CAP, BUCKETS), np.float32), shaped((CAP, 3), np.int32),
+             shaped((CAP, 3), np.int32), shaped((CAP,), np.int32),
+             shaped((CAP,), np.bool_))
+    rows = shaped((m,), np.int32)
+    csr = (shaped((z,), np.int32), shaped((z,), np.int32))
+    kids = shaped((KID_CAP, CAP // 32), np.uint32)
+    return {
+        "arena_scatter": (lanes + (rows, *csr, shaped((m, 3), np.int32),
+                                   shaped((m, 3), np.int32),
+                                   shaped((m,), np.int32),
+                                   shaped((m,), np.bool_)), lanes),
+        "arena_scatter_keys": ((lanes[0], rows, *csr), lanes[:1]),
+        "kid_word_scatter": ((kids, *csr, shaped((z,), np.uint32)), (kids,)),
+        "arena_copy": (lanes, lanes),
+    }[program]
+
+
+def _bytes(structs):
+    return sum(int(np.prod(s.shape)) * np.dtype(s.dtype).itemsize
+               for s in structs)
+
+
+@pytest.mark.parametrize("program, m, z", [
+    ("arena_scatter", 64, 512), ("arena_scatter", 8, 64),
+    ("arena_scatter_keys", 64, 512), ("kid_word_scatter", 0, 512)])
+def test_a_donating_scatter_rewrites_its_lanes_in_place_on_the_chip(
+        shaped, program, m, z):
+    from accord_tpu.ops import kernels
+    args, lanes = _args(shaped, program, m, z)
+    memory = getattr(kernels, program).lower(*args).compile() \
+        .memory_analysis()
+    # the chip pads a [cap, 3] lane to four columns: at least the lanes'
+    # own bytes are aliased, and everything the program returns
+    assert _bytes(lanes) <= memory.alias_size_in_bytes \
+        <= memory.output_size_in_bytes < memory.alias_size_in_bytes + 4096
+    assert memory.temp_size_in_bytes == 0
+
+
+def test_arena_copy_aliases_nothing_on_the_chip(shaped):
+    from accord_tpu.ops import kernels
+    args, lanes = _args(shaped, "arena_copy", 0, 0)
+    memory = kernels.arena_copy.lower(*args).compile().memory_analysis()
+    assert memory.alias_size_in_bytes == 0
+    assert memory.output_size_in_bytes >= _bytes(lanes)

@@ -28,7 +28,8 @@ LIVE_METRICS = ("preaccept_us_per_subject.batch",
                 "truncate_us_per_txn.batch",
                 "fence_us_per_subject.batch",
                 "compact_ms_per_compaction.batch",
-                "arena_sync_device_us_per_dispatch.batch")
+                "arena_sync_device_us_per_dispatch.batch",
+                "arena_donated_share.batch")
 
 
 def listed(cell):
@@ -78,7 +79,7 @@ def test_the_cell_lists_its_metrics():
     assert [m["name"] for m in listed(RANGE_CELL)] == \
         [n for n in names if n not in RANGE_METRICS] + list(RANGE_METRICS)
     assert not set(names) & set(RANGE_METRICS)
-    # and the live cell the sibling's and its own seven (PR 33)
+    # and the live cell the sibling's and its own eight (PR 33, PR 34)
     assert [m["name"] for m in listed(LIVE_CELL)] == \
         [n for n in names if n not in RANGE_METRICS] + list(LIVE_METRICS)
     assert not set(names) & set(LIVE_METRICS)
@@ -124,7 +125,7 @@ def test_range_metrics_read_nothing_where_the_program_has_no_range_path(
 
 def test_live_metrics_read_nothing_on_the_static_cells(runs):
     """A store that never registers, truncates or fills (the two accepted
-    cells, or a parent without the counters) gives the seven nothing to
+    cells, or a parent without the counters) gives the eight nothing to
     read; the preaccept span opens on every tick and finds an empty queue
     there, so that one reads next to 0."""
     for cell in (CELL, RANGE_CELL):
