@@ -15,8 +15,9 @@ from __future__ import annotations
 from typing import Callable, List, Optional, Sequence, TYPE_CHECKING
 
 from accord_tpu.local.store import CommandStore
+from accord_tpu.obs.trace import phase
 from accord_tpu.primitives.keyspace import Range, Ranges, Seekables
-from accord_tpu.utils.async_ import AsyncResult, all_of
+from accord_tpu.utils.async_ import AsyncResult, all_of, success
 from accord_tpu.utils.invariants import Invariants
 
 if TYPE_CHECKING:
@@ -48,6 +49,9 @@ class CommandStores:
         partition means per-key state never migrates between stores."""
         self.node = node
         self.splitter = splitter
+        # the fan-out's counters (map_reduce_async), in the node's registry
+        self._requests = node.metrics.counter("node.requests")
+        self._store_slices = node.metrics.counter("node.store_slices")
         per_store: List[List[Range]] = [[] for _ in range(num_stores)]
         for rng in global_ranges:
             pieces = splitter(rng, num_stores)
@@ -95,7 +99,6 @@ class CommandStores:
             if not added.is_empty():
                 pending.append(self._bootstrap(s, topology.epoch, added))
         if not pending:
-            from accord_tpu.utils.async_ import success
             return success(None)
         return all_of(pending).map(lambda _: None)
 
@@ -134,10 +137,40 @@ class CommandStores:
             # topology churn can deliver a request for ranges this node has
             # never owned (e.g. a read sliced below the route); reduce of
             # nothing is None and the caller decides how to reply
-            from accord_tpu.utils.async_ import success
             return success(None)
         chains = [s.submit(map_fn) for s in targets]
         return all_of(chains).map(lambda vs: _reduce_non_null(vs, reduce_fn))
+
+    def map_reduce_async(self, seekables: Seekables,
+                         map_fn: Callable[[CommandStore], AsyncResult],
+                         reduce_fn: Callable[[object, object], object]
+                         ) -> AsyncResult:
+        """The node's request path: ask every store `seekables` intersects
+        (`map_fn(store)` slices the request to the store and returns that
+        store's asynchronous answer, here on the micro-batched device tick)
+        and fold the answers, in store order, into one reply (reference:
+        CommandStores.mapReduceConsume, local/CommandStores.java:626, with
+        the PreAcceptOk reduce, messages/PreAccept.java:141-156). PreAccept
+        and Accept both come through here. A request no store owns completes
+        with None, as map_reduce's does. Spans node.fanout and node.reduce;
+        node.requests counts calls and node.store_slices the stores asked."""
+        metrics = self.node.metrics
+        with phase(metrics, "node.fanout", "node.fanout_s"):
+            targets = self.intersecting(seekables)
+            parts = [map_fn(s) for s in targets]
+        self._requests.inc()
+        self._store_slices.inc(len(targets))
+        if not targets:
+            return success(None)
+
+        def reduce(values: list):
+            with phase(metrics, "node.reduce", "node.reduce_s"):
+                acc = values[0]
+                for v in values[1:]:
+                    acc = reduce_fn(acc, v)
+                return acc
+
+        return all_of(parts).map(reduce)
 
     def for_each(self, seekables: Seekables,
                  fn: Callable[[CommandStore], None]) -> AsyncResult:

@@ -25,12 +25,7 @@ class Accept(Request):
         self.wait_for_epoch = max(txn_id.epoch, execute_at.epoch)
 
     def process(self, node, from_node, reply_context) -> None:
-        from accord_tpu.utils.async_ import all_of, success
-
-        stores = node.command_stores.intersecting(self.keys)
-        if not stores:
-            node.reply(from_node, reply_context, None)
-            return
+        from accord_tpu.utils.async_ import success
 
         def one_store(store):
             outcome = store.accept_op(self.txn_id, self.ballot, self.route,
@@ -54,17 +49,17 @@ class Accept(Request):
                 self.txn_id, store.owned(self.keys), self.execute_at) \
                 .map(lambda deps: AcceptOk(self.txn_id, deps))
 
-        def finish(parts):
-            reply = None
-            for part in parts:
-                if isinstance(part, (AcceptNack, AcceptRedundant)):
-                    reply = part
-                    break
-                reply = part if reply is None \
-                    else AcceptOk(self.txn_id, reply.deps.union(part.deps))
-            node.reply(from_node, reply_context, reply)
+        def merge(reply, part):
+            # the first Nack or Redundant in store order is the reply
+            if isinstance(reply, (AcceptNack, AcceptRedundant)):
+                return reply
+            if isinstance(part, (AcceptNack, AcceptRedundant)):
+                return part
+            return AcceptOk(self.txn_id, reply.deps.union(part.deps))
 
-        all_of([one_store(s) for s in stores]).on_success(finish) \
+        node.command_stores.map_reduce_async(self.keys, one_store, merge) \
+            .on_success(lambda reply: node.reply(from_node, reply_context,
+                                                 reply)) \
             .on_failure(node.agent.on_uncaught_exception)
 
     def __repr__(self):

@@ -25,41 +25,38 @@ class PreAccept(Request):
         self.wait_for_epoch = max(txn_id.epoch, min_epoch)
 
     def process(self, node, from_node, reply_context) -> None:
-        from accord_tpu.utils.async_ import all_of, success
+        def one_store(store):
+            # per-store PreAccept, micro-batched onto the device when a batch
+            # resolver is installed (store.submit_preaccept)
+            return store.submit_preaccept(
+                self.txn_id,
+                self.txn.slice(store.ranges, include_query=False),
+                self.route).map(as_reply)
 
-        stores = node.command_stores.intersecting(self.txn.keys)
-        if not stores:
-            node.reply(from_node, reply_context, None)
-            return
-        # per-store PreAccept, micro-batched onto the device when a batch
-        # resolver is installed (store.submit_preaccept)
-        parts = [s.submit_preaccept(
-                    self.txn_id, self.txn.slice(s.ranges, include_query=False),
-                    self.route)
-                 for s in stores]
+        def as_reply(result):
+            outcome, witnessed, deps = result
+            if outcome in (AcceptOutcome.REJECTED_BALLOT,
+                           AcceptOutcome.TRUNCATED):
+                return PreAcceptNack(self.txn_id)
+            return PreAcceptOk(self.txn_id, witnessed, deps)
 
-        def finish(results):
-            reply = None
-            for outcome, witnessed, deps in results:
-                if outcome in (AcceptOutcome.REJECTED_BALLOT,
-                               AcceptOutcome.TRUNCATED):
-                    reply = PreAcceptNack(self.txn_id)
-                    break
-                part = PreAcceptOk(self.txn_id, witnessed, deps)
-                if reply is None:
-                    reply = part
-                else:
-                    # (reference: PreAcceptOk reduce, messages/PreAccept.java:
-                    # 141-156; merge_witnessed keeps one store's rejection
-                    # sticky across stores)
-                    reply = PreAcceptOk(
-                        self.txn_id,
-                        Timestamp.merge_witnessed(reply.witnessed_at,
-                                                  part.witnessed_at),
-                        reply.deps.union(part.deps))
-            node.reply(from_node, reply_context, reply)
+        def merge(reply, part):
+            # (reference: PreAcceptOk reduce, messages/PreAccept.java:
+            # 141-156; merge_witnessed keeps one store's rejection sticky
+            # across stores; the first Nack in store order is the reply)
+            if isinstance(reply, PreAcceptNack):
+                return reply
+            if isinstance(part, PreAcceptNack):
+                return part
+            return PreAcceptOk(
+                self.txn_id,
+                Timestamp.merge_witnessed(reply.witnessed_at,
+                                          part.witnessed_at),
+                reply.deps.union(part.deps))
 
-        all_of(parts).on_success(finish) \
+        node.command_stores.map_reduce_async(self.txn.keys, one_store, merge) \
+            .on_success(lambda reply: node.reply(from_node, reply_context,
+                                                 reply)) \
             .on_failure(node.agent.on_uncaught_exception)
 
     def __repr__(self):

@@ -1854,7 +1854,7 @@ class _Plan:
     harvest."""
 
     __slots__ = ("items", "groups", "key_call", "range_call", "empty",
-                 "fin_calls", "rfin_calls", "kfin_calls", "want",
+                 "fused", "fin_calls", "rfin_calls", "kfin_calls", "want",
                  "key_args", "range_args",
                  "fin_args", "rfin_args", "kfin_args")
 
@@ -1865,6 +1865,9 @@ class _Plan:
         self.key_call = None        # () -> packed, or None
         self.range_call = None      # () -> (rpacked, kpacked), or None
         self.empty = empty
+        # a deferred call above runs a fused cross-store program (several
+        # store groups, routed by the store-id lane)
+        self.fused = False
         # node-lane merge inputs (ops/node_lane.py): the EXACT arrays the
         # deferred calls above would feed their kernels, recorded only when
         # a cluster tick_driver is attached -- the mesh-burn engine stacks
@@ -1954,6 +1957,10 @@ class BatchDepsResolver(DepsResolver):
     range_filtered_decodes = RegCounter("resolver.range_filtered_decodes")
     # calls of _cut_csr, the whole-dispatch cut: one a domain a group
     array_cuts = RegCounter("resolver.array_cuts")
+    # the node's fan-out as the device sees it: dispatches that ran a fused
+    # cross-store program, and the store groups that rode them
+    fused_dispatches = RegCounter("resolver.fused_dispatches")
+    store_groups = RegCounter("resolver.store_groups")
     # the store's lifecycle, each under its own span. The arenas count the
     # first three groups into this registry themselves: device sync
     # (resolver.arena_sync: dirty rows shipped by device_arrays(), dirty
@@ -2720,6 +2727,7 @@ class BatchDepsResolver(DepsResolver):
                 j_of, j_keys = jnp.asarray(subj_of), jnp.asarray(subj_keys)
                 j_store = jnp.asarray(subj_store)
                 j_sb, j_sknd = jnp.asarray(sb), jnp.asarray(sknd)
+                plan.fused = True
                 plan.key_call = (
                     lambda ksnaps=ksnaps, j_slots=j_slots, j_of=j_of,
                     j_keys=j_keys, j_store=j_store, j_sb=j_sb, j_sknd=j_sknd:
@@ -2906,6 +2914,7 @@ class BatchDepsResolver(DepsResolver):
                         j_iv[2], j_store, j_sb, j_sknd, j_srng)
                     return (rp if has_r else None, kp if has_k else None)
 
+                plan.fused = True
                 plan.range_call = range_call
                 if self.tick_driver is not None:
                     plan.range_args = dict(
@@ -4234,6 +4243,9 @@ class BatchDepsResolver(DepsResolver):
                         _dev_copy_async(dev)
                 if call.has_device:
                     self._occ.launched()
+                if plan.fused:
+                    self.fused_dispatches += 1
+                    self.store_groups += len(plan.groups)
                 if fault == "stuck":
                     plane.note("stuck")
                     self.device_faults_injected += 1

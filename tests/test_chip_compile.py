@@ -91,3 +91,30 @@ def test_arena_copy_aliases_nothing_on_the_chip(shaped):
     memory = kernels.arena_copy.lower(*args).compile().memory_analysis()
     assert memory.alias_size_in_bytes == 0
     assert memory.output_size_in_bytes >= _bytes(lanes)
+
+
+def test_the_fused_cross_store_program_fits_the_chip_at_the_node_cells_shapes(
+        shaped):
+    """`fused_deps_resolve` over eight arenas of 65,536 rows, a full
+    dispatch of 1,024 store slices (`preaccept-8stores-100k.fanout-4096`):
+    the chip's compiler takes it, the eight bitmaps are its arguments (2.15
+    GB), the packed result is one u32[1024, 8 x 2048], and what it needs
+    beside them stays a fraction of a bitmap (73 MB when PR 35 measured it):
+    no store's f32[1024, 65536] product is ever whole in memory."""
+    from accord_tpu.ops import kernels
+    from accord_tpu.ops.encoding import WITNESS_TABLE
+    cap, stores, b, z = 65536, 8, 1024, 2048
+    arena = (shaped((cap, BUCKETS), np.float32), shaped((cap, 3), np.int32),
+             shaped((cap,), np.int32), shaped((cap,), np.bool_))
+    table = np.asarray(WITNESS_TABLE)
+    memory = kernels.fused_deps_resolve.lower(
+        shaped((z,), np.int32), shaped((z,), np.int32),
+        shaped((b,), np.int32), shaped((b, 3), np.int32),
+        shaped((b,), np.int32), shaped((stores,), np.int32),
+        (arena,) * stores, shaped(table.shape, table.dtype)) \
+        .compile().memory_analysis()
+    bitmaps = stores * cap * BUCKETS * 4
+    assert bitmaps <= memory.argument_size_in_bytes < bitmaps + (64 << 20)
+    assert b * stores * cap // 8 <= memory.output_size_in_bytes \
+        < b * stores * cap // 8 + 4096
+    assert memory.temp_size_in_bytes < cap * BUCKETS * 4

@@ -15,7 +15,8 @@ from benchmark import common
 CELL = "preaccept-batch-10k.resolve-4096"
 RANGE_CELL = "preaccept-ranges-10k.range-20"
 LIVE_CELL = "preaccept-batch-100k.resolve-4096"
-CELLS = (CELL, RANGE_CELL, LIVE_CELL)
+NODE_CELL = "preaccept-8stores-100k.fanout-4096"
+CELLS = (CELL, RANGE_CELL, LIVE_CELL, NODE_CELL)
 MANIFEST = common.load_json(common.ROOT / "BENCHMARK.json")
 RANGE_METRICS = ("range_encode_us_per_subject.batch",
                  "range_decode_us_per_subject.batch",
@@ -30,6 +31,12 @@ LIVE_METRICS = ("preaccept_us_per_subject.batch",
                 "compact_ms_per_compaction.batch",
                 "arena_sync_device_us_per_dispatch.batch",
                 "arena_donated_share.batch")
+NODE_METRICS = ("store_slices_per_txn.batch",
+                "fanout_us_per_txn.batch",
+                "reduce_us_per_txn.batch",
+                "store_groups_per_dispatch.batch",
+                "fused_resolve_device_us_per_dispatch.batch",
+                "finalize_device_us_per_dispatch.batch")
 
 
 def listed(cell):
@@ -83,6 +90,10 @@ def test_the_cell_lists_its_metrics():
     assert [m["name"] for m in listed(LIVE_CELL)] == \
         [n for n in names if n not in RANGE_METRICS] + list(LIVE_METRICS)
     assert not set(names) & set(LIVE_METRICS)
+    # and the node cell the sibling's and its own six (PR 35)
+    assert [m["name"] for m in listed(NODE_CELL)] == \
+        [n for n in names if n not in RANGE_METRICS] + list(NODE_METRICS)
+    assert not set(names) & set(NODE_METRICS)
 
 
 @pytest.mark.parametrize("cell,entry", LISTED)
@@ -94,7 +105,8 @@ def test_manifest_entry_and_metric_file_agree(cell, entry):
         assert spec[key] == entry[key], f"{entry['name']}: {key} differs"
     assert spec["runner"] == (
         "ranges" if entry["name"] in RANGE_METRICS
-        else "live" if entry["name"] in LIVE_METRICS else "batch")
+        else "live" if entry["name"] in LIVE_METRICS
+        else "node" if entry["name"] in NODE_METRICS else "batch")
 
 
 @pytest.mark.parametrize("cell,entry", LISTED)
@@ -137,6 +149,25 @@ def test_live_metrics_read_nothing_on_the_static_cells(runs):
                 assert 0.0 <= value < 1.0, (cell, value)
             else:
                 assert not value, (cell, name, value)
+
+
+def test_node_metrics_read_nothing_on_a_one_store_cell(runs):
+    """The three accepted cells hold one store a dispatch and never come
+    through the node's fan-out (as a parent without the counters does not):
+    the six find nothing to read and do not raise; on the node cell the
+    fan-out's ratios are what the deployment says."""
+    for cell in (CELL, RANGE_CELL, LIVE_CELL):
+        for name in NODE_METRICS:
+            spec = common.load_json(
+                common.HERE / "layer_metrics" / f"{name}.json")
+            assert not common.evaluate_ratio(spec, runs[cell]), (cell, name)
+
+    def read(name):
+        return common.evaluate_ratio(common.load_json(
+            common.HERE / "layer_metrics" / f"{name}.json"), runs[NODE_CELL])
+    assert 3.0 <= read("store_slices_per_txn.batch") <= 3.6
+    assert read("store_groups_per_dispatch.batch") == 8.0
+    assert read("subjects_per_dispatch.batch") > 8
 
 
 def test_fetch_split_is_the_readback_metric(counters):

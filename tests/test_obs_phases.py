@@ -20,7 +20,12 @@ Load-bearing properties:
      spans (arena_sync, compact, grow, fence, truncate) lie inside their
      parents, their timers inside the parents' timers, their counters move
      only where a registration, a wave or a fill happened, and the arena's
-     device programs carry their stage names.
+     device programs carry their stage names;
+  8. the node's fan-out -- at the node cell's rehearsal sizes `node.fanout`
+     and `node.reduce` open once a request, both outside every resolver
+     phase (the fan-out in the caller's enqueue loop, the reduce where a
+     harvest delivers the last store's part), their timers move, `node.store_slices` counts the
+     stores asked, and a one-store node counts no fused dispatch.
 """
 from __future__ import annotations
 
@@ -759,3 +764,85 @@ def test_arena_programs_carry_their_scope_names(program):
         debug_info=True)
     missing = [s for s in ARENA_SCOPES[program] if f"/{s}/" not in text]
     assert not missing, f"{program} lowered without scopes {missing}"
+
+
+# -- (h) the node's fan-out -----------------------------------------------------
+
+NODE_CELL = "preaccept-8stores-100k.fanout-4096"
+
+
+def _node(stores, seed=17):
+    from benchmark import common
+    from benchmark.runners import node as node_runner
+    cell = common.load_json(common.HERE / "workloads" / f"{NODE_CELL}.json")
+    config = common.load_json(
+        common.HERE / "configs" / f"{cell['config']}.json")
+    p = {**config, **cell, **cell["rehearsal"], "stores": stores}
+    return node_runner.Deployment(p, seed), p
+
+
+@pytest.mark.parametrize("stores", [1, 8])
+def test_node_counters_count_requests_and_stores_asked(stores):
+    from benchmark import common
+    node, p = _node(stores)
+    asked = []
+    intersecting = node.stores.intersecting
+    node.stores.intersecting = lambda keys: (
+        asked.append(intersecting(keys)) or asked[-1])
+    before = node.counters()
+    assert node.round(p["subjects"])[2:4] == (0, 0)
+    d = common.delta(node.counters(), before)
+    assert d["node.requests"] == len(asked) == p["subjects"]
+    assert d["node.store_slices"] == sum(len(s) for s in asked) \
+        == d["resolver.subjects"]
+    assert d["node.fanout_s"] > 0.0 and d["node.reduce_s"] > 0.0
+    if stores == 1:
+        assert d["node.store_slices"] == d["node.requests"]
+        # one group a dispatch runs the plain kernels: neither moves
+        assert not d.get("resolver.fused_dispatches")
+        assert not d.get("resolver.store_groups")
+        assert d["resolver.dispatches"] > 0
+    else:
+        assert d["resolver.fused_dispatches"] == d["resolver.dispatches"] > 0
+        assert d["resolver.store_groups"] == 8 * d["resolver.dispatches"]
+
+
+def test_node_spans_open_once_a_request_and_nest_as_written(tmp_path):
+    import jax
+    node, p = _node(8)
+    assert node.round(p["subjects"])[2:4] == (0, 0)  # compiles
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        assert node.round(p["subjects"])[2:4] == (0, 0)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    spans = {}
+    for plane in data.planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.split(".")[0] in ("node", "resolver", "bench"):
+                    assert plane.name.startswith("/host:")
+                    spans.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+
+    def inside(span, parents):
+        return any(ps <= span[0] and span[1] <= pe
+                   for name in parents for ps, pe in spans[name])
+
+    assert len(spans["node.fanout"]) == len(spans["node.reduce"]) \
+        == p["subjects"]
+    # the fan-out is the caller's: inside the enqueue loop, in no phase of
+    # the pipeline; the reduce runs where a harvest hands out the last
+    # store's part, after that harvest's span has closed: in no phase either
+    pipeline = ("resolver.tick", "resolver.launch", "resolver.harvest")
+    assert all(inside(s, ("bench.enqueue",)) for s in spans["node.fanout"])
+    for name in ("node.fanout", "node.reduce"):
+        assert not any(inside(s, pipeline) for s in spans[name]), name
+    assert not any(inside(s, ("bench.enqueue",)) for s in spans["node.reduce"])
+    assert max(e for _, e in spans["node.fanout"]) \
+        <= min(e for _, e in spans["resolver.harvest"]) \
+        <= min(s for s, _ in spans["node.reduce"])
