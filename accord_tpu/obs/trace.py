@@ -30,6 +30,8 @@ below; unit-tested in tests/test_obs_phases.py).
 """
 from __future__ import annotations
 
+import contextlib
+import sys
 import time
 from collections import deque
 from typing import Callable, List, Optional
@@ -304,8 +306,9 @@ class phase:
     complete event on (`track`, `event`) with `ph.args` when REC is enabled,
     closes the `jax.profiler.TraceAnnotation` opened on entry -- so the span
     is in the profiler's host plane, on the device trace's clock, whenever a
-    profiler session is open, and costs well under a microsecond when none is
-    -- and tells `account` (an Occupancy) that the host left the phase. `ids`
+    profiler session is open, and costs well under a microsecond when none is;
+    a process that has not imported jax gets none and imports none --
+    and tells `account` (an Occupancy) that the host left the phase. `ids`
     (a dispatch id) become the annotation's arguments and seed `ph.args`.
     Meant per phase and per dispatch, never per item."""
 
@@ -316,7 +319,6 @@ class phase:
                  account: Optional[Occupancy] = None, node=None,
                  track: Optional[str] = None, event: Optional[str] = None,
                  **ids):
-        from jax.profiler import TraceAnnotation
         self.args = ids or None
         self.dt = 0.0
         self._timer = registry.timer(timer) if timer else None
@@ -325,7 +327,14 @@ class phase:
         self._node = node
         self._track = track
         self._event = event
-        self._span = TraceAnnotation(name, **ids)
+        # a process that never imported jax has no profiler session to
+        # annotate, and importing it here would stall a host-only node's
+        # protocol thread for seconds inside its first request
+        if "jax" in sys.modules:
+            from jax.profiler import TraceAnnotation
+            self._span = TraceAnnotation(name, **ids)
+        else:
+            self._span = contextlib.nullcontext()
 
     def __enter__(self) -> "phase":
         if self._account is not None:

@@ -540,28 +540,6 @@ def range_deps_resolve(iv_of, iv_start, iv_end, subj_before, subj_kinds,
     return _pack_bits(m_r), _pack_bits(m_k)
 
 
-def _segment_compact(hits, out_cap: int):
-    """Segment compaction: per-segment popcount -> exclusive prefix sum ->
-    masked scatter. `hits` is i32[S, N] (0/1); returns (indptr i32[S+1],
-    dep_rows i32[out_cap]) where dep_rows packs the hit COLUMN indices of all
-    segments contiguously in (segment-major, column-ascending) order. Hits
-    beyond out_cap are dropped by the scatter; callers detect overflow via
-    indptr[-1] > out_cap and fall back."""
-    s, n = hits.shape
-    with jax.named_scope("segment_prefix"):
-        counts = jnp.sum(hits, axis=1, dtype=jnp.int32)
-        indptr = jnp.concatenate(
-            [jnp.zeros(1, jnp.int32), jnp.cumsum(counts, dtype=jnp.int32)])
-        within = jnp.cumsum(hits, axis=1, dtype=jnp.int32) - hits
-        pos = jnp.where(hits > 0, indptr[:-1][:, None] + within, out_cap)
-    with jax.named_scope("row_scatter"):
-        col = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None, :],
-                               (s, n))
-        dep_rows = jnp.zeros(out_cap, jnp.int32) \
-            .at[pos.reshape(-1)].set(col.reshape(-1), mode="drop")
-    return indptr, dep_rows
-
-
 def _popcount_u32(x):
     """Branch-free SWAR popcount per u32 lane (jnp.bitwise_count is not
     available across the supported jax versions)."""
@@ -571,55 +549,104 @@ def _popcount_u32(x):
     return ((x * jnp.uint32(0x01010101)) >> 24).astype(jnp.int32)
 
 
+def _kth_set_bit(v, k):
+    """Position of the k-th set bit (k from 0) of each u32 lane, by popcount
+    halving: is it in the low half, else skip the low half's count. Lanes
+    with fewer than k + 1 bits give a meaningless position."""
+    pos = jnp.zeros_like(k)
+    for half in (16, 8, 4, 2, 1):
+        low = v & jnp.uint32((1 << half) - 1)
+        below = _popcount_u32(low)
+        up = k >= below
+        pos = pos + jnp.where(up, half, 0)
+        k = k - jnp.where(up, below, 0)
+        v = jnp.where(up, v >> half, low)
+    return pos
+
+
+def _fold_nonzero(v):
+    """u32[N] -> u32[ceil(N / 32)]: bit b of mask g says element 32 g + b is
+    non-zero (zero padding to a multiple of 32)."""
+    pad = -v.shape[0] % 32
+    if pad:
+        v = jnp.concatenate([v, jnp.zeros(pad, v.dtype)])
+    # the barrier keeps what computes `v` out of _pack_bits' [N/32, 32] view,
+    # whose 32 columns the chip pads to 128: fused in, the whole slot-mask
+    # stage ran over u32 temporaries of four times the matrix (1.2 GB of
+    # temporaries at the live cell's 4,096 x 8,192 words against 0.4 GB,
+    # compiled for a described v5e, PR 36); a bool view is a quarter of one
+    nonzero = jax.lax.optimization_barrier(v != 0)
+    return _pack_bits(nonzero.reshape(1, -1))[0]
+
+
+def _expand_masks(val, idx, n_out: int):
+    """The set bits of the masks `val` u32[C], laid out in mask order: output
+    j is the j-th set bit, reported as (idx of the mask that holds it, its
+    bit position in that mask, whether there is a j-th bit at all: the
+    first two are meaningless where the third is False). Each output FINDS
+    its bit: a non-zero mask is written once, at the output position of its
+    first bit (its exclusive popcount prefix: distinct targets, C candidates
+    and not 32 C), the up to 31 outputs behind a written one copy it in five
+    doubling steps, and the distance to it says which of the mask's bits
+    the output is."""
+    j = jnp.arange(n_out, dtype=jnp.int32)
+    with jax.named_scope("mark_scatter"):
+        pop = _popcount_u32(val)
+        end = jnp.cumsum(pop, dtype=jnp.int32)
+        at = jnp.where(val != 0, end - pop, n_out)
+        own = jnp.full(n_out, -1, jnp.int32).at[at].set(idx, mode="drop")
+        mask = jnp.zeros(n_out, jnp.uint32).at[at].set(val, mode="drop")
+    with jax.named_scope("owner_fill"):
+        first = jnp.where(own >= 0, j, 0)
+        for d in (1, 2, 4, 8, 16):
+            has = own >= 0
+            own, mask, first = (
+                jnp.where(has, x, jnp.pad(x, (d, 0))[:n_out])
+                for x in (own, mask, first))
+    with jax.named_scope("bit_select"):
+        return own, _kth_set_bit(mask, j - first), j < end[-1]
+
+
 def _packed_segment_compact(m, out_cap: int):
-    """_segment_compact over BIT-PACKED segments: `m` is u32[S, W] (each
-    segment a packed row set, cap == W*32). Crucially never materializes the
-    S x cap bit matrix -- popcounts and prefix sums run word-packed (S*W
-    elements), only the <= out_cap NONZERO words expand to bit granularity
-    (out_cap x 32). At dispatch shapes (S=2k segments, cap=16k rows) that is
-    ~30x less intermediate traffic than the dense path, which dominated the
-    kernel's wall time. Output contract matches _segment_compact: (indptr
-    i32[S+1], dep_rows i32[out_cap]) in (segment-major, row-ascending)
-    order; indptr[-1] > out_cap signals overflow (a nonzero word count can
-    never exceed the bit count, so the word compaction cannot overflow
-    without the bit total overflowing too)."""
-    s, w = m.shape
+    """Segment compaction over BIT-PACKED segments: `m` is u32[S, W] (each
+    segment a packed row set, cap == W*32). Returns (indptr i32[S+1],
+    dep_rows i32[out_cap]) where dep_rows packs the set ROW indices of all
+    segments contiguously in (segment-major, row-ascending) order and is 0
+    beyond indptr[-1]. indptr is exact from the popcounts whatever the
+    output holds: indptr[-1] > out_cap signals overflow, and dep_rows then
+    carries the first out_cap rows.
+
+    It gathers by output position (_expand_masks) where it used to scatter
+    every candidate: a TPU runs a scatter an update at a time, dropped or
+    not (4.6 ns each; `out_cap x 32` bit candidates and `S x W` word
+    candidates were 55 of the key cell's 59 ms a dispatch, ledger PR 35).
+    The flat word matrix is folded, 32 elements to a mask of which are
+    non-zero, until a level has at most out_cap masks; the top level is
+    expanded into the indices of the level below's non-zero elements, their
+    values are gathered (at most out_cap of them matter: the j-th set bit
+    lies in one of the first j + 1 non-zero words), and so on down to the
+    words, whose expansion is the rows. Groups may cross segments: the flat
+    order is the output order. The count of folds follows from S x W and
+    out_cap, both static."""
+    w = m.shape[1]
     with jax.named_scope("popcount_prefix"):
-        pop = _popcount_u32(m)                                # i32[S, W]
-        counts = jnp.sum(pop, axis=1, dtype=jnp.int32)
+        counts = jnp.sum(_popcount_u32(m), axis=1, dtype=jnp.int32)
         indptr = jnp.concatenate(
             [jnp.zeros(1, jnp.int32), jnp.cumsum(counts, dtype=jnp.int32)])
-        flat_pop = pop.reshape(-1)
-        flat_val = m.reshape(-1)
-        # global output offset of each word's first bit (word-major order ==
-        # segment-major, row-ascending)
-        bit_off = jnp.cumsum(flat_pop, dtype=jnp.int32) - flat_pop
-        nz = flat_pop > 0
-        slot = jnp.where(
-            nz, jnp.cumsum(nz.astype(jnp.int32), dtype=jnp.int32) - 1,
-            out_cap)
-    # compact the nonzero words: ONE S*W-entry scatter of flat indices, then
-    # out_cap-sized gathers for (value, bit offset, base row index) -- three
-    # full-size scatters here tripled the kernel's wall time
+    with jax.named_scope("word_fold"):
+        levels = [m.reshape(-1)]
+        while levels[-1].shape[0] > out_cap:
+            levels.append(_fold_nonzero(levels[-1]))
+    val = levels[-1]
+    idx = jnp.arange(val.shape[0], dtype=jnp.int32)
     with jax.named_scope("word_compact"):
-        src = jnp.zeros(out_cap, jnp.int32) \
-            .at[slot].set(jnp.arange(s * w, dtype=jnp.int32), mode="drop")
-        live = jnp.arange(out_cap, dtype=jnp.int32) \
-            < jnp.sum(nz.astype(jnp.int32))
-        cw_val = jnp.where(live, flat_val[src], jnp.uint32(0))
-        cw_off = bit_off[src]
-        cw_row = (src % w) * 32
-    # bit-expand only the compacted words
-    with jax.named_scope("bit_expand"):
-        bits = ((cw_val[:, None] >> jnp.arange(32, dtype=jnp.uint32)) & 1) \
-            .astype(jnp.int32)                                # [out_cap, 32]
-        within = jnp.cumsum(bits, axis=1, dtype=jnp.int32) - bits
-        pos = jnp.where((bits > 0) & live[:, None],
-                        cw_off[:, None] + within, out_cap)
-        rows = cw_row[:, None] + jnp.arange(32, dtype=jnp.int32)[None, :]
-    with jax.named_scope("row_scatter"):
-        dep_rows = jnp.zeros(out_cap, jnp.int32) \
-            .at[pos.reshape(-1)].set(rows.reshape(-1), mode="drop")
+        for lower in reversed(levels[:-1]):
+            own, bit, live = _expand_masks(val, idx, out_cap)
+            idx = jnp.where(live, own * 32 + bit, 0)
+            val = jnp.where(live, lower[idx], jnp.uint32(0))
+    with jax.named_scope("row_expand"):
+        own, bit, live = _expand_masks(val, idx, out_cap)
+        dep_rows = jnp.where(live, (own % w) * 32 + bit, 0)
     return indptr, dep_rows
 
 
@@ -898,7 +925,7 @@ def _range_finalize_csr_body(iv_of, iv_start, iv_end, ent_ok,
         witness = _witness_mask(witness_table, subj_kinds[o], r_kinds)
         before = _lex_before(r_ts[None, :, :], subj_before[o][:, None, :])
         m = stab & witness & before
-    indptr, dep_rows = _segment_compact(m.astype(jnp.int32), out_cap)
+    indptr, dep_rows = _packed_segment_compact(_pack_bits(m), out_cap)
     with jax.named_scope("ts_gather"):
         dep_ts = r_ts[dep_rows]
     with jax.named_scope("checksum"):

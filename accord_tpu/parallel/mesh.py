@@ -616,8 +616,9 @@ def _sharded_finalize_body(mesh: Mesh, packed, word_off, kid_rows,
             jnp.arange(data, dtype=jnp.int32)[:, None] < d,
             counts_all, 0), axis=0, dtype=jnp.int32)
         seg_base = seg0 + prefix
-        # local word compaction (kernels._packed_segment_compact with
-        # shard-global bit offsets and row bases)
+        # local word compaction: the scatter form kernels had until it
+        # compacted by output position (tests/test_gather_compact.py keeps
+        # it as the oracle), with shard-global bit offsets and row bases
         flat_pop = pop.reshape(-1)
         flat_val = m.reshape(-1)
         within_seg = jnp.cumsum(pop, axis=1, dtype=jnp.int32) - pop

@@ -118,3 +118,32 @@ def test_the_fused_cross_store_program_fits_the_chip_at_the_node_cells_shapes(
     assert b * stores * cap // 8 <= memory.output_size_in_bytes \
         < b * stores * cap // 8 + 4096
     assert memory.temp_size_in_bytes < cap * BUCKETS * 4
+
+
+def test_finalize_csr_keeps_no_out_cap_by_32_temporary_at_the_key_cells_shapes(
+        shaped):
+    """`finalize_csr` at `preaccept-batch-10k.resolve-4096`'s steady shapes
+    (1,024 subjects, 4,096 slots against a 16,384-row arena, out-cap
+    262,144): the scatter form expanded every compacted word to 32 bit
+    candidates, five `[out_cap, 32]` arrays of 33.5 MB in the compiled
+    program; the form that gathers by output position works in lanes of
+    out_cap elements, and the largest array it holds is the slot matrix
+    itself. (The program's peak is `ts_gather`'s, before and after: the chip
+    pads its `[out_cap, 3]` result to 128 columns, 134 MB.)"""
+    import re
+
+    from accord_tpu.ops import kernels
+    b, slots, cap, kid_cap, out_cap = 1024, 4096, 16384, 4096, 262144
+    compiled = kernels.finalize_csr.lower(
+        shaped((b, cap // 32), np.uint32), shaped((), np.int32),
+        shaped((kid_cap, cap // 32), np.uint32), shaped((slots,), np.int32),
+        shaped((slots,), np.int32), shaped((b,), np.int32),
+        shaped((cap, 3), np.int32), out_cap=out_cap).compile()
+    sizes = {dims: int(np.prod([int(d) for d in dims.split(",")]))
+             for dims in re.findall(r"\b(?:pred|[suf]\d+)\[([\d,]+)\]",
+                                    compiled.as_text())}
+    assert max(sizes.values()) == slots * (cap // 32), max(
+        sizes, key=sizes.get)
+    assert not [d for d, n in sizes.items() if n >= out_cap * 32]
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < out_cap * 128 * 4 + out_cap * 32

@@ -30,6 +30,8 @@ Load-bearing properties:
 from __future__ import annotations
 
 import contextlib
+import subprocess
+import sys
 import time
 
 import numpy as np
@@ -193,6 +195,24 @@ def test_phase_feeds_timer_recorder_and_account():
     assert len(REC.events()) == 1 and reg.timer("p.other_s").total > 0.0
 
 
+def test_a_host_only_process_imports_no_jax_for_a_phase():
+    """A maelstrom node builds no device resolver; its first request's spans
+    must not import jax on the protocol thread (seconds, against an rpc
+    time-out of three). A fresh interpreter, because this one has jax."""
+    code = (
+        "import sys\n"
+        "from accord_tpu.obs.metrics import MetricsRegistry\n"
+        "from accord_tpu.obs.trace import phase\n"
+        "reg = MetricsRegistry()\n"
+        "with phase(reg, 'node.fanout', 'node.fanout_s', did=1) as ph:\n"
+        "    pass\n"
+        "assert reg.timer('node.fanout_s').total == ph.dt > 0.0\n"
+        "assert 'jax' not in sys.modules, 'a phase imported jax'\n")
+    done = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=60)
+    assert done.returncode == 0, done.stderr
+
+
 # -- (b) a real resolver at the cell's rehearsal sizes ------------------------
 
 def _params():
@@ -327,13 +347,15 @@ def _kernel_args(seed=3, b=8, cap=64, k=32, nnz=24, s=16, kc=16):
 
 RESOLVE_SCOPES = ("subject_bitmap", "overlap", "witness_before_mask",
                   "pack_bits")
-FINALIZE_SCOPES = ("slot_mask", "bound", "popcount_prefix", "word_compact",
-                   "bit_expand", "row_scatter", "ts_gather", "checksum")
+COMPACT_SCOPES = ("popcount_prefix", "word_fold", "word_compact",
+                  "row_expand", "mark_scatter", "owner_fill", "bit_select")
+FINALIZE_SCOPES = ("slot_mask", "bound", *COMPACT_SCOPES, "ts_gather",
+                   "checksum")
 RANGE_RESOLVE_SCOPES = ("interval_overlap", "range_witness_before_mask",
                         "covered_buckets", "bucket_overlap",
                         "key_witness_before_mask", "pack_bits")
 RANGE_FINALIZE_SCOPES = ("interval_stab", "bound", "witness_before_mask",
-                         "segment_prefix", "row_scatter", "ts_gather",
+                         "pack_bits", *COMPACT_SCOPES, "ts_gather",
                          "checksum")
 
 
@@ -381,7 +403,9 @@ def test_lowered_text_carries_the_scope_names(program):
     else:
         if program == "finalize_csr":
             f_args = (resolve(*r_args),) + f_args
-        lowered, scopes = finalize.lower(*f_args, out_cap=256), f_scopes
+        # an out-cap under the 32 (24) words of the slot matrix, so that the
+        # compaction folds once and its word stages are in the program
+        lowered, scopes = finalize.lower(*f_args, out_cap=16), f_scopes
     text = lowered.as_text(debug_info=True)
     missing = [s for s in scopes if f"/{s}/" not in text]
     assert not missing, f"{program} lowered without scopes {missing}"
