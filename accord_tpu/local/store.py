@@ -20,6 +20,7 @@ from typing import Callable, Dict, Optional, TYPE_CHECKING
 from accord_tpu.local.cfk import CfkStatus, CommandsForKey
 from accord_tpu.local.command import Command
 from accord_tpu.local.status import Status
+from accord_tpu.obs.trace import phase
 from accord_tpu.primitives.deps import Deps, KeyDepsBuilder, RangeDepsBuilder
 from accord_tpu.primitives.keyspace import Key, Keys, Range, Ranges, Seekables
 from accord_tpu.primitives.timestamp import Timestamp, TxnId, TxnKind
@@ -426,7 +427,7 @@ class CommandStore:
         TRUNCATED. The floor is an ExclusiveSyncPoint id, and the LATEST sync
         point is never below its own floor, so it survives to carry the
         transitive ordering edge for laggards."""
-        from accord_tpu.utils.range_map import merge as _merge, min_intersection
+        from accord_tpu.utils.range_map import min_intersection
         # the two tiers are independent: a replica that missed the one-shot
         # SetShardDurable broadcast (empty majority floor) must still erase
         # when the universal floor reaches it
@@ -434,7 +435,22 @@ class CommandStore:
         erase_floor = min_intersection(self.durable_universal, self.redundant_before)
         if shrink_floor.is_empty() and erase_floor.is_empty():
             return
+        # the walk, on the profiler's clock and in the resolver's registry
+        # (store.cleanup_s; store.cleanup_scanned counts the commands and the
+        # cfk keys it visited); a store with no resolver times nothing
+        registry = getattr(self.deps_resolver, "metrics", None)
+        with phase(registry, "store.cleanup",
+                   "store.cleanup_s" if registry is not None else None):
+            scanned = self._truncate_below(shrink_floor, erase_floor)
+        if registry is not None:
+            registry.counter("store.cleanup_scanned").inc(scanned)
+
+    def _truncate_below(self, shrink_floor, erase_floor) -> int:
+        """cleanup()'s walk over every command and, where the shrink floor is
+        set, every cfk key. Returns how many of both it visited."""
+        from accord_tpu.utils.range_map import merge as _merge
         from accord_tpu.local.status import Status as _S
+        scanned = len(self.commands)
         erased = []
         for txn_id, cmd in self.commands.items():
             if not (cmd.has_been(_S.APPLIED) or cmd.is_(_S.INVALIDATED)):
@@ -461,7 +477,9 @@ class CommandStore:
             # lingering waiters) -- the injected floor dep subsumes their
             # ordering for every future scan. Bounds per-key set sizes
             # between truncation rounds.
-            for key in list(self.cfks):
+            keys = list(self.cfks)
+            scanned += len(keys)
+            for key in keys:
                 floor = shrink_floor.get(key)
                 if floor is None:
                     continue
@@ -481,6 +499,7 @@ class CommandStore:
                                            Timestamp.merge_max)
             if self.truncated_before != prev:
                 self.reevaluate_waiters()
+        return scanned
 
     def _shrink(self, cmd) -> None:
         # deps are RETAINED: a straggler repairing its copy from our

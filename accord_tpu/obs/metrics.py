@@ -3,7 +3,7 @@
 One registry instance lives wherever counters used to be scattered as
 plain attributes (BatchDepsResolver, ExecPlane, Node, the maelstrom
 runner). Existing attribute reads and writes (`resolver.dispatches += 1`,
-`resolver.host_hidden_s`) keep working through the `RegCounter` /
+`resolver.decode_s`) keep working through the `RegCounter` /
 `RegTimer` descriptors, which proxy class attributes onto the owning
 object's `metrics` registry -- so every legacy call site compiles into a
 registry update and `registry.snapshot()` is the single source for bench
@@ -141,7 +141,8 @@ class Histogram:
 class MetricsRegistry:
     """Named metric cells, created on first touch; kind mismatches raise."""
 
-    __slots__ = ("_metrics",)
+    # weakly referable: obs.trace.watch_collector holds registries so
+    __slots__ = ("_metrics", "__weakref__")
 
     def __init__(self):
         self._metrics: Dict[str, object] = {}
@@ -320,8 +321,10 @@ GLOSSARY: Dict[str, str] = {
     "resolver.starved_stage_s": "device starved (work pending, no call in flight) on the tick path: preaccept, encode, launch",
     "resolver.starved_decode_s": "device starved inside a harvest's decode",
     "resolver.starved_outside_s": "device starved outside every resolver phase (enqueue loop, batch-window timer, event queue)",
+    "resolver.drained_stage_s": "device drained (work pending, every call in flight finished on the device, its results not on the host) on the tick path",
+    "resolver.drained_decode_s": "device drained inside a harvest",
+    "resolver.drained_outside_s": "device drained outside every resolver phase (the caller's loop, a store's wave, a node's reduce, the event queue)",
     "resolver.materialize_s": "decode minus in-decode readback",
-    "resolver.host_hidden_s": "host phase seconds run while a call was in flight",
     "resolver.staged_dispatches": "launches taken off the encode-ahead list",
     "resolver.prefetched": "harvests whose transfer the readiness poll drained",
     "resolver.polls_armed": "readiness polls armed (device_poll_ms)",
@@ -358,6 +361,12 @@ GLOSSARY: Dict[str, str] = {
     "resolver.range_intervals": "interval pieces of range-domain subjects encoded",
     "resolver.range_deps": "range-vs-range dependencies delivered from the device stab, one per (intersection, txn)",
     "resolver.shard_merge_s": "sharded finalize launch + fragment-merge wall seconds",
+    # -- in the store's resolver registry, and the collector's hook ----------
+    "store.cleanup_s": "time in CommandStore.cleanup()'s walk (the truncations and fences inside it included)",
+    "store.cleanup_scanned": "commands and cfk keys those walks visited",
+    "gc.pause_s": "wall seconds the garbage collector held the process while the resolver had work pending, every generation",
+    "gc.collections": "garbage collections run while it had work pending, every generation",
+    "gc.full_collections": "collections of the oldest generation",
     # -- resolver device-plane fault handling (ops/fault_plane.py) -----------
     "resolver.device_faults_injected": "injected device faults consumed by the pipeline",
     "resolver.device_retries": "bounded dispatch retries + watchdog probes spent",
@@ -368,7 +377,6 @@ GLOSSARY: Dict[str, str] = {
     "resolver.quarantine_exits": "probation ladders completed back to HEALTHY",
     "resolver.device_canaries": "probation canary dispatches double-decoded",
     # -- resolver computed gauges (folded into resolver.snapshot()) ----------
-    "resolver.host_hidden_pct": "share of host phase time hidden in the device window",
     "resolver.pending": "subjects accepted and not yet answered",
     "resolver.upload_bytes": "bytes shipped host->device by arena scatters",
     "resolver.upload_bytes_full_equiv": "bytes the whole-row scheme would have shipped",

@@ -26,13 +26,16 @@ artifact (guard unit-tested in tests/test_obs.py).
 
 Host pipeline stages are entered through `phase`, which also puts the span
 on the jax profiler's clock and feeds an owner's `Occupancy` account (both
-below; unit-tested in tests/test_obs_phases.py).
+below; unit-tested in tests/test_obs_phases.py). `watch_collector` puts the
+garbage collector's pauses into the registries that ask for them.
 """
 from __future__ import annotations
 
 import contextlib
+import gc
 import sys
 import time
+import weakref
 from collections import deque
 from typing import Callable, List, Optional
 
@@ -222,46 +225,74 @@ def node_ts(node) -> int:
 
 
 class Occupancy:
-    """When the device had nothing because the host had given it nothing.
+    """Where the device's idle time went, by what the host was doing.
 
     Two counts on the host clock: work accepted and not yet answered
     (`pending`) and device calls launched and not yet fetched (`inflight`).
-    Time with work pending and nothing in flight is STARVED time, and each
-    starved interval is credited, whole, to what the host was doing in it:
+    While work is pending the time falls in one of three states:
+
+      running  -- a call is in flight and the device has not finished the
+                  newest one;
+      STARVED  -- no call is in flight: the host has given the device
+                  nothing;
+      DRAINED  -- calls are in flight and the device has finished all of
+                  them: their results are not on the host yet.
+
+    Starved and drained time is credited to what the host was doing in it:
     `phases` maps a phase name (as `phase` reports it) to a bucket, a
     nested phase that is not in the map stays in its parent's bucket, and
     time outside every phase is bucket "outside". One registry timer
-    `<prefix>.starved_<bucket>_s` per bucket; together they partition
-    starved time exactly. Time with nothing pending is in none of them. An
-    interval is credited when it closes or the host changes phase.
+    `<prefix>.starved_<bucket>_s` and one `<prefix>.drained_<bucket>_s` per
+    bucket; while work is pending the two families together are the
+    device's idle time on the host clock. Time with nothing pending is in
+    none of them. An interval is credited when it closes: at every phase
+    boundary and every launch, and when the first item is accepted, the
+    last call lands or the last answer is delivered.
 
-    The clock is read at phase boundaries and at the four transitions
-    (first item accepted, first call launched, last call landed, last
-    answer delivered), never per item.
-    """
+    The device's side comes from the caller: `launched(stamp)` hands over
+    an object whose `done_at` is None until someone (the resolver's
+    completion waiter, or the call's landing) writes the clock
+    reading at which the call's outputs were ready. Calls finish in launch
+    order, so the open interval is split at the newest call's `done_at`:
+    what lies after it is drained. The clock is never read per item."""
 
-    __slots__ = ("pending", "inflight", "_clock", "_phases", "_stack",
-                 "_since")
+    __slots__ = ("pending", "inflight", "_clock", "_phases", "_starved",
+                 "_drained", "_stack", "_since", "_busy", "_newest")
 
     def __init__(self, registry, prefix: str, phases: dict,
                  clock: Callable[[], float] = time.perf_counter):
         self.pending = 0
         self.inflight = 0
         self._clock = clock
-        timers = {b: registry.timer(f"{prefix}.starved_{b}_s")
-                  for b in ("outside", *phases.values())}
-        self._phases = {name: timers[b] for name, b in phases.items()}
-        self._stack = [timers["outside"]]  # the bucket of each open phase
-        # start of the open starved interval, None while not starved
+        buckets = ("outside", *phases.values())
+        self._starved = {b: registry.timer(f"{prefix}.starved_{b}_s")
+                         for b in buckets}
+        self._drained = {b: registry.timer(f"{prefix}.drained_{b}_s")
+                         for b in buckets}
+        self._phases = dict(phases)
+        self._stack = ["outside"]  # the bucket of each open phase
+        # start of the open interval with work pending (None: nothing
+        # pending), and whether a call was in flight through it
         self._since: Optional[float] = None
+        self._busy = False
+        # the newest launched call's stamp holder
+        self._newest = None
 
     def _turn(self) -> None:
-        """Close the open starved interval into the current bucket; open
-        the next one if the state now reached is starved."""
+        """Close the open interval into the current bucket: all of it if
+        nothing was in flight, what followed the newest call's completion
+        if something was; open the next one if work is still pending."""
         now = self._clock()
-        if self._since is not None:
-            self._stack[-1].add(now - self._since)
-        self._since = now if self.pending and not self.inflight else None
+        since = self._since
+        if since is not None:
+            if not self._busy:
+                self._starved[self._stack[-1]].add(now - since)
+            else:
+                done = getattr(self._newest, "done_at", None)
+                if done is not None and done < now:
+                    self._drained[self._stack[-1]].add(now - max(done, since))
+        self._since = now if self.pending else None
+        self._busy = bool(self.inflight)
 
     def accept(self) -> None:
         self.pending += 1
@@ -273,10 +304,12 @@ class Occupancy:
         if not self.pending:
             self._turn()
 
-    def launched(self) -> None:
+    def launched(self, stamp=None) -> None:
+        """A call left for the device; `stamp.done_at` says when it
+        finished (None: not known yet, or never where no stamp is given)."""
         self.inflight += 1
-        if self.inflight == 1:
-            self._turn()
+        self._turn()
+        self._newest = stamp
 
     def landed(self) -> None:
         self.inflight -= 1
@@ -290,6 +323,63 @@ class Occupancy:
     def exit(self) -> None:
         self._turn()
         self._stack.pop()
+
+
+class _CollectorWatch:
+    """The process's one `gc.callbacks` hook: every collection's pause is
+    added to `gc.pause_s` and counted in `gc.collections` (and, for the
+    oldest generation, `gc.full_collections`) of each registry that asked,
+    held weakly -- of a registry that came with an Occupancy account, only
+    the collections that ran while the account had work pending, so what a
+    caller does between its requests is not its owner's. A full collection
+    also opens a `gc.collect` span on the profiler's clock (arg
+    `generation`); the young generations run hundreds of times a second
+    and get none."""
+
+    def __init__(self):
+        self._cells = weakref.WeakKeyDictionary()
+        self._t0 = 0.0
+        self._span = None
+        gc.callbacks.append(self._on_collection)
+
+    def add(self, registry, account: Optional[Occupancy]) -> None:
+        self._cells[registry] = (registry.timer("gc.pause_s"),
+                                 registry.counter("gc.collections"),
+                                 registry.counter("gc.full_collections"),
+                                 account)
+
+    def _on_collection(self, phase_: str, info: dict) -> None:
+        full = info["generation"] == 2
+        if phase_ == "start":
+            if full and "jax" in sys.modules:
+                from jax.profiler import TraceAnnotation
+                self._span = TraceAnnotation("gc.collect", generation=2)
+                self._span.__enter__()
+            self._t0 = time.perf_counter()
+            return
+        dt = time.perf_counter() - self._t0
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+        for pause, runs, fulls, account in list(self._cells.values()):
+            if account is not None and not account.pending:
+                continue
+            pause.total += dt
+            runs.value += 1
+            fulls.value += full
+
+
+_collector_watch: Optional[_CollectorWatch] = None
+
+
+def watch_collector(registry, account: Optional[Occupancy] = None) -> None:
+    """Time the garbage collector into `registry` from now on, where
+    `account` is given only while it has work pending (the hook is
+    installed by the first caller and stays for the process)."""
+    global _collector_watch
+    if _collector_watch is None:
+        _collector_watch = _CollectorWatch()
+    _collector_watch.add(registry, account)
 
 
 class phase:

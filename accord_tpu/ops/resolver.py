@@ -60,6 +60,8 @@ from __future__ import annotations
 
 import functools
 import logging
+import threading
+import time
 from collections import deque
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -67,7 +69,8 @@ import numpy as np
 
 from accord_tpu.local.cfk import CfkStatus
 from accord_tpu.obs.metrics import MetricsRegistry, RegCounter, RegTimer
-from accord_tpu.obs.trace import REC, Occupancy, node_pid, node_ts, phase
+from accord_tpu.obs.trace import (REC, Occupancy, node_pid, node_ts, phase,
+                                  watch_collector)
 from accord_tpu.ops.encoding import (TimestampEncoder, WITNESS_TABLE,
                                      encode_interval,
                                      encode_key_point_intervals,
@@ -1758,6 +1761,80 @@ def _dev_copy_async(dev) -> None:
         dev.copy_to_host_async()
 
 
+class _Done:
+    """When a call's outputs were ready on the device, on the host clock
+    (None until learned): all the occupancy account and the completion
+    waiter keep of a call, so neither holds its results or its answers."""
+
+    __slots__ = ("done_at",)
+
+    def __init__(self):
+        self.done_at: Optional[float] = None
+
+
+class _CompletionWaiter:
+    """When each launched call finished on the device, on the host clock:
+    one thread, alive only while calls wait, probes each call's outputs in
+    launch order (`is_ready()`, between sleeps of PROBE_S with the GIL
+    released) and stamps its `done_at`, late by at most PROBE_S plus the
+    interpreter's switch interval. It does not block in
+    `jax.block_until_ready`: on the chip that wait burns host CPU for as
+    long as the device runs (PERF.md, PR 37). It touches nothing else: the
+    stamps feed only the occupancy account. A call whose buffer is gone
+    counts as done when the thread learns it; a call the host has landed
+    (fetched, or given up on) is stamped at its landing, and the thread
+    asks nothing more of its buffers, so a wedged call never holds up the
+    harvest's `join`."""
+
+    PROBE_S = 0.001
+
+    def __init__(self):
+        self._queue: deque = deque()
+        self._lock = threading.Lock()
+        self._thread: Optional[threading.Thread] = None
+
+    def add(self, call: "_Call") -> None:
+        devs = [b for _, _, dev in call.buffers()
+                for b in (dev if isinstance(dev, tuple) else (dev,))]
+        with self._lock:
+            self._queue.append((call.done, devs))
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._run, name="resolver-completion", daemon=True)
+                self._thread.start()
+
+    def _run(self) -> None:
+        while True:
+            with self._lock:
+                if not self._queue:
+                    self._thread = None
+                    return
+                done, devs = self._queue[0]
+            # is_ready() on a deleted buffer is not safe to ask
+            if done.done_at is None and not all(
+                    b.is_deleted() or b.is_ready() for b in devs):
+                time.sleep(self.PROBE_S)
+                continue
+            self.stamp(done, time.perf_counter())
+            with self._lock:
+                self._queue.popleft()
+            done = devs = None  # hold nothing the harvest has let go
+
+    def stamp(self, done: _Done, at: float) -> None:
+        """Keep the earliest clock reading known to follow the call's
+        completion (the thread and the landing both write it)."""
+        with self._lock:
+            if done.done_at is None or at < done.done_at:
+                done.done_at = at
+
+    def join(self) -> None:
+        """Wait for the thread to stamp what it holds and end."""
+        with self._lock:
+            thread = self._thread
+        if thread is not None:
+            thread.join()
+
+
 class _Call:
     """One in-flight kernel dispatch: up to three device result buffers
     (key-domain deps, range-arena candidates, key-arena candidates for range
@@ -1772,7 +1849,7 @@ class _Call:
     __slots__ = ("packed", "rpacked", "kpacked", "items", "groups",
                  "np_packed", "np_rpacked", "np_kpacked", "want", "did",
                  "stuck_left", "corrupt_pending", "overflow_pending",
-                 "degraded", "faulted", "canary", "landed")
+                 "degraded", "faulted", "canary", "landed", "done")
 
     def __init__(self, packed, rpacked, kpacked, items, groups,
                  want=(True, True, True), did=-1):
@@ -1802,8 +1879,11 @@ class _Call:
         self.faulted = False
         self.canary = False
         # occupancy account: counted in flight from launch until its
-        # results are on the host (BatchDepsResolver._land)
+        # results are on the host (BatchDepsResolver._land); `done` says
+        # when its outputs were ready on the device, once _CompletionWaiter
+        # learns it, or at the latest when the call lands
         self.landed = False
+        self.done = _Done()
 
     def buffers(self):
         """(holder, host attr, device value) triples the async-copy / poll /
@@ -1914,8 +1994,6 @@ class BatchDepsResolver(DepsResolver):
     transfer_s = RegTimer("resolver.transfer_s")
     readback_bytes = RegCounter("resolver.readback_bytes")
     materialize_s = RegTimer("resolver.materialize_s")  # decode minus readback
-    host_hidden_s = RegTimer("resolver.host_hidden_s")  # host time overlapped
-    #                                                     with an in-flight call
     staged_dispatches = RegCounter("resolver.staged_dispatches")
     prefetched = RegCounter("resolver.prefetched")   # poll-drained transfers
     polls_armed = RegCounter("resolver.polls_armed")
@@ -2022,11 +2100,14 @@ class BatchDepsResolver(DepsResolver):
         # counter touch
         self.metrics = MetricsRegistry()
         # the occupancy account (obs/trace.py): time with work pending and
-        # no call in flight is the device starved by the host, credited to
-        # resolver.starved_{stage,decode,outside}_s by the phase the host
-        # was in -- the tick path (preaccept, encode, launch), the harvest
-        # (decode), or neither (the caller's enqueue loop, the batch-window
-        # timer, the event queue). host_hidden_s counts the opposite case
+        # no call in flight is the device starved by the host, time with
+        # every call in flight finished on the device (the waiter's stamps)
+        # and not yet fetched is the device drained; each is credited to
+        # resolver.{starved,drained}_{stage,decode,outside}_s by the phase
+        # the host was in -- the tick path (preaccept, encode, launch), the
+        # harvest (decode), or neither (the caller's enqueue loop, the
+        # batch-window timer, the event queue, a store's wave)
+        self._waiter = _CompletionWaiter()
         self._occ = Occupancy(self.metrics, "resolver", {
             "resolver.tick": "stage", "resolver.preaccept": "stage",
             "resolver.encode": "stage", "resolver.launch": "stage",
@@ -2035,6 +2116,9 @@ class BatchDepsResolver(DepsResolver):
         # registry timer, flight-recorder span, profiler annotation, account
         self._phase = functools.partial(phase, self.metrics,
                                         account=self._occ)
+        # the collector's pauses while work is pending, into this registry
+        # (obs/trace.py)
+        watch_collector(self.metrics, self._occ)
         # the range kernel's covered-bucket contraction reduces intervals
         # modulo the bucket count with int32 arithmetic; that wrap is exact
         # only when num_buckets divides 2^32
@@ -2124,15 +2208,6 @@ class BatchDepsResolver(DepsResolver):
         return next(iter(self._table.devices()))
 
     @property
-    def host_hidden_pct(self) -> float:
-        """Share of total host-phase wall time (preaccept + encode + launch
-        + decode) that ran while a device call was already in flight -- the
-        fraction the staged pipeline hid inside the device window."""
-        total = (self.preaccept_s + self.encode_s + self.dispatch_s
-                 + self.decode_s)
-        return 100.0 * self.host_hidden_s / total if total > 0.0 else 0.0
-
-    @property
     def upload_bytes(self) -> int:
         """Total bytes shipped host->device by arena dirty-row scatters."""
         return sum(a.upload_bytes + a.ranges.upload_bytes
@@ -2164,7 +2239,6 @@ class BatchDepsResolver(DepsResolver):
         """Flat registry snapshot plus the arena-computed gauges -- the
         single source for bench JSON and metrics dumps."""
         snap = self.metrics.snapshot()
-        snap["resolver.host_hidden_pct"] = round(self.host_hidden_pct, 3)
         snap["resolver.pending"] = self._occ.pending
         snap["resolver.upload_bytes"] = self.upload_bytes
         snap["resolver.upload_bytes_full_equiv"] = self.upload_bytes_full_equiv
@@ -2496,12 +2570,8 @@ class BatchDepsResolver(DepsResolver):
             items = self._drain_and_preaccept(node)
             self._adapt(node, len(items))
             plans = [self._stage(node, sub) for sub in self._slices(items)]
-            hidden = bool(self._inflight.get(id(node)))
-            ph.args = {"hidden": hidden, "items": len(items)}
-        if hidden:
-            # the span's dur is this exact contribution, so a trace-side
-            # hidden-share computation reconciles with host_hidden_pct
-            self.host_hidden_s += ph.dt
+            ph.args = {"hidden": bool(self._inflight.get(id(node))),
+                       "items": len(items)}
         if plans:
             self._staged[id(node)] = plans
             self._arm_tick(node)
@@ -4242,7 +4312,8 @@ class BatchDepsResolver(DepsResolver):
                     for _, _, dev in call.buffers():
                         _dev_copy_async(dev)
                 if call.has_device:
-                    self._occ.launched()
+                    self._waiter.add(call)
+                    self._occ.launched(call.done)
                 if plan.fused:
                     self.fused_dispatches += 1
                     self.store_groups += len(plan.groups)
@@ -4365,7 +4436,10 @@ class BatchDepsResolver(DepsResolver):
         was given up on."""
         if call.has_device and not call.landed:
             call.landed = True
+            self._waiter.stamp(call.done, time.perf_counter())
             self._occ.landed()
+            if not self._occ.inflight:
+                self._waiter.join()
 
     def _collect(self, node, call: _Call, hidden: bool) -> List[Deps]:
         """The harvest's work on one call: wait for the device and fetch
@@ -4436,8 +4510,6 @@ class BatchDepsResolver(DepsResolver):
         # lazy fallback fetches inside the decode were timed into readback_s;
         # what's left is pure host materialization
         self.materialize_s += ph.dt - (self.readback_s - rb0)
-        if hidden:
-            self.host_hidden_s += ph.dt
         health = self._health.get(id(node))
         if health is not None and call.has_device and not call.degraded \
                 and not call.faulted:

@@ -30,7 +30,17 @@ LIVE_METRICS = ("preaccept_us_per_subject.batch",
                 "fence_us_per_subject.batch",
                 "compact_ms_per_compaction.batch",
                 "arena_sync_device_us_per_dispatch.batch",
-                "arena_donated_share.batch")
+                "arena_donated_share.batch",
+                "cleanup_us_per_txn.batch",
+                "cleanup_scanned_per_txn.batch")
+CLEANUP_METRICS = LIVE_METRICS[-2:]
+# the device's idle time the account found with calls in flight, and the
+# collector's pauses (PR 37): every cell, and nothing forces either above 0
+# in a rehearsal
+IDLE_METRICS = ("drained_stage_us_per_subject.batch",
+                "drained_decode_us_per_subject.batch",
+                "drained_outside_us_per_subject.batch",
+                "gc_pause_us_per_subject.batch")
 NODE_METRICS = ("store_slices_per_txn.batch",
                 "fanout_us_per_txn.batch",
                 "reduce_us_per_txn.batch",
@@ -82,18 +92,18 @@ def test_the_cell_lists_its_metrics():
                  "starved_decode_us_per_subject.batch",
                  "starved_outside_us_per_subject.batch"):
         assert name in names
-    # the range cell reports the sibling's metrics and its own five
-    assert [m["name"] for m in listed(RANGE_CELL)] == \
-        [n for n in names if n not in RANGE_METRICS] + list(RANGE_METRICS)
-    assert not set(names) & set(RANGE_METRICS)
-    # and the live cell the sibling's and its own eight (PR 33, PR 34)
-    assert [m["name"] for m in listed(LIVE_CELL)] == \
-        [n for n in names if n not in RANGE_METRICS] + list(LIVE_METRICS)
-    assert not set(names) & set(LIVE_METRICS)
-    # and the node cell the sibling's and its own six (PR 35)
-    assert [m["name"] for m in listed(NODE_CELL)] == \
-        [n for n in names if n not in RANGE_METRICS] + list(NODE_METRICS)
-    assert not set(names) & set(NODE_METRICS)
+    assert all(name in names for name in IDLE_METRICS)
+
+    def own(cell, metrics):
+        got = [m["name"] for m in listed(cell)]
+        assert [n for n in got if n not in metrics] == names
+        assert [n for n in got if n in metrics] == list(metrics)
+        assert not set(names) & set(metrics)
+    # the range cell reports the sibling's metrics and its own five; the
+    # live cell its own ten (PR 33, PR 34, PR 37); the node cell its six
+    own(RANGE_CELL, RANGE_METRICS)
+    own(LIVE_CELL, LIVE_METRICS)
+    own(NODE_CELL, NODE_METRICS)
 
 
 @pytest.mark.parametrize("cell,entry", LISTED)
@@ -122,7 +132,8 @@ def test_metric_reads_the_programs_counters(cell, entry, runs):
         f"{entry['name']}: a counter of {spec['num'] + spec['den']} is " \
         f"missing from the program's snapshot"
     assert math.isfinite(value) and value >= 0.0
-    if "starved_outside" not in entry["name"]:
+    if "starved_outside" not in entry["name"] \
+            and entry["name"] not in IDLE_METRICS:
         assert value > 0.0
 
 
@@ -149,6 +160,33 @@ def test_live_metrics_read_nothing_on_the_static_cells(runs):
                 assert 0.0 <= value < 1.0, (cell, value)
             else:
                 assert not value, (cell, name, value)
+
+
+def test_cleanup_metrics_read_nothing_on_the_static_cells(runs):
+    """The three cells that never run a wave open no `store.cleanup` span:
+    its two metrics evaluate to nothing (as on a parent without the span);
+    on the live cell the walk visits every resident command, twice a wave,
+    for each txn it truncates."""
+    def read(name, cell):
+        return common.evaluate_ratio(common.load_json(
+            common.HERE / "layer_metrics" / f"{name}.json"), runs[cell])
+    for cell in (CELL, RANGE_CELL, NODE_CELL):
+        for name in CLEANUP_METRICS:
+            assert read(name, cell) is None, (cell, name)
+            assert "store.cleanup_s" not in runs[cell]
+    assert read("cleanup_us_per_txn.batch", LIVE_CELL) > 0.0
+    assert read("cleanup_scanned_per_txn.batch", LIVE_CELL) >= 2.0
+
+
+def test_idle_metrics_read_a_number_in_every_cell(runs):
+    """The account's drained timers and the collector's pause are in every
+    resolver's registry from its construction: each cell reads a number
+    (0 where nothing of the kind happened), never nothing."""
+    for cell in CELLS:
+        for name in IDLE_METRICS:
+            value = common.evaluate_ratio(common.load_json(
+                common.HERE / "layer_metrics" / f"{name}.json"), runs[cell])
+            assert value is not None and value >= 0.0, (cell, name)
 
 
 def test_node_metrics_read_nothing_on_a_one_store_cell(runs):

@@ -119,7 +119,9 @@ def test_resolver_snapshot_is_registry_backed(recorded):
         # registry cells the snapshot serializes
         assert snap["resolver.dispatches"] == r.dispatches
         assert snap["resolver.subjects"] == r.subjects
-        assert snap["resolver.host_hidden_s"] == r.host_hidden_s
+        for b in ("stage", "decode", "outside"):
+            name = f"resolver.drained_{b}_s"
+            assert snap[name] == r.metrics.timer(name).total >= 0.0
         assert snap["resolver.upload_bytes"] == r.upload_bytes
         # and nothing escapes the documented vocabulary
         unknown = set(snap) - set(GLOSSARY)

@@ -32,7 +32,9 @@ from __future__ import annotations
 import contextlib
 import subprocess
 import sys
+import threading
 import time
+from collections import deque
 
 import numpy as np
 import pytest
@@ -169,6 +171,198 @@ def test_occupancy_partitions_starved_time():
     assert min(starved().values()) > 0.0
 
 
+class Stamp:
+    """A call's stamp holder: `done_at` as the completion waiter writes it."""
+
+    def __init__(self):
+        self.done_at = None
+
+
+def _drained(reg):
+    return {b: reg.timer(f"p.drained_{b}_s").total
+            for b in ("stage", "decode", "outside")}
+
+
+def test_occupancy_drained_scripted_pipeline():
+    """Two calls in flight; the device finishes them while the host is in
+    a phase, between phases, and before it asks."""
+    reg, clock = MetricsRegistry(), Script()
+    occ = Occupancy(reg, "p", PHASES, clock=clock)
+    starved = {b: reg.timer(f"p.starved_{b}_s") for b in
+               ("stage", "decode", "outside")}
+    one, two = Stamp(), Stamp()
+    occ.accept()
+    occ.enter("p.launch")
+    clock.advance(0.25)             # starved: nothing in flight yet
+    occ.exit()
+    occ.launched(one)
+    clock.advance(1.0)              # running
+    one.done_at = clock.now
+    clock.advance(0.5)              # drained, outside every phase
+    occ.enter("p.launch")
+    clock.advance(0.125)            # drained, on the tick path
+    occ.exit()
+    occ.launched(two)               # the newest is running again
+    occ.enter("p.harvest")
+    clock.advance(2.0)              # running: two is not done
+    two.done_at = clock.now - 0.5   # done half a second ago: a late stamp
+    occ.enter("p.materialize")      # splits at two's done_at
+    clock.advance(0.75)             # drained, in the harvest's bucket
+    occ.landed()
+    occ.exit()
+    occ.exit()
+    occ.landed()                    # nothing in flight: starved from here
+    clock.advance(0.0625)
+    occ.deliver()
+    assert _drained(reg) == {"stage": 0.125, "decode": 1.25,
+                             "outside": 0.5}
+    assert {b: t.total for b, t in starved.items()} == {
+        "stage": 0.25, "decode": 0.0, "outside": 0.0625}
+
+
+def test_occupancy_three_states_partition_pending_time():
+    """Whatever the interleaving, with the device finishing calls in launch
+    order at moments the host does not see: running, starved and drained
+    add up to the time with work pending, each bucket gets what a model of
+    the rule gives it, and calls launched with no stamp are never
+    drained."""
+    reg, clock = MetricsRegistry(), Script()
+    occ = Occupancy(reg, "p", PHASES, clock=clock)
+    rng = np.random.default_rng(9)
+    names = ("p.stage", "p.harvest", "p.other")
+    buckets = ("stage", "decode", "outside")
+    want_starved = dict.fromkeys(buckets, 0.0)
+    want_drained = dict.fromkeys(buckets, 0.0)
+    pending_time = running = 0.0
+    stack, flight, newest = ["outside"], deque(), None
+    for _ in range(4000):
+        dt = float(rng.integers(1, 64)) / 64.0  # exact in binary
+        if occ.pending:
+            pending_time += dt
+            if not flight:
+                want_starved[stack[-1]] += dt
+            elif newest.done_at is not None:
+                want_drained[stack[-1]] += dt
+            else:
+                running += dt
+        clock.advance(dt)
+        op = int(rng.integers(0, 8))
+        if op == 0 and occ.pending < 3:
+            occ.accept()
+        elif op == 1 and occ.pending:
+            occ.deliver()
+        elif op == 2 and len(flight) < 3:
+            newest = Stamp()
+            flight.append(newest)
+            occ.launched(newest)
+        elif op == 3 and flight:
+            call = flight.popleft()  # landing implies done: stamp it now
+            if call.done_at is None:
+                call.done_at = clock.now
+            occ.landed()
+        elif op in (4, 5) and flight:
+            # the device finishes the oldest call not yet done
+            call = next((c for c in flight if c.done_at is None), None)
+            if call is not None:
+                call.done_at = clock.now
+        elif op == 6 and len(stack) < 4:
+            name = names[len(stack) - 1]
+            stack.append(PHASES.get(name, stack[-1]))
+            occ.enter(name)
+        elif op == 7 and len(stack) > 1:
+            stack.pop()
+            occ.exit()
+    occ.deliver(occ.pending)  # an open interval is credited as it closes
+    starved = {b: reg.timer(f"p.starved_{b}_s").total for b in buckets}
+    drained = _drained(reg)
+    for b in buckets:
+        assert starved[b] == pytest.approx(want_starved[b], rel=1e-12)
+        assert drained[b] == pytest.approx(want_drained[b], rel=1e-12)
+        assert drained[b] > 0.0
+    assert sum(starved.values()) + sum(drained.values()) + running == \
+        pytest.approx(pending_time, rel=1e-12)
+
+
+def test_occupancy_unstamped_calls_are_never_drained():
+    occ, clock, starved = _account()
+    occ.accept()
+    occ.launched()
+    clock.advance(2.0)
+    occ.enter("p.harvest")
+    clock.advance(1.0)
+    occ.landed()
+    occ.exit()
+    occ.deliver()
+    assert sum(starved().values()) == 0.0
+    assert occ._drained["decode"].total == occ._drained["outside"].total \
+        == 0.0
+
+
+# -- the collector's hook ------------------------------------------------------
+
+def test_a_full_collection_is_timed_into_every_registered_registry(tmp_path):
+    import gc
+
+    import jax
+    from accord_tpu.obs.trace import watch_collector
+    regs = [MetricsRegistry(), MetricsRegistry()]
+    for reg in regs:
+        watch_collector(reg)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        gc.collect(2)
+    finally:
+        jax.profiler.stop_trace()
+    for reg in regs:
+        assert reg.timer("gc.pause_s").total > 0.0
+        assert reg.counter("gc.full_collections").value >= 1
+        assert reg.counter("gc.collections").value >= \
+            reg.counter("gc.full_collections").value
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    found = [dict(e.stats) for plane in data.planes if
+             plane.name.startswith("/host:") for line in plane.lines
+             for e in line.events if e.name == "gc.collect"]
+    assert found and all(st["generation"] == 2 for st in found)
+
+
+def test_young_collections_count_and_open_no_span():
+    import gc
+    from accord_tpu.obs.trace import watch_collector
+    reg = MetricsRegistry()
+    watch_collector(reg)
+    gc.collect(0)
+    assert reg.counter("gc.collections").value >= 1
+    assert reg.counter("gc.full_collections").value == 0
+    # the registry is held weakly: one dropped is let go
+    import weakref
+    gone = MetricsRegistry()
+    watch_collector(gone)
+    ref = weakref.ref(gone)
+    del gone
+    gc.collect(0)
+    assert ref() is None
+
+
+def test_collections_count_only_while_the_account_has_work_pending():
+    import gc
+    from accord_tpu.obs.trace import watch_collector
+    reg = MetricsRegistry()
+    occ = Occupancy(reg, "p", PHASES)
+    watch_collector(reg, occ)
+    gc.collect(0)  # nothing pending: the caller's, not the owner's
+    assert reg.counter("gc.collections").value == 0
+    assert reg.timer("gc.pause_s").total == 0.0
+    occ.accept()
+    gc.collect(2)
+    occ.deliver()
+    assert reg.counter("gc.collections").value == 1
+    assert reg.counter("gc.full_collections").value == 1
+    assert reg.timer("gc.pause_s").total > 0.0
+
+
 # -- the primitive itself ------------------------------------------------------
 
 def test_phase_feeds_timer_recorder_and_account():
@@ -271,6 +465,140 @@ def test_readback_bytes_repeat_for_one_seed(resolved):
     assert d["resolver.readback_bytes"] > 0
     assert again["resolver.readback_bytes"] == d["resolver.readback_bytes"]
     assert again["resolver.dispatches"] == d["resolver.dispatches"]
+
+
+def test_idle_timers_fit_the_wall_time(resolved):
+    _, wall, d = resolved
+    drained = [d[f"resolver.drained_{b}_s"]
+               for b in ("stage", "decode", "outside")]
+    starved = [d[f"resolver.starved_{b}_s"]
+               for b in ("stage", "decode", "outside")]
+    assert all(x >= 0.0 for x in drained)
+    assert 0.0 < sum(starved) + sum(drained) <= wall
+
+
+def test_completion_stamps_come_in_launch_order():
+    """Every call of a round is stamped by the time its harvest ends, in
+    launch order, and no waiter thread outlives the round."""
+    from benchmark.runners.batch import Arena
+    p = _params()
+    arena = Arena(p, 11)
+    r = arena.resolver
+    seen = []
+    collect = r._collect
+
+    def spy(node, call, hidden):
+        out = collect(node, call, hidden)
+        seen.append((call.did, call.done.done_at, time.perf_counter()))
+        return out
+    r._collect = spy
+    for _ in range(2):
+        assert arena.round(p["subjects"])[2:4] == (0, 0)
+    assert [did for did, _, _ in seen] == list(range(r.dispatches))
+    stamps = [at for _, at, _ in seen]
+    assert all(at is not None for at in stamps)
+    assert stamps == sorted(stamps)
+    assert all(at <= end for _, at, end in seen)
+    assert r._occ.inflight == 0 and r._waiter._thread is None
+    assert not [t for t in threading.enumerate()
+                if t.name == "resolver-completion"]
+
+
+def test_completion_waiter_under_a_short_switch_interval():
+    """The host adds calls and lands them while the thread pops and stamps
+    them, the interpreter switching every microsecond: every call is
+    stamped, the earliest stamp kept, in launch order, and the thread
+    ends."""
+    import jax.numpy as jnp
+    from accord_tpu.ops.resolver import _Call, _CompletionWaiter
+    bufs = [jnp.full((4,), i) for i in range(64)]
+    calls = [_Call(bufs[i % 64], None, None, [], []) for i in range(3000)]
+    waiter = _CompletionWaiter()
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        landed = 0
+        for i, call in enumerate(calls):
+            waiter.add(call)
+            if i % 3 == 0:  # the host lands calls in launch order, stamping
+                for c in calls[landed:i - 8]:
+                    waiter.stamp(c.done, time.perf_counter())
+                landed = max(landed, i - 8)
+        thread = waiter._thread
+        if thread is not None:
+            thread.join(timeout=60)
+            assert not thread.is_alive()
+    finally:
+        sys.setswitchinterval(was)
+    stamps = [c.done.done_at for c in calls]
+    assert all(at is not None for at in stamps)
+    assert stamps == sorted(stamps)
+    assert waiter._thread is None and not waiter._queue
+
+
+def test_a_call_whose_buffer_is_gone_counts_as_done():
+    import jax.numpy as jnp
+    from accord_tpu.ops.resolver import _Call, _CompletionWaiter
+    buf = jnp.arange(8) + 1
+    call = _Call(buf, None, None, [], [])
+    buf.delete()
+    waiter = _CompletionWaiter()
+    t0 = time.perf_counter()
+    waiter.add(call)
+    waiter.join()
+    assert call.done.done_at is not None and call.done.done_at >= t0
+    assert waiter._thread is None
+
+
+def test_a_call_given_up_on_does_not_hold_up_the_harvest():
+    """Every call is stuck past the watchdog's budget on a device that
+    stays wedged for a minute: the harvest answers host-side at once, the
+    landing stamps each call, and the waiter lets them go without asking
+    their buffers again."""
+    from accord_tpu.ops import fault_plane
+    from accord_tpu.ops.resolver import _Call
+    from accord_tpu.utils.rng import RandomSource
+    from benchmark.runners.batch import Arena
+
+    class Wedged:
+        probes = 0
+
+        def is_deleted(self):
+            return False
+
+        def is_ready(self):
+            Wedged.probes += 1
+            return time.perf_counter() >= until
+
+    p = _params()
+    arena = Arena(p, 13)
+    r = arena.resolver
+    assert arena.round(p["subjects"])[2:4] == (0, 0)  # compiles
+    until = time.perf_counter() + 60.0
+    wedged, calls, add = Wedged(), [], r._waiter.add
+
+    def add_wedged(call):
+        calls.append(call)
+        saved = _Call.buffers
+        _Call.buffers = lambda self: [(None, None, wedged)]
+        try:
+            add(call)
+        finally:
+            _Call.buffers = saved
+    r._waiter.add = add_wedged
+    r.watchdog_probes = 0  # every stuck call trips the watchdog
+    plane = fault_plane.DeviceFaultPlane(RandomSource(5).fork(),
+                                         stuck_rate=1.0)
+    t0 = time.perf_counter()
+    with fault_plane.scoped(plane):
+        assert arena.round(p["subjects"])[2:4] == (0, 0)
+    assert time.perf_counter() - t0 < 20.0
+    assert calls and r.device_watchdog_trips == len(calls)
+    assert all(c.degraded and t0 < c.done.done_at < until for c in calls)
+    assert r._waiter._thread is None and not r._waiter._queue
+    probes = Wedged.probes
+    time.sleep(0.01)
+    assert Wedged.probes == probes
 
 
 # -- (c) the spans on the profiler's clock ------------------------------------
@@ -525,8 +853,13 @@ def test_recorder_events_are_what_they_were(wall):
     for name, timer in (("preaccept", r.preaccept_s), ("encode", r.encode_s),
                         ("launch", r.dispatch_s), ("decode", r.decode_s)):
         assert by_name[name] == pytest.approx(timer * 1e6, abs=0.01)
-    hidden = sum(e["dur"] for e in spans if e["args"].get("hidden"))
-    assert hidden == pytest.approx(r.host_hidden_s * 1e6, abs=0.01)
+    # a hidden decode ran with a call in flight: what of it the device had
+    # finished is drained time, and the harvest's drained time lies in
+    # those decodes or in the fetch before them
+    hidden = sum(e["dur"] for e in spans
+                 if e["name"] == "decode" and e["args"]["hidden"])
+    drained = r.metrics.timer("resolver.drained_decode_s").total
+    assert 0.0 <= drained * 1e6 <= hidden + (r.readback_s + 0.002) * 1e6
 
 
 # -- (f) the range path's spans and counters -----------------------------------
@@ -686,6 +1019,12 @@ def test_lifecycle_counters_move_only_where_something_happened():
         assert (d.get("resolver.truncate_s", 0.0) > 0.0) == waved
         assert d.get("resolver.fence_s", 0.0) <= \
             d.get("resolver.truncate_s", 0.0)
+        # the wave's walk: only where it truncates, and it holds them
+        assert (d.get("store.cleanup_s", 0.0) > 0.0) == waved
+        assert d.get("resolver.truncate_s", 0.0) <= \
+            d.get("store.cleanup_s", 0.0)
+        if waved:
+            assert d["store.cleanup_scanned"] >= len(live.store.commands)
         # a fill: the arena was full when a row was asked for, so it grew
         # or compacted, and only then
         filled = bool(d.get("resolver.arena_compactions")
@@ -707,6 +1046,7 @@ def test_lifecycle_counters_move_only_where_something_happened():
     d = _query_round(live)
     assert d["resolver.dispatches"] > 0 and d["resolver.subjects"] > 0
     assert [k for k in LIFECYCLE if d.get(k)] == []
+    assert not d.get("store.cleanup_s") and not d.get("store.cleanup_scanned")
 
 
 def test_lifecycle_spans_nest_inside_their_parents(tmp_path):
@@ -731,11 +1071,12 @@ def test_lifecycle_spans_nest_inside_their_parents(tmp_path):
     for plane in data.planes:
         for line in plane.lines:
             for e in line.events:
-                if e.name.startswith("resolver."):
+                if e.name.startswith(("resolver.", "store.")):
                     assert plane.name.startswith("/host:")
                     spans.setdefault(e.name, []).append(
                         (e.start_ns, e.start_ns + e.duration_ns))
     for child, parent in (("resolver.arena_sync", "resolver.encode"),
+                          ("resolver.truncate", "store.cleanup"),
                           ("resolver.compact", "resolver.preaccept"),
                           ("resolver.grow", "resolver.preaccept"),
                           ("resolver.fence", "resolver.truncate"),
