@@ -127,12 +127,13 @@ class DeviceFaultPlane:
         self.injected[kind] += 1
 
     def corrupt_arrays(self, bufs) -> bool:
-        """Flip one bit of one array in `bufs` (host numpy copies of a
-        fetched finalize triple) -- the simulated corrupted readback. The
-        flip lands in the arrays the checksum lane covers (never the
-        trailing bound/csum words), so every injection is detectable.
-        Returns False (and draws nothing) when there is nothing to hit."""
-        targets = [b for b in bufs[:3]
+        """Flip one bit of one array in `bufs` -- the simulated corrupted
+        readback. The caller passes exactly the words a finalize lane's
+        checksum covers (its host copies of indptr and dep_rows up to the
+        total; never the bound/csum words or the zero tail), so every
+        injection is detectable. Returns False (and draws nothing) when
+        there is nothing to hit."""
+        targets = [b for b in bufs
                    if isinstance(b, np.ndarray) and b.size > 0]
         if not targets:
             return False

@@ -664,26 +664,30 @@ def _csum_fold(x, seed: int):
                    dtype=jnp.uint32)
 
 
-def csr_checksum(indptr, dep_rows, dep_ts):
-    """Device-side integrity word over a finalized CSR triple, fused into
+def _csum_fold_host(x, seed: int):
+    """numpy twin of _csum_fold: the same words, bit for bit."""
+    v = np.ascontiguousarray(x).view(np.uint32).reshape(-1)
+    v = v ^ (v >> np.uint32(16))
+    idx = np.arange(v.shape[0], dtype=np.uint32)
+    return (v * (np.uint32(2) * idx + np.uint32(seed))).sum(dtype=np.uint32)
+
+
+def csr_checksum(indptr, dep_rows):
+    """Device-side integrity word over a finalized CSR pair, fused into
     the finalize kernels' returns and re-derived from the host copies at
     harvest (resolver._csum_ok): a readback that arrives bit-flipped can
     never decode into wrong deps -- the mismatch routes the group to the
-    legacy fallback, which re-reads the raw candidate buffers."""
-    return (_csum_fold(indptr, 1) ^ _csum_fold(dep_rows, 5)
-            ^ _csum_fold(dep_ts, 9))
+    legacy fallback, which re-reads the raw candidate buffers. dep_rows is
+    0 past indptr[-1] and a 0 word folds to 0, so the host may fold only
+    dep_rows[:indptr[-1]] and still match this whole-lane word."""
+    return _csum_fold(indptr, 1) ^ _csum_fold(dep_rows, 5)
 
 
-def csr_checksum_host(indptr, dep_rows, dep_ts) -> int:
-    """numpy twin of csr_checksum, computed over the fetched host copies.
+def csr_checksum_host(indptr, dep_rows) -> int:
+    """numpy twin of csr_checksum, computed over the fetched host copies
+    (dep_rows whole, or any prefix that holds its first indptr[-1] words).
     Must track the device fold bit for bit."""
-    def fold(x, seed):
-        v = np.ascontiguousarray(x).view(np.uint32).reshape(-1)
-        v = v ^ (v >> np.uint32(16))
-        idx = np.arange(v.shape[0], dtype=np.uint32)
-        return (v * (np.uint32(2) * idx + np.uint32(seed))).sum(
-            dtype=np.uint32)
-    return int(fold(indptr, 1) ^ fold(dep_rows, 5) ^ fold(dep_ts, 9))
+    return int(_csum_fold_host(indptr, 1) ^ _csum_fold_host(dep_rows, 5))
 
 
 # --------------------------------------------------------------------------
@@ -711,13 +715,7 @@ def frontier_checksum(indptr, rows):
 def frontier_checksum_host(indptr, rows) -> int:
     """numpy twin of frontier_checksum, computed over the fetched host
     copies. Must track the device fold bit for bit."""
-    def fold(x, seed):
-        v = np.ascontiguousarray(x).view(np.uint32).reshape(-1)
-        v = v ^ (v >> np.uint32(16))
-        idx = np.arange(v.shape[0], dtype=np.uint32)
-        return (v * (np.uint32(2) * idx + np.uint32(seed))).sum(
-            dtype=np.uint32)
-    return int(fold(indptr, 13) ^ fold(rows, 17))
+    return int(_csum_fold_host(indptr, 13) ^ _csum_fold_host(rows, 17))
 
 
 def _frontier_compact_body(planes, out_cap: int):
@@ -799,7 +797,7 @@ def recovery_scan(status, touched_ms, now_ms, stall_ms, out_cap: int):
 
 @functools.partial(jax.jit, static_argnames=("out_cap",))
 def finalize_csr(packed, word_off, kid_rows, slot_subj, slot_kid,
-                 subj_row, act_ts, out_cap: int):
+                 subj_row, out_cap: int):
     """Device-side dep FINALIZATION for the key domain: consume the packed
     conflict bitmask straight out of deps_resolve (or one store's word span
     of the fused/sharded output -- `word_off` is the traced span offset) and
@@ -821,24 +819,24 @@ def finalize_csr(packed, word_off, kid_rows, slot_subj, slot_kid,
     subj_row:  i32[B]          subject's own arena row (-1 if unregistered),
                                cleared from its slots (a txn never deps on
                                itself)
-    act_ts:    i32[cap, 3]     the arena's txn-id lanes; gathered through the
-                               compacted rows so RESULTS ARE TXN IDS
-    -> (indptr i32[S+1], dep_rows i32[out_cap], dep_ts i32[out_cap, 3],
-        bound i32 scalar, csum u32 scalar);
-       dep order within a slot is ascending arena row; indptr[-1] > out_cap
-       signals overflow. `bound` is the segmented reduction over the slots'
-       kid-table row masks -- exactly the host popcount bound
-       (sum of key_pop over the dispatch's slot keys) -- read back with the
-       result so the NEXT dispatch's out_cap tier needs no host O(keys)
-       pass (resolver's OutCapTiers policy). `csum` is the csr_checksum
-       integrity word over the triple, verified at harvest.
+    -> (indptr i32[S+1], dep_rows i32[out_cap], bound i32 scalar,
+        csum u32 scalar);
+       dep order within a slot is ascending arena row, and dep_rows is 0
+       past indptr[-1]; indptr[-1] > out_cap signals overflow. The host
+       reads txn ids off its own arena lanes by row. `bound` is the
+       segmented reduction over the slots' kid-table row masks -- exactly
+       the host popcount bound (sum of key_pop over the dispatch's slot
+       keys) -- read back with the result so the NEXT dispatch's out_cap
+       tier needs no host O(keys) pass (resolver's OutCapTiers policy).
+       `csum` is the csr_checksum integrity word over (indptr, dep_rows),
+       verified at harvest.
     """
     return _finalize_csr_body(packed, word_off, kid_rows, slot_subj,
-                              slot_kid, subj_row, act_ts, out_cap)
+                              slot_kid, subj_row, out_cap)
 
 
 def _finalize_csr_body(packed, word_off, kid_rows, slot_subj, slot_kid,
-                       subj_row, act_ts, out_cap: int):
+                       subj_row, out_cap: int):
     """finalize_csr's trace body, unjitted so protocol_tick can inline the
     same compaction inside the fused cluster-tick program (the standalone
     jit wrapper above delegates here -- one source of truth, bit-identical
@@ -865,11 +863,9 @@ def _finalize_csr_body(packed, word_off, kid_rows, slot_subj, slot_kid,
             jnp.uint32(0))
         m = m & ~selfbit
     indptr, dep_rows = _packed_segment_compact(m, out_cap)
-    with jax.named_scope("ts_gather"):
-        dep_ts = act_ts[dep_rows]
     with jax.named_scope("checksum"):
-        csum = csr_checksum(indptr, dep_rows, dep_ts)
-    return indptr, dep_rows, dep_ts, bound, csum
+        csum = csr_checksum(indptr, dep_rows)
+    return indptr, dep_rows, bound, csum
 
 
 @functools.partial(jax.jit, static_argnames=("out_cap",))
@@ -889,9 +885,8 @@ def range_finalize_csr(iv_of, iv_start, iv_end, ent_ok,
     range_deps_resolve; `ent_ok` gates which entries finalize (entries of
     the targeted store).
 
-    -> (indptr i32[NV+1], dep_rows i32[out_cap], dep_ts i32[out_cap, 3],
-        bound i32 scalar, csum u32 scalar); dep_ts carries the range
-       arena's txn-id lanes so results are txn ids. `bound` is the
+    -> (indptr i32[NV+1], dep_rows i32[out_cap], bound i32 scalar,
+        csum u32 scalar); dep_rows is 0 past indptr[-1]. `bound` is the
        segmented STAB COUNT -- the number of (entry, valid-range) interval
        overlaps before the witness/before narrowing -- an exact upper
        bound on indptr[-1] read back with the result so the NEXT
@@ -926,11 +921,9 @@ def _range_finalize_csr_body(iv_of, iv_start, iv_end, ent_ok,
         before = _lex_before(r_ts[None, :, :], subj_before[o][:, None, :])
         m = stab & witness & before
     indptr, dep_rows = _packed_segment_compact(_pack_bits(m), out_cap)
-    with jax.named_scope("ts_gather"):
-        dep_ts = r_ts[dep_rows]
     with jax.named_scope("checksum"):
-        csum = csr_checksum(indptr, dep_rows, dep_ts)
-    return indptr, dep_rows, dep_ts, bound, csum
+        csum = csr_checksum(indptr, dep_rows)
+    return indptr, dep_rows, bound, csum
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4))
@@ -1524,12 +1517,12 @@ def _protocol_tick_fn(statics):
             else:
                 _k, rows, words, out_cap = spec
                 (r0, w_lo, word_off, kid_rows, slot_subj, slot_kid,
-                 subj_row, act_ts) = args
+                 subj_row) = args
                 src = packed if kind == "key" else rng_out[1]
                 blk = jax.lax.dynamic_slice(src, (r0, w_lo), (rows, words))
                 fin_outs.append(_finalize_csr_body(
                     blk, word_off, kid_rows, slot_subj, slot_kid,
-                    subj_row, act_ts, out_cap))
+                    subj_row, out_cap))
         cmd_outs = []
         for promote, args in zip(cmd_promotes, cmd_in):
             cmd_outs.append(_cmd_tick_body(*args, promote=promote))
@@ -1570,7 +1563,7 @@ def protocol_tick(witness_table, key_in=None, rng_in=None, fins=(),
     rng_in:  node_fused_range_deps_resolve's args minus witness_table
     fins:    finalize specs, one per (plan, group), in harvest order:
                ("key",  row_off, w_lo, rows, words, word_off, kid_rows,
-                slot_subj, slot_kid, subj_row, act_ts, out_cap)
+                slot_subj, slot_kid, subj_row, out_cap)
                ("rkey", ... same lanes, sliced from the k-side range output)
                ("range", iv_of, iv_s, iv_e, ent_ok, sb, sknd,
                 rsnap 5-tuple, out_cap)
@@ -1635,8 +1628,8 @@ def _fin_split(fins):
             fin_statics.append(("range", f[8]))
             fin_traced.append(tuple(f[1:8]))
         else:
-            fin_statics.append((f[0], f[3], f[4], f[11]))
-            fin_traced.append((f[1], f[2]) + tuple(f[5:11]))
+            fin_statics.append((f[0], f[3], f[4], f[10]))
+            fin_traced.append((f[1], f[2]) + tuple(f[5:10]))
     order = sorted(range(len(fin_statics)), key=lambda i: fin_statics[i])
     return ([fin_statics[i] for i in order],
             [fin_traced[i] for i in order], order)

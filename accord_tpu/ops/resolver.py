@@ -319,7 +319,7 @@ def warmup(num_buckets: int = 1024, cap: int = 8192,
                     kidx = jnp.full(z, kid_cap, jnp.int32)
                     for oc in out_tiers:
                         out = finalize_csr(packed, zero_off, kid_rows,
-                                           subj, kidx, srow, ts, out_cap=oc)
+                                           subj, kidx, srow, out_cap=oc)
             for z in nnz_tiers:
                 of = jnp.full(z, b, jnp.int32)
                 zz = jnp.zeros(z, jnp.int32)
@@ -1569,7 +1569,7 @@ class _Group:
         self.kp: Optional[Tuple[int, int]] = None
         # finalize_on_device state: the mutation-sequence snapshots the
         # harvest guards against, the deferred finalize kernels' device
-        # (indptr, dep_rows, dep_ts) triples + their host copies, and the
+        # (indptr, dep_rows, bound, csum) results + their host copies, and the
         # host-side routing tables the materialization walks
         self.kseq = arena.kseq
         self.rseq = arena.ranges.rseq
@@ -1741,10 +1741,21 @@ def _cut_csr(e_slot: np.ndarray, e_rank: np.ndarray, slot_off: np.ndarray,
 
 def _dev_ready(dev) -> bool:
     """is_ready over a device value that may be a tuple (the finalize
-    kernels return (indptr, dep_rows, dep_ts) triples)."""
+    kernels return (indptr, dep_rows, bound, csum))."""
     if isinstance(dev, tuple):
         return all(b.is_ready() for b in dev)
     return dev.is_ready()
+
+
+def _checked_words(buf):
+    """The words of a fetched finalize lane that its checksum covers and
+    its decode reads: indptr whole (a corrupted total still fails the
+    check) and dep_rows up to the total, or whole on overflow. The device
+    folds the whole dep_rows lane, which is 0 past the total, and a 0 word
+    folds to 0, so the host's prefix fold matches it bit for bit."""
+    indptr, dep_rows = buf[0], buf[1]
+    n = min(max(int(indptr[-1]), 0), dep_rows.shape[0])
+    return indptr, dep_rows[:n]
 
 
 def _dev_read(dev):
@@ -1838,7 +1849,7 @@ class _CompletionWaiter:
 class _Call:
     """One in-flight kernel dispatch: up to three device result buffers
     (key-domain deps, range-arena candidates, key-arena candidates for range
-    subjects) plus each group's finalized-CSR triples, the per-store groups
+    subjects) plus each group's finalized-CSR results, the per-store groups
     whose spans slice them, and the generation pins needed to decode after a
     compaction (held per group, so one store compacting never disturbs a
     batchmate). `want` flags which RAW candidate buffers the harvest reads
@@ -2137,7 +2148,7 @@ class BatchDepsResolver(DepsResolver):
         # True (default): the deps kernels' bucket-level results run through
         # finalize_csr / range_finalize_csr on device -- exact key filtering
         # + segment compaction -- so harvest reads back one contiguous
-        # (indptr, dep_rows, dep_ts) CSR per store instead of the full bit
+        # (indptr, dep_rows) CSR per store instead of the full bit
         # matrices. False: every group takes the legacy unpackbits decode,
         # which is the automatic per-group fallback when a sequence guard
         # trips mid-flight and the other side of the probation canary's
@@ -2263,12 +2274,12 @@ class BatchDepsResolver(DepsResolver):
         return pol
 
     def _run_finalize_kernel(self, packed, j_off, kid_rows, j_subj, j_kid,
-                             j_srow, act_ts, out_cap: int):
+                             j_srow, out_cap: int):
         """The finalize_csr launch point; the sharded resolver overrides it
         with the mesh-compacted twin (per-shard counts + gather-merge)."""
         from accord_tpu.ops.kernels import finalize_csr
         return finalize_csr(packed, j_off, kid_rows, j_subj, j_kid, j_srow,
-                            act_ts, out_cap=out_cap)
+                            out_cap=out_cap)
 
     # -- device health + fault handling ---------------------------------------
     def _node_health(self, node) -> "DeviceHealth":
@@ -2304,7 +2315,7 @@ class BatchDepsResolver(DepsResolver):
         if not self.verify_checksums:
             return True
         from accord_tpu.ops.kernels import csr_checksum_host
-        if csr_checksum_host(buf[0], buf[1], buf[2]) == int(buf[-1]):
+        if csr_checksum_host(*_checked_words(buf)) == int(buf[-1]):
             return True
         self.checksum_mismatches += 1
         call.faulted = True
@@ -2316,18 +2327,19 @@ class BatchDepsResolver(DepsResolver):
         return False
 
     def _apply_corruption(self, call: "_Call", plane) -> None:
-        """Consume a pending corrupt injection: flip one bit in the first
-        fetched finalize triple's host copy (writable clone -- the fetched
-        arrays may be read-only views of device buffers). Dropped when the
-        call carried no finalized lane (nothing checksummed to corrupt)."""
+        """Consume a pending corrupt injection: flip one bit of the words
+        the checksum covers in the first fetched finalize lane's host copy
+        (writable clones -- the fetched arrays may be read-only views of
+        device buffers). Dropped when the call carried no finalized lane
+        (nothing checksummed to corrupt)."""
         for g in call.groups:
             for attr in ("fin_np", "rfin_np", "rkfin_np"):
                 buf = getattr(g, attr)
                 if buf is None:
                     continue
-                arrs = [np.array(a) for a in buf[:3]]
-                if plane.corrupt_arrays(arrs):
-                    setattr(g, attr, tuple(arrs) + tuple(buf[3:]))
+                arrs = tuple(np.array(a) for a in buf[:2])
+                if plane.corrupt_arrays(_checked_words(arrs)):
+                    setattr(g, attr, arrs + tuple(buf[2:]))
                     self.device_faults_injected += 1
                     return
         # no finalized buffer on this call: injection dropped, uncounted
@@ -3061,25 +3073,23 @@ class BatchDepsResolver(DepsResolver):
         for i, item in pairs:
             subj_row[i] = arena.row_of.get(item.txn_id, -1)
         kid_rows = arena.kid_arrays()
-        act_ts = arena.device_arrays()[1]
         j_subj = jnp.asarray(a_subj)
         j_kid = jnp.asarray(a_kid)
         j_srow = jnp.asarray(subj_row)
         j_off = jnp.asarray(g.pk[0], jnp.int32)
         plan.fin_calls.append((g, lambda packed, kid_rows=kid_rows,
                                j_subj=j_subj, j_kid=j_kid, j_srow=j_srow,
-                               j_off=j_off, act_ts=act_ts, oc=out_cap:
+                               j_off=j_off, oc=out_cap:
                                self._run_finalize_kernel(
                                    packed, j_off, kid_rows, j_subj, j_kid,
-                                   j_srow, act_ts, out_cap=oc)))
+                                   j_srow, out_cap=oc)))
         if self.tick_driver is not None:
             # megakernel lane (index-aligned with the closure above):
             # slot_subj is plan-local and g.pk the plan-local word offset,
             # so the recorded lanes run unchanged against protocol_tick's
             # in-kernel demux of this plan's merge span
             plan.fin_args.append((g, ("key", kid_rows, j_subj, j_kid,
-                                      j_srow, act_ts, int(g.pk[0]),
-                                      out_cap)))
+                                      j_srow, int(g.pk[0]), out_cap)))
 
     def _plan_range_finalize(self, plan: _Plan, groups: List[_Group],
                              grents, givs, nv: int, j_iv, j_sb,
@@ -3183,21 +3193,19 @@ class BatchDepsResolver(DepsResolver):
         # check handles self-dependency like the legacy decode
         subj_row = np.full(b, -1, dtype=np.int32)
         kid_rows = arena.kid_arrays()
-        act_ts = arena.device_arrays()[1]
         j_subj = jnp.asarray(a_subj)
         j_kid = jnp.asarray(a_kid)
         j_srow = jnp.asarray(subj_row)
         j_off = jnp.asarray(g.kp[0], jnp.int32)
         plan.kfin_calls.append((g, lambda kpacked, kid_rows=kid_rows,
                                 j_subj=j_subj, j_kid=j_kid, j_srow=j_srow,
-                                j_off=j_off, act_ts=act_ts, oc=out_cap:
+                                j_off=j_off, oc=out_cap:
                                 self._run_finalize_kernel(
                                     kpacked, j_off, kid_rows, j_subj, j_kid,
-                                    j_srow, act_ts, out_cap=oc)))
+                                    j_srow, out_cap=oc)))
         if self.tick_driver is not None:
             plan.kfin_args.append((g, ("rkey", kid_rows, j_subj, j_kid,
-                                       j_srow, act_ts, int(g.kp[0]),
-                                       out_cap)))
+                                       j_srow, int(g.kp[0]), out_cap)))
 
     def _run_kernel(self, ksnap, subj_of, subj_keys, sb, sknd):
         """The single-store kernel call against a plan-time arena snapshot
@@ -3439,7 +3447,7 @@ class BatchDepsResolver(DepsResolver):
     def _fetch_np(self, holder, attr: str, dev):
         """Lazy blocking host read of a device buffer, cached on its holder
         (_Call for the raw candidate buffers, _Group for the finalized CSR
-        triples) and timed into readback_s -- the finalized path skips the
+        results) and timed into readback_s -- the finalized path skips the
         eager raw-buffer readback; fallbacks pay only for what they touch."""
         cached = getattr(holder, attr)
         if cached is not None:
@@ -3494,7 +3502,7 @@ class BatchDepsResolver(DepsResolver):
             return None     # kernel never launched (defensive)
         if not self._csum_ok(call, g, buf):
             return None     # corrupted readback: caught before decode
-        indptr, dep_rows, _, dbound, _ = buf
+        indptr, dep_rows, dbound, _ = buf
         ns = len(flat_key)
         pol = self._observe_bound(arena, "key", dbound, ns)
         if call is not None and call.overflow_pending:
@@ -3615,7 +3623,7 @@ class BatchDepsResolver(DepsResolver):
         buf = self._fetch_np(g, "rfin_np", g.rfin_dev)
         if not self._csum_ok(call, g, buf):
             return None     # corrupted readback: caught before decode
-        indptr, dep_rows, _, dbound, _ = buf
+        indptr, dep_rows, dbound, _ = buf
         pol = self._observe_bound(g.arena, "range", dbound,
                                   max(len(g.rents), 1))
         if int(indptr[-1]) > dep_rows.shape[0]:
@@ -3708,7 +3716,7 @@ class BatchDepsResolver(DepsResolver):
         buf = self._fetch_np(g, "rkfin_np", g.rkfin_dev)
         if not self._csum_ok(call, g, buf):
             return None     # corrupted readback: caught before decode
-        indptr, dep_rows, _, dbound, _ = buf
+        indptr, dep_rows, dbound, _ = buf
         ns = len(g.rk_slots)
         pol = self._observe_bound(arena, "rkey", dbound, ns)
         total = int(indptr[ns])
@@ -4665,7 +4673,7 @@ class ShardedBatchDepsResolver(BatchDepsResolver):
                     act_bm, act_ts, act_kinds, act_valid, self._table)
 
     def _run_finalize_kernel(self, packed, j_off, kid_rows, j_subj, j_kid,
-                             j_srow, act_ts, out_cap: int):
+                             j_srow, out_cap: int):
         # the finalize compaction shards its word columns over 'data': each
         # shard popcounts and compacts ITS slice of every slot's row mask,
         # an all-gather of the per-shard counts yields the global indptr
@@ -4676,7 +4684,7 @@ class ShardedBatchDepsResolver(BatchDepsResolver):
         kern = sharded_finalize_csr(self.mesh)
         with self._phase("resolver.shard_merge", "resolver.shard_merge_s"):
             return kern(packed, j_off, kid_rows, j_subj, j_kid, j_srow,
-                        act_ts, out_cap=out_cap)
+                        out_cap=out_cap)
 
     def _run_range_kernel(self, rsnap, ksnap, iv_of, iv_s, iv_e,
                           sb, sknd, srng):

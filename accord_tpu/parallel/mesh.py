@@ -524,8 +524,7 @@ def sharded_node_tick(mesh: Mesh, key_merge, range_merge, table):
 
 
 def _sharded_finalize_body(mesh: Mesh, packed, word_off, kid_rows,
-                           slot_subj, slot_kid, subj_row, act_ts,
-                           out_cap: int):
+                           slot_subj, slot_kid, subj_row, out_cap: int):
     """Mesh-sharded twin of ops.kernels._finalize_csr_body: the
     finalized-CSR COMPACTION distributed over 'data' word columns, shared
     by the standalone sharded_finalize_csr jit and the sharded protocol
@@ -554,13 +553,14 @@ def _sharded_finalize_body(mesh: Mesh, packed, word_off, kid_rows,
     stop duplicating the full [slots x words] SWAR pass.
 
     Word order equals row order and shards partition words contiguously,
-    so (indptr, dep_rows, dep_ts, bound, csum) is bit-identical to the
+    so (indptr, dep_rows, bound, csum) is bit-identical to the
     single-device finalize_csr -- the csr_checksum integrity word is
-    computed over the MERGED triple, after the fragment sum, so it folds
-    exactly the arrays the harvest will read back. Overflow keeps the
-    same contract
-    (indptr[-1] > out_cap; the exact total comes from the gathered counts,
-    never from the possibly-dropped scatters)."""
+    computed over the MERGED pair, after the fragment sum, so it folds
+    exactly the arrays the harvest will read back (no fragment writes past
+    the total, so dep_rows is 0 there, as the host's prefix fold needs).
+    Overflow keeps the same contract (indptr[-1] > out_cap; the exact total
+    comes from the gathered counts, never from the possibly-dropped
+    scatters)."""
     from accord_tpu.ops.kernels import _popcount_u32
     data = mesh.shape["data"]
     model = mesh.shape["model"]
@@ -656,10 +656,8 @@ def _sharded_finalize_body(mesh: Mesh, packed, word_off, kid_rows,
         [jnp.zeros(1, jnp.int32), jnp.cumsum(counts, dtype=jnp.int32)])
     dep_rows = jnp.sum(frags, axis=0)
     bound = jnp.sum(bounds, dtype=jnp.int32)
-    dep_ts = act_ts[dep_rows]
     from accord_tpu.ops.kernels import csr_checksum
-    return (indptr, dep_rows, dep_ts, bound,
-            csr_checksum(indptr, dep_rows, dep_ts))
+    return indptr, dep_rows, bound, csr_checksum(indptr, dep_rows)
 
 
 @functools.lru_cache(maxsize=8)
@@ -670,10 +668,10 @@ def sharded_finalize_csr(mesh: Mesh):
     (shape, out_cap)."""
 
     def run(packed, word_off, kid_rows, slot_subj, slot_kid,
-            subj_row, act_ts, out_cap: int):
+            subj_row, out_cap: int):
         return _sharded_finalize_body(mesh, packed, word_off, kid_rows,
                                       slot_subj, slot_kid, subj_row,
-                                      act_ts, out_cap)
+                                      out_cap)
 
     return jax.jit(run, static_argnames=("out_cap",))
 
@@ -776,12 +774,12 @@ def _sharded_tick_fn(mesh: Mesh, statics):
             else:
                 _kk, rows, words, out_cap = spec
                 (r0, w_lo, word_off, kid_rows, slot_subj, slot_kid,
-                 subj_row, act_ts) = args
+                 subj_row) = args
                 src = packed if kind == "key" else rng_out[1]
                 blk = jax.lax.dynamic_slice(src, (r0, w_lo), (rows, words))
                 fin_outs.append(_sharded_finalize_body(
                     mesh, blk, word_off, kid_rows, slot_subj, slot_kid,
-                    subj_row, act_ts, out_cap))
+                    subj_row, out_cap))
         cmd_outs = []
         for promote, args in zip(cmd_promotes, cmd_in):
             cmd_outs.append(_k._cmd_tick_body(*args, promote=promote))
@@ -970,7 +968,7 @@ def warmup_sharded(mesh: Mesh, num_buckets: int = 256, cap: int = 4096,
                 kidx = jnp.full(z, kid_cap, jnp.int32)
                 for oc in out_tiers:
                     out = fin(packed, zero_off, kid_rows, subj, kidx,
-                              srow, ts, out_cap=oc)
+                              srow, out_cap=oc)
     if cmd_caps:
         from accord_tpu.ops.cmd_plane import (CMD_OP_TIERS,
                                               warmup_cmd_plane)

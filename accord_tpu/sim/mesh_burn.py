@@ -542,10 +542,9 @@ class ClusterTickEngine:
         if km is not None:
             for (plan, _args), (r0, b, wlo, w) in zip(key_entries, km.spans):
                 for gi, (_g, spec) in enumerate(plan.fin_args):
-                    (_k, kid_rows, j_subj, j_kid, j_srow, act_ts,
-                     off, oc) = spec
+                    _k, kid_rows, j_subj, j_kid, j_srow, off, oc = spec
                     fins.append(("key", r0, wlo, b, w, off, kid_rows,
-                                 j_subj, j_kid, j_srow, act_ts, oc))
+                                 j_subj, j_kid, j_srow, oc))
                     fin_sched.append((plan, "fin", gi))
         if rm is not None:
             for (plan, _args), (r0, b, _rwlo, _rw, kwlo, kw) \
@@ -556,10 +555,9 @@ class ClusterTickEngine:
                                  j_sknd, rsnap, oc))
                     fin_sched.append((plan, "rfin", gi))
                 for gi, (_g, spec) in enumerate(plan.kfin_args):
-                    (_k, kid_rows, j_subj, j_kid, j_srow, act_ts,
-                     off, oc) = spec
+                    _k, kid_rows, j_subj, j_kid, j_srow, off, oc = spec
                     fins.append(("rkey", r0, kwlo, b, kw, off, kid_rows,
-                                 j_subj, j_kid, j_srow, act_ts, oc))
+                                 j_subj, j_kid, j_srow, oc))
                     fin_sched.append((plan, "kfin", gi))
         # stack the drains' deferred cmd transition lanes for the quorum
         # count, padded to the MEGA_LANE_TIERS ladder

@@ -128,8 +128,9 @@ def test_finalize_csr_keeps_no_out_cap_by_32_temporary_at_the_key_cells_shapes(
     candidates, five `[out_cap, 32]` arrays of 33.5 MB in the compiled
     program; the form that gathers by output position works in lanes of
     out_cap elements, and the largest array it holds is the slot matrix
-    itself. (The program's peak is `ts_gather`'s, before and after: the chip
-    pads its `[out_cap, 3]` result to 128 columns, 134 MB.)"""
+    itself. It returns indptr, dep_rows and two words, and holds under 16
+    bytes of temporaries an out-cap row: an `[out_cap, 3]` lane, which the
+    chip pads to 128 columns (134 MB here), would break both bounds."""
     import re
 
     from accord_tpu.ops import kernels
@@ -138,12 +139,13 @@ def test_finalize_csr_keeps_no_out_cap_by_32_temporary_at_the_key_cells_shapes(
         shaped((b, cap // 32), np.uint32), shaped((), np.int32),
         shaped((kid_cap, cap // 32), np.uint32), shaped((slots,), np.int32),
         shaped((slots,), np.int32), shaped((b,), np.int32),
-        shaped((cap, 3), np.int32), out_cap=out_cap).compile()
+        out_cap=out_cap).compile()
     sizes = {dims: int(np.prod([int(d) for d in dims.split(",")]))
              for dims in re.findall(r"\b(?:pred|[suf]\d+)\[([\d,]+)\]",
                                     compiled.as_text())}
     assert max(sizes.values()) == slots * (cap // 32), max(
         sizes, key=sizes.get)
     assert not [d for d, n in sizes.items() if n >= out_cap * 32]
-    assert compiled.memory_analysis().temp_size_in_bytes \
-        < out_cap * 128 * 4 + out_cap * 32
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes < (slots + 1 + out_cap) * 4 + 8192
+    assert memory.temp_size_in_bytes < out_cap * 16

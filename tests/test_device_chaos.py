@@ -94,29 +94,114 @@ def test_health_ladder_probation_fault_requarantines():
 
 # -- units: checksum lane ----------------------------------------------------
 
-def test_checksum_device_host_agree_and_catch_bit_flips():
+@pytest.mark.parametrize("lane", [0, 1], ids=["indptr", "dep_rows"])
+def test_checksum_device_host_agree_and_catch_bit_flips(lane):
     """The jitted fold and its host twin agree exactly on the finalize
-    kernels' result shapes (indptr i32[S+1], dep_rows i32[N], dep_ts
-    i32[N, 3]), and ANY single-bit flip in any covered array changes the
-    sum."""
+    kernels' result shapes (indptr i32[S+1], dep_rows i32[N]), the device's
+    fold over a lane with a zero tail equals the host's over the words up
+    to the total, and ANY single-bit flip in either covered array changes
+    the sum."""
     rng = np.random.default_rng(5)
     indptr = np.cumsum(rng.integers(0, 5, 33)).astype(np.int32)
     rows = rng.integers(0, 1 << 20, int(indptr[-1])).astype(np.int32)
-    ts = rng.integers(0, 1 << 31, (int(indptr[-1]), 3)).astype(np.int32)
-    dev = int(csr_checksum(jnp.asarray(indptr), jnp.asarray(rows),
-                           jnp.asarray(ts)))
-    host = csr_checksum_host(indptr, rows, ts)
+    dev = int(csr_checksum(jnp.asarray(indptr), jnp.asarray(rows)))
+    host = csr_checksum_host(indptr, rows)
     assert dev == host
-    for arr in (indptr, rows, ts):
-        for _ in range(8):
-            clone = [np.array(a) for a in (indptr, rows, ts)]
-            tgt = clone[[id(indptr), id(rows), id(ts)].index(id(arr))]
-            flat = tgt.reshape(-1).view(np.uint32)
-            pos = int(rng.integers(flat.shape[0]))
+    tail = np.concatenate([rows, np.zeros(37, np.int32)])
+    assert int(csr_checksum(jnp.asarray(indptr), jnp.asarray(tail))) == host
+    for _ in range(8):
+        clone = [np.array(indptr), np.array(rows)]
+        flat = clone[lane].view(np.uint32)
+        pos = int(rng.integers(flat.shape[0]))
+        bit = int(rng.integers(32))
+        flat[pos] ^= np.uint32(1) << np.uint32(bit)
+        assert csr_checksum_host(*clone) != host, \
+            f"flip at word {pos} bit {bit} not detected"
+
+
+def _finalized_lane(program, dense, out_cap):
+    """A real finalize_csr or range_finalize_csr result at small shapes, as
+    the harvest fetches it: (indptr, dep_rows, bound, csum) host copies.
+    `dense` inputs hold more dependencies than the 256 out-cap tier."""
+    from accord_tpu.ops.encoding import WITNESS_TABLE
+    from accord_tpu.ops.kernels import finalize_csr, range_finalize_csr
+    rng = np.random.default_rng(11 if dense else 7)
+    b, cap = 8, 256
+    if program == "finalize_csr":
+        w, kc, s = cap // 32, 16, 32
+        bits = rng.random((b, w, 32)) < (0.6 if dense else 0.08)
+        packed = np.packbits(bits, axis=-1, bitorder="little") \
+            .view(np.uint32).reshape(b, w)
+        kid = np.packbits(rng.random((kc, w, 32)) < 0.3, axis=-1,
+                          bitorder="little").view(np.uint32).reshape(kc, w)
+        out = finalize_csr(
+            packed, np.int32(0), kid,
+            rng.integers(0, b + 1, s).astype(np.int32),
+            rng.integers(0, kc + 1, s).astype(np.int32),
+            rng.integers(-1, cap, b).astype(np.int32), out_cap=out_cap)
+    else:
+        nv, rcap = 24, 64
+        r_start = rng.integers(0, 60, rcap).astype(np.int32)
+        iv_s = rng.integers(0, 60, nv).astype(np.int32)
+        width = 40 if dense else 6
+        out = range_finalize_csr(
+            rng.integers(0, b, nv).astype(np.int32), iv_s,
+            iv_s + rng.integers(1, width, nv).astype(np.int32),
+            np.ones(nv, bool),
+            np.stack([np.zeros(b), np.full(b, 90), np.zeros(b)],
+                     axis=1).astype(np.int32),
+            np.ones(b, np.int32), r_start,
+            r_start + rng.integers(1, width, rcap).astype(np.int32),
+            np.stack([np.zeros(rcap), rng.integers(0, 80, rcap),
+                      np.arange(rcap)], axis=1).astype(np.int32),
+            np.ones(rcap, np.int32), rng.random(rcap) < 0.9,
+            np.asarray(WITNESS_TABLE), out_cap=out_cap)
+    return tuple(np.asarray(a) for a in out)
+
+
+@pytest.mark.parametrize("program", ["finalize_csr", "range_finalize_csr"])
+@pytest.mark.parametrize("dense,out_cap", [(False, 256), (False, 2048),
+                                           (True, 256)],
+                         ids=["tier-256", "tier-2048", "overflow"])
+def test_finalized_lane_checks_exactly_the_words_the_decode_reads(
+        program, dense, out_cap):
+    """On the kernels' real outputs: dep_rows is 0 past the total, the
+    harvest's fold of indptr and dep_rows[:total] equals the device's
+    checksum word, and a single-bit flip in any word it covers is caught
+    (on overflow every dep_rows word is covered)."""
+    from accord_tpu.ops.resolver import _checked_words
+    indptr, dep_rows, bound, csum = _finalized_lane(program, dense, out_cap)
+    total = int(indptr[-1])
+    assert 0 < total <= int(bound)
+    assert (total > out_cap) == dense, (total, out_cap)
+    assert not dep_rows[total:].any()
+    words = _checked_words((indptr, dep_rows, bound, csum))
+    assert words[1].shape[0] == min(total, out_cap)
+    assert csr_checksum_host(*words) == int(csum)
+    rng = np.random.default_rng(out_cap + total)
+    for lane, n in ((0, indptr.shape[0]), (1, words[1].shape[0])):
+        for pos in range(n):
+            clone = [np.array(indptr), np.array(dep_rows)]
             bit = int(rng.integers(32))
-            flat[pos] ^= np.uint32(1) << np.uint32(bit)
-            assert csr_checksum_host(*clone) != host, \
-                f"flip at word {pos} bit {bit} not detected"
+            clone[lane].view(np.uint32)[pos] ^= np.uint32(1) << np.uint32(bit)
+            assert csr_checksum_host(*_checked_words(clone)) != int(csum), \
+                f"lane {lane} word {pos} bit {bit} not detected"
+
+
+def test_injected_corruption_lands_in_the_checked_words():
+    """The fault plane's flip, drawn over `_checked_words` as the harvest
+    draws it, always lands where the check sees it and never in the zero
+    tail the decode does not read."""
+    from accord_tpu.ops.resolver import _checked_words
+    indptr, dep_rows, _, csum = _finalized_lane("finalize_csr", False, 2048)
+    total = int(indptr[-1])
+    plane = DeviceFaultPlane(RandomSource(3).fork(), corrupt_rate=1.0)
+    for _ in range(64):
+        arrs = (np.array(indptr), np.array(dep_rows))
+        assert plane.corrupt_arrays(_checked_words(arrs))
+        assert not arrs[1][total:].any()
+        assert csr_checksum_host(*_checked_words(arrs)) != int(csum)
+    assert plane.injected["corrupt"] == 64
 
 
 def test_fault_plane_deterministic_and_exact_ledger():

@@ -357,6 +357,43 @@ def test_out_cap_overflow_bumps_tier_and_falls_back_exactly():
     assert resolver.host_fallbacks == 0
 
 
+def test_finalized_key_harvest_reads_back_indptr_rows_and_two_words(
+        monkeypatch):
+    """One finalized key lane's harvest fetches (indptr, dep_rows, bound,
+    csum) and nothing else: readback_bytes grows by exactly indptr's and
+    dep_rows' bytes and the two words, with no [out_cap, 3] txn-id lane
+    (the decode reads txn ids off the host's arena lanes by row)."""
+    rng = np.random.default_rng(29)
+    _, node, store = setup_store()
+    resolver = BatchDepsResolver(num_buckets=128, initial_cap=128)
+    store.deps_resolver = resolver
+    for _ in range(40):
+        ts = node.unique_now()
+        tid = TxnId.create(ts.epoch, ts.hlc, ts.node, TxnKind.WRITE,
+                           Domain.KEY)
+        ks = {int(k) for k in rng.integers(0, 12, 3)}
+        store.register(tid, Keys(sorted(ks)), CfkStatus.WITNESSED, ts)
+    reads = []
+    read = resolver._read
+    monkeypatch.setattr(resolver, "_read",
+                        lambda dev: reads.append(read(dev)) or reads[-1])
+    far = Timestamp(node.epoch, node.time_service.now_micros() + 50_000,
+                    0, node.id)
+    tid = node.next_txn_id(TxnKind.WRITE, Domain.KEY)
+    owned = store.owned(Keys([3, 7]))
+    before = resolver.readback_bytes
+    dev = resolver.resolve_one(store, tid, owned, far)
+    assert dev == store.host_calculate_deps(tid, owned, far)
+    assert dev.key_deps.all_txn_ids(), "differential vacuous"
+    assert resolver.finalized_decodes == 1 and resolver.legacy_decodes == 0
+    (fetched,) = reads
+    indptr, dep_rows, bound, csum = fetched
+    assert indptr.ndim == dep_rows.ndim == 1
+    assert bound.shape == csum.shape == ()
+    assert resolver.readback_bytes - before \
+        == indptr.nbytes + dep_rows.nbytes + 8
+
+
 def test_device_bound_and_range_stab_randomized_differential():
     """The retired host residuals, differentially: the default resolver
     (device-computed out-cap bound + on-device range-subject stabbing) vs

@@ -114,8 +114,8 @@ def test_burn_sharded_matches_host_resolver_log():
 def test_sharded_finalize_kernel_matches_single_device():
     """The sharded compaction twin: per-shard popcount/prefix fragments
     gather-merged into the global CSR must be BIT-identical to
-    kernels.finalize_csr -- indptr, dep_rows, dep_ts and the fused bound
-    scalar -- including fused word spans (word_off != 0) and overflow
+    kernels.finalize_csr -- indptr, dep_rows, the fused bound scalar and
+    the checksum word -- including fused word spans (word_off != 0) and overflow
     (where both sides must still report the exact total)."""
     import jax.numpy as jnp
     from accord_tpu.ops.kernels import finalize_csr
@@ -141,11 +141,10 @@ def test_sharded_finalize_kernel_matches_single_device():
                 jnp.asarray(kid),
                 jnp.asarray(rng.integers(-1, b + 2, s), jnp.int32),
                 jnp.asarray(rng.integers(0, kc + 1, s), jnp.int32),
-                jnp.asarray(rng.integers(-1, cap, b), jnp.int32),
-                jnp.asarray(rng.integers(0, 1 << 20, (cap, 3)), jnp.int32))
+                jnp.asarray(rng.integers(-1, cap, b), jnp.int32))
         single = finalize_csr(*args, out_cap=out_cap)
         sharded = kern(*args, out_cap=out_cap)
-        for name, a, c in zip(("indptr", "dep_rows", "dep_ts", "bound"),
+        for name, a, c in zip(("indptr", "dep_rows", "bound", "csum"),
                               single, sharded):
             assert np.array_equal(np.asarray(a), np.asarray(c)), \
                 f"trial {trial}: sharded {name} != single-device"
@@ -185,14 +184,13 @@ def test_model_sharded_kid_bound_matches_single_device():
                 jnp.asarray(kid),
                 jnp.asarray(rng.integers(-1, b + 2, s), jnp.int32),
                 jnp.asarray(rng.integers(0, kc + 1, s), jnp.int32),
-                jnp.asarray(rng.integers(-1, cap, b), jnp.int32),
-                jnp.asarray(rng.integers(0, 1 << 20, (cap, 3)), jnp.int32))
+                jnp.asarray(rng.integers(-1, cap, b), jnp.int32))
         single = finalize_csr(*args, out_cap=2048)
         sharded = kern(*args, out_cap=2048)
-        assert int(np.asarray(single[3])) == int(np.asarray(sharded[3])), \
+        assert int(np.asarray(single[2])) == int(np.asarray(sharded[2])), \
             f"nnz {s}: model-sharded bound != single-device bound"
-        assert int(np.asarray(single[3])) > 0, f"nnz {s}: bound vacuous"
-        for name, a, c in zip(("indptr", "dep_rows", "dep_ts"),
+        assert int(np.asarray(single[2])) > 0, f"nnz {s}: bound vacuous"
+        for name, a, c in zip(("indptr", "dep_rows", "bound", "csum"),
                               single, sharded):
             assert np.array_equal(np.asarray(a), np.asarray(c)), \
                 f"nnz {s}: sharded {name} != single-device"

@@ -668,8 +668,7 @@ def _kernel_args(seed=3, b=8, cap=64, k=32, nnz=24, s=16, kc=16):
         rng.integers(0, 2 ** 32, (kc, cap // 32), dtype=np.uint32),
         rng.integers(0, b + 1, s).astype(np.int32),
         rng.integers(0, kc + 1, s).astype(np.int32),
-        rng.integers(-1, cap, b).astype(np.int32),
-        resolve[5])
+        rng.integers(-1, cap, b).astype(np.int32))
     return resolve, finalize
 
 
@@ -677,14 +676,12 @@ RESOLVE_SCOPES = ("subject_bitmap", "overlap", "witness_before_mask",
                   "pack_bits")
 COMPACT_SCOPES = ("popcount_prefix", "word_fold", "word_compact",
                   "row_expand", "mark_scatter", "owner_fill", "bit_select")
-FINALIZE_SCOPES = ("slot_mask", "bound", *COMPACT_SCOPES, "ts_gather",
-                   "checksum")
+FINALIZE_SCOPES = ("slot_mask", "bound", *COMPACT_SCOPES, "checksum")
 RANGE_RESOLVE_SCOPES = ("interval_overlap", "range_witness_before_mask",
                         "covered_buckets", "bucket_overlap",
                         "key_witness_before_mask", "pack_bits")
 RANGE_FINALIZE_SCOPES = ("interval_stab", "bound", "witness_before_mask",
-                         "pack_bits", *COMPACT_SCOPES, "ts_gather",
-                         "checksum")
+                         "pack_bits", *COMPACT_SCOPES, "checksum")
 
 
 def _range_kernel_args(seed=3, nv=24, rcap=32):
@@ -787,7 +784,7 @@ def test_scope_names_change_no_answer(monkeypatch, path):
         return tuple(out) + tuple(finalize(*f_args, out_cap=256))
 
     named = answers(resolve, finalize)
-    assert int(named[-5][-1]) > 0, "the inputs produced no dependency"
+    assert int(named[-4][-1]) > 0, "the inputs produced no dependency"
     # the same trace bodies with every scope a no-op
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())

@@ -139,11 +139,8 @@ def test_sharded_tick_key_finalize_matches_single_device(mesh):
     j_subj = jnp.asarray(rng.integers(0, b, 12).astype(np.int32))
     j_kid = jnp.asarray(rng.integers(0, kc, 12).astype(np.int32))
     j_srow = jnp.asarray(rng.integers(-1, cap, b).astype(np.int32))
-    act_ts = arenas[0][1]
-    fins = (("key", 0, 0, b, w, 0, kid_rows, j_subj, j_kid, j_srow,
-             act_ts, oc),
-            ("key", 0, w, b, w, 0, kid_rows, j_subj, j_kid, j_srow,
-             act_ts, oc))
+    fins = (("key", 0, 0, b, w, 0, kid_rows, j_subj, j_kid, j_srow, oc),
+            ("key", 0, w, b, w, 0, kid_rows, j_subj, j_kid, j_srow, oc))
     ref = protocol_tick(table, key_in=key_in, fins=fins)
     got = sharded_protocol_tick(mesh, table, key_in=key_in, fins=fins)
     np.testing.assert_array_equal(np.asarray(ref[0]), np.asarray(got[0]))
