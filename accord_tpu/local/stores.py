@@ -52,6 +52,9 @@ class CommandStores:
         # the fan-out's counters (map_reduce_async), in the node's registry
         self._requests = node.metrics.counter("node.requests")
         self._store_slices = node.metrics.counter("node.store_slices")
+        self._range_requests = node.metrics.counter("node.range_requests")
+        self._range_store_slices = node.metrics.counter(
+            "node.range_store_slices")
         per_store: List[List[Range]] = [[] for _ in range(num_stores)]
         for rng in global_ranges:
             pieces = splitter(rng, num_stores)
@@ -153,13 +156,18 @@ class CommandStores:
         the PreAcceptOk reduce, messages/PreAccept.java:141-156). PreAccept
         and Accept both come through here. A request no store owns completes
         with None, as map_reduce's does. Spans node.fanout and node.reduce;
-        node.requests counts calls and node.store_slices the stores asked."""
+        node.requests counts calls and node.store_slices the stores asked,
+        node.range_requests and node.range_store_slices the same for the
+        requests whose seekables are Ranges."""
         metrics = self.node.metrics
         with phase(metrics, "node.fanout", "node.fanout_s"):
             targets = self.intersecting(seekables)
             parts = [map_fn(s) for s in targets]
         self._requests.inc()
         self._store_slices.inc(len(targets))
+        if isinstance(seekables, Ranges):
+            self._range_requests.inc()
+            self._range_store_slices.inc(len(targets))
         if not targets:
             return success(None)
 

@@ -342,6 +342,9 @@ GLOSSARY: Dict[str, str] = {
     "resolver.array_cuts": "calls of the whole-dispatch cut (_cut_csr: every item's KeyDeps or RangeDeps from one sort and one cut over the dispatch's pairs), one a domain a group",
     "resolver.fused_dispatches": "dispatches that ran a fused cross-store program (several store groups routed by the store-id lane; a one-store tick runs the plain kernels and counts none)",
     "resolver.store_groups": "store groups that rode those fused dispatches, summed (over resolver.dispatches: the stores a dispatch of the node answers for)",
+    "resolver.range_dispatches": "dispatches that carried a range call (range subjects, or key subjects stabbing a store's range txns)",
+    "resolver.fused_range_dispatches": "of those, dispatches whose range call ran the fused cross-store range program (fused_range_deps_resolve)",
+    "resolver.fused_range_groups": "store groups with a block in those fused range programs, summed",
     "resolver.arena_sync_s": "encode_s spent bringing the device arena up to the host shadows: dirty rows shipped by device_arrays(), dirty kid words by kid_arrays() (host time of the calls: the scatters rewrite in place and the host waits for none)",
     "resolver.arena_rows_uploaded": "arena rows shipped to the device, of any lane group (whole rows, key sets, one lane)",
     "resolver.arena_upload_calls": "device scatter calls those uploads took (arena_scatter, arena_scatter_keys, scatter_rows, kid_word_scatter)",
@@ -436,6 +439,8 @@ GLOSSARY: Dict[str, str] = {
     # -- the node's fan-out (CommandStores.map_reduce_async, Node.metrics) ---
     "node.requests": "requests fanned out over the node's stores (every PreAccept and Accept)",
     "node.store_slices": "stores those requests were sliced to and asked, summed",
+    "node.range_requests": "of those requests, the ones whose seekables are Ranges",
+    "node.range_store_slices": "stores those range requests were sliced to and asked, summed",
     "node.fanout_s": "wall seconds finding the intersecting stores, slicing the request and handing each store its part",
     "node.reduce_s": "wall seconds folding the stores' answers into one reply, in store order",
     # -- maelstrom runner (Runner.metrics) -----------------------------------

@@ -1945,8 +1945,8 @@ class _Plan:
     harvest."""
 
     __slots__ = ("items", "groups", "key_call", "range_call", "empty",
-                 "fused", "fin_calls", "rfin_calls", "kfin_calls", "want",
-                 "key_args", "range_args",
+                 "fused", "range_groups", "fin_calls", "rfin_calls",
+                 "kfin_calls", "want", "key_args", "range_args",
                  "fin_args", "rfin_args", "kfin_args")
 
     def __init__(self, items: List[_Item], groups: List[_Group],
@@ -1959,6 +1959,9 @@ class _Plan:
         # a deferred call above runs a fused cross-store program (several
         # store groups, routed by the store-id lane)
         self.fused = False
+        # the store groups with a block in range_call's fused program
+        # (fused_range_deps_resolve); 0 where it runs the plain kernel
+        self.range_groups = 0
         # node-lane merge inputs (ops/node_lane.py): the EXACT arrays the
         # deferred calls above would feed their kernels, recorded only when
         # a cluster tick_driver is attached -- the mesh-burn engine stacks
@@ -2050,6 +2053,12 @@ class BatchDepsResolver(DepsResolver):
     # cross-store program, and the store groups that rode them
     fused_dispatches = RegCounter("resolver.fused_dispatches")
     store_groups = RegCounter("resolver.store_groups")
+    # the same for the range call alone: dispatches that carried one, those
+    # whose range call ran fused_range_deps_resolve, and the store groups
+    # with a block in it
+    range_dispatches = RegCounter("resolver.range_dispatches")
+    fused_range_dispatches = RegCounter("resolver.fused_range_dispatches")
+    fused_range_groups = RegCounter("resolver.fused_range_groups")
     # the store's lifecycle, each under its own span. The arenas count the
     # first three groups into this registry themselves: device sync
     # (resolver.arena_sync: dirty rows shipped by device_arrays(), dirty
@@ -2100,6 +2109,7 @@ class BatchDepsResolver(DepsResolver):
                  finalize_on_device: bool = True,
                  adaptive_window: bool = False,
                  kid_cap: int = 4096,
+                 initial_range_cap: int = 64,
                  verify_checksums: bool = True,
                  retry_limit: int = 2,
                  watchdog_probes: int = 3,
@@ -2186,9 +2196,10 @@ class BatchDepsResolver(DepsResolver):
         # (the pool grows alongside arenas that outgrow initial_cap)
         self._pad_key: Dict[int, tuple] = {}
         self._pad_range: Dict[int, tuple] = {}
-        # initial _RangeArena capacity (the sharded resolver widens it to
-        # keep rcap % (32*data) == 0)
-        self.range_cap = 64
+        # initial _RangeArena capacity, every store's alike (the fused range
+        # program compiles for the capacities of its arenas; the sharded
+        # resolver widens it to keep rcap % (32*data) == 0)
+        self.range_cap = initial_range_cap
         # device-plane fault tolerance: re-derive the finalize kernels'
         # fused checksum word from the host copies at harvest (a corrupted
         # readback can never decode into wrong deps -- it falls back to the
@@ -2997,6 +3008,7 @@ class BatchDepsResolver(DepsResolver):
                     return (rp if has_r else None, kp if has_k else None)
 
                 plan.fused = True
+                plan.range_groups = len({gi for gi, _ in r_parts + h_parts})
                 plan.range_call = range_call
                 if self.tick_driver is not None:
                     plan.range_args = dict(
@@ -4325,6 +4337,11 @@ class BatchDepsResolver(DepsResolver):
                 if plan.fused:
                     self.fused_dispatches += 1
                     self.store_groups += len(plan.groups)
+                if plan.range_call is not None:
+                    self.range_dispatches += 1
+                    if plan.range_groups:
+                        self.fused_range_dispatches += 1
+                        self.fused_range_groups += plan.range_groups
                 if fault == "stuck":
                     plane.note("stuck")
                     self.device_faults_injected += 1

@@ -120,6 +120,39 @@ def test_the_fused_cross_store_program_fits_the_chip_at_the_node_cells_shapes(
     assert memory.temp_size_in_bytes < cap * BUCKETS * 4
 
 
+def test_the_fused_range_program_fits_the_chip_at_the_node_range_cells_shapes(
+        shaped):
+    """`fused_range_deps_resolve` over eight stores, each a range arena of
+    8,192 rows and a key arena of 65,536, a full dispatch of 1,024 store
+    slices with 2,048 intervals (`preaccept-8stores-ranges-100k.range-20`):
+    the chip's compiler takes it, the eight bitmaps are its arguments, the
+    two packed results are u32[1024, 8 x 256] and u32[1024, 8 x 2048], and
+    what it needs beside them stays under two stores' f32[1024, 65536]
+    contraction (445 MB when compiled for this cell): the eight stores'
+    products are never all alive at once."""
+    from accord_tpu.ops import kernels
+    from accord_tpu.ops.encoding import WITNESS_TABLE
+    cap, rcap, stores, b, z = 65536, 8192, 8, 1024, 2048
+    karena = (shaped((cap, BUCKETS), np.float32), shaped((cap, 3), np.int32),
+              shaped((cap,), np.int32), shaped((cap,), np.bool_))
+    rarena = (shaped((rcap,), np.int32), shaped((rcap,), np.int32),
+              shaped((rcap, 3), np.int32), shaped((rcap,), np.int32),
+              shaped((rcap,), np.bool_))
+    table = np.asarray(WITNESS_TABLE)
+    lanes = [shaped((z,), np.int32) for _ in range(3)]
+    memory = kernels.fused_range_deps_resolve.lower(
+        *lanes, shaped((b,), np.int32), shaped((b, 3), np.int32),
+        shaped((b,), np.int32), shaped((b,), np.bool_),
+        shaped((stores,), np.int32), (rarena,) * stores,
+        shaped((stores,), np.int32), (karena,) * stores,
+        shaped(table.shape, table.dtype)).compile().memory_analysis()
+    bitmaps = stores * cap * BUCKETS * 4
+    assert bitmaps <= memory.argument_size_in_bytes < bitmaps + (64 << 20)
+    packed = b * stores * (rcap + cap) // 8
+    assert packed <= memory.output_size_in_bytes < packed + 4096
+    assert memory.temp_size_in_bytes < 2 * b * cap * 4
+
+
 def test_finalize_csr_keeps_no_out_cap_by_32_temporary_at_the_key_cells_shapes(
         shaped):
     """`finalize_csr` at `preaccept-batch-10k.resolve-4096`'s steady shapes

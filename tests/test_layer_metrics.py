@@ -16,7 +16,8 @@ CELL = "preaccept-batch-10k.resolve-4096"
 RANGE_CELL = "preaccept-ranges-10k.range-20"
 LIVE_CELL = "preaccept-batch-100k.resolve-4096"
 NODE_CELL = "preaccept-8stores-100k.fanout-4096"
-CELLS = (CELL, RANGE_CELL, LIVE_CELL, NODE_CELL)
+NODE_RANGE_CELL = "preaccept-8stores-ranges-100k.range-20"
+CELLS = (CELL, RANGE_CELL, LIVE_CELL, NODE_CELL, NODE_RANGE_CELL)
 MANIFEST = common.load_json(common.ROOT / "BENCHMARK.json")
 RANGE_METRICS = ("range_encode_us_per_subject.batch",
                  "range_decode_us_per_subject.batch",
@@ -47,6 +48,10 @@ NODE_METRICS = ("store_slices_per_txn.batch",
                 "store_groups_per_dispatch.batch",
                 "fused_resolve_device_us_per_dispatch.batch",
                 "finalize_device_us_per_dispatch.batch")
+NODE_RANGE_METRICS = ("fused_range_device_us_per_dispatch.batch",
+                      "range_finalize_device_us_per_dispatch.batch",
+                      "range_slices_per_range_txn.batch",
+                      "range_groups_per_dispatch.batch")
 
 
 def listed(cell):
@@ -100,10 +105,12 @@ def test_the_cell_lists_its_metrics():
         assert [n for n in got if n in metrics] == list(metrics)
         assert not set(names) & set(metrics)
     # the range cell reports the sibling's metrics and its own five; the
-    # live cell its own ten (PR 33, PR 34, PR 37); the node cell its six
+    # live cell its own ten; the node cell its six; the node-ranges cell
+    # the range cell's five, the node cell's six and its own four
     own(RANGE_CELL, RANGE_METRICS)
     own(LIVE_CELL, LIVE_METRICS)
     own(NODE_CELL, NODE_METRICS)
+    own(NODE_RANGE_CELL, RANGE_METRICS + NODE_METRICS + NODE_RANGE_METRICS)
 
 
 @pytest.mark.parametrize("cell,entry", LISTED)
@@ -116,7 +123,8 @@ def test_manifest_entry_and_metric_file_agree(cell, entry):
     assert spec["runner"] == (
         "ranges" if entry["name"] in RANGE_METRICS
         else "live" if entry["name"] in LIVE_METRICS
-        else "node" if entry["name"] in NODE_METRICS else "batch")
+        else "node" if entry["name"] in NODE_METRICS
+        else "noderanges" if entry["name"] in NODE_RANGE_METRICS else "batch")
 
 
 @pytest.mark.parametrize("cell,entry", LISTED)
@@ -206,6 +214,22 @@ def test_node_metrics_read_nothing_on_a_one_store_cell(runs):
     assert 3.0 <= read("store_slices_per_txn.batch") <= 3.6
     assert read("store_groups_per_dispatch.batch") == 8.0
     assert read("subjects_per_dispatch.batch") > 8
+
+
+def test_node_range_metrics_read_nothing_on_the_other_cells(runs):
+    """The four accepted cells hold no range call fused over stores and ask
+    no store for Ranges (nor does a parent without the counters): the four
+    find nothing to read and do not raise; on the node-ranges cell every
+    dispatch's range call rode all eight stores."""
+    def read(name, cell):
+        return common.evaluate_ratio(common.load_json(
+            common.HERE / "layer_metrics" / f"{name}.json"), runs[cell])
+    for cell in CELLS[:-1]:
+        for name in NODE_RANGE_METRICS:
+            assert read(name, cell) is None, (cell, name)
+    assert read("range_groups_per_dispatch.batch", NODE_RANGE_CELL) == 8.0
+    assert 1.0 < read("range_slices_per_range_txn.batch", NODE_RANGE_CELL) \
+        < read("store_slices_per_txn.batch", NODE_RANGE_CELL)
 
 
 def test_fetch_split_is_the_readback_metric(counters):
